@@ -1,18 +1,28 @@
 #!/usr/bin/env bash
-# One-command LOCAL cluster bring-up with restart-on-failure supervision
-# — the container-less analogue of `docker compose up` above and of the
-# reference's `run.sh` (reference: run.sh:32 `docker stack deploy`,
+# One-command LOCAL bring-up with restart-on-failure supervision — the
+# container-less analogue of `docker compose up` and of the reference's
+# `run.sh` (reference: run.sh:32 `docker stack deploy`,
 # docker-compose.yml:3-6 restart policy).
 #
-#   deploy/run_local.sh [N_AGENTS]
+#   deploy/run_local.sh [N_AGENTS]     (default 0)
+#
+# One process per chip: a TPU belongs to the process that initialized
+# it, and the API server initializes the backend at boot and takes
+# every chip of the host.  So by default this starts exactly ONE
+# chip-owning process, the API server; in-process POST /train/horovod
+# already drives all local chips.  N_AGENTS >= 1 is the multi-host
+# topology (coordinator + one agent per host) REHEARSED on one host:
+# the agents are pinned to the CPU here, because they cannot share the
+# server's chips.  Real multi-host agents run one per TPU host
+# (deploy/docker-compose.yml, deploy/k8s.yaml).
 #
 # Env: LO_TPU_API_PORT (default 8080), LO_COORD_PORT (default 7070),
 #      LO_TPU_STORE_ROOT / LO_TPU_VOLUME_ROOT (default ./lo-data/...).
-# Stops the whole cluster on Ctrl-C / SIGTERM.
+# Stops everything on Ctrl-C / SIGTERM.
 
 set -u
 
-N_AGENTS="${1:-2}"
+N_AGENTS="${1:-0}"
 API_PORT="${LO_TPU_API_PORT:-8080}"
 COORD_PORT="${LO_COORD_PORT:-7070}"
 DATA_ROOT="${LO_DATA_ROOT:-$PWD/lo-data}"
@@ -80,8 +90,10 @@ cleanup() {
 }
 trap cleanup INT TERM
 
-supervise coordinator python -m learningorchestra_tpu coordinator \
-  --host 127.0.0.1 --port "$COORD_PORT"
+if [ "$N_AGENTS" -ge 1 ]; then
+  supervise coordinator python -m learningorchestra_tpu coordinator \
+    --host 127.0.0.1 --port "$COORD_PORT"
+fi
 # Port on the command line (redundant with LO_TPU_API_PORT) so the
 # process is identifiable by pgrep/pkill for teardown sweeps.
 supervise api python -m learningorchestra_tpu serve --port "$API_PORT"
@@ -110,10 +122,15 @@ if [ "${LO_HA_STANDBY:-0}" = "1" ]; then
     --port "$STANDBY_PORT" --host 127.0.0.1 \
     --interval 2 --misses 15
 fi
+if [ "$N_AGENTS" -ge 1 ]; then
+  echo "agents share this host with the API server, which owns its" \
+    "chips: starting them on the CPU (JAX_PLATFORMS=cpu)" >&2
+fi
 for i in $(seq 1 "$N_AGENTS"); do
-  supervise "agent$i" python -m learningorchestra_tpu agent \
+  supervise "agent$i" env JAX_PLATFORMS=cpu \
+    python -m learningorchestra_tpu agent \
     --coordinator "127.0.0.1:$COORD_PORT" --id "agent$i"
 done
 
-echo "cluster up: api=:$API_PORT coordinator=:$COORD_PORT agents=$N_AGENTS" >&2
+echo "up: api=:$API_PORT coordinator=:$COORD_PORT agents=$N_AGENTS" >&2
 wait

@@ -24,7 +24,7 @@ import os
 import tempfile
 import threading
 
-try:  # repo path + CPU-demo plugin guard, for both invocation styles
+try:  # repo on sys.path, for both invocation styles
     import _demo_env  # noqa: F401  (python examples/<name>.py)
 except ImportError:
     from examples import _demo_env  # noqa: F401  (python -m examples.<name>)

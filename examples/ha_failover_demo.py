@@ -29,7 +29,7 @@ import sys
 import tempfile
 import time
 
-try:  # repo path + CPU-demo plugin guard, for both invocation styles
+try:  # repo on sys.path, for both invocation styles
     import _demo_env  # noqa: F401  (python examples/<name>.py)
 except ImportError:
     from examples import _demo_env  # noqa: F401  (python -m examples.<name>)
@@ -61,6 +61,9 @@ def main() -> None:
     api_port, standby_port = _free_port(), _free_port()
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env.update({
+        # Two server processes on one host cannot share a chip (one
+        # process owns it); the demo is about the STORE plane, so both
+        # children are pinned to the CPU.
         "JAX_PLATFORMS": "cpu",
         "PYTHONPATH": REPO,
         "LO_TPU_API_PORT": str(api_port),

@@ -17,7 +17,7 @@ from __future__ import annotations
 import os
 import tempfile
 
-try:  # repo path + CPU-demo plugin guard, for both invocation styles
+try:  # repo on sys.path, for both invocation styles
     import _demo_env  # noqa: F401  (python examples/<name>.py)
 except ImportError:
     from examples import _demo_env  # noqa: F401  (python -m examples.<name>)

@@ -16,6 +16,15 @@ from pathlib import Path
 
 from learningorchestra_tpu.concurrency_rt import make_lock
 
+#: jax's persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR``
+#: is unset: one fixed, git-ignored path in the checkout.  The
+#: directory is part of the cache key, so a path that moved between
+#: runs would never hit; deployments place it from outside with the
+#: jax variable (services/context.py ``_init_backend``).
+DEFAULT_XLA_CACHE_DIR = (
+    Path(__file__).resolve().parent.parent / ".jax_cache"
+)
+
 
 @dataclasses.dataclass
 class StoreConfig:
@@ -31,10 +40,6 @@ class StoreConfig:
     durable_writes: bool = False
     # Document-store engine: "auto" | "native" (C++ liblodstore) | "python".
     backend: str = "auto"
-    # Persistent XLA compilation cache (first TPU compile of a model is
-    # 20-40s; repeat jobs across server restarts hit the disk cache).
-    # Empty string disables.
-    xla_cache_dir: str = "~/.learningorchestra_tpu/xla_cache"
 
     def store_path(self) -> Path:
         return Path(os.path.expanduser(self.root))
@@ -136,8 +141,8 @@ class CompileCacheConfig:
     """Process-wide compiled-program cache (train/compile_cache.py):
     jitted epoch/eval callables survive across jobs so a repeated train
     spec or a same-architecture tune sweep traces once.  Complements
-    ``StoreConfig.xla_cache_dir`` (which dedups only the XLA compile,
-    not Python tracing or closure rebuilds)."""
+    jax's persistent compilation cache (which dedups only the XLA
+    compile, not Python tracing or closure rebuilds)."""
 
     # Entry cap; <= 0 disables the cache (every job re-traces).
     # Env: LO_TPU_COMPILE_CACHE_ENTRIES.
@@ -747,8 +752,6 @@ class Config:
             cfg.store.volume_root = env["LO_TPU_VOLUME_ROOT"]
         if "LO_TPU_STORE_BACKEND" in env:
             cfg.store.backend = env["LO_TPU_STORE_BACKEND"]
-        if "LO_TPU_XLA_CACHE" in env:  # "" disables
-            cfg.store.xla_cache_dir = env["LO_TPU_XLA_CACHE"]
         if "LO_TPU_API_PORT" in env:
             cfg.api.port = int(env["LO_TPU_API_PORT"])
         if "LO_TPU_MONITORING_EXTERNAL_HOST" in env:

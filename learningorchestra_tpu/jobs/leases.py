@@ -19,9 +19,11 @@ duration of its on-device work, so
 - the lease is recorded in the job's metadata document, making
   placement observable through the ordinary GET/poll contract.
 
-On CPU-only backends leasing is a no-op (there is no chip to contend
-for; XLA:CPU interleaves fine) unless a device list is injected, which
-is how the unit tests exercise the serialization property.
+On a CPU backend leasing is a no-op (there is no chip to contend for;
+XLA:CPU interleaves fine) unless a device list is injected, which is
+how the unit tests exercise the serialization property.  A backend that
+cannot be discovered at all is an error, never "no devices": a server
+that lost its chip must fail its jobs, not run them unplaced.
 """
 
 from __future__ import annotations
@@ -98,11 +100,9 @@ class DeviceLeaser:
         else:
             import jax
 
-            try:
-                devs = jax.devices()
-            except Exception:
-                devs = []
-            if devs and devs[0].platform != "cpu":
+            # Discovery failure propagates to the leasing job.
+            devs = jax.devices()
+            if devs[0].platform != "cpu":
                 self._all = [f"{d.platform}:{d.id}" for d in devs]
             else:
                 self._all = []  # CPU backend: leasing is a no-op
@@ -314,14 +314,43 @@ class LeaseHandle:
 def jax_device_for(device_id: str):
     """Resolve a lease's device id ("tpu:3") back to the jax.Device —
     the placement step: a job that leased chip k must actually RUN on
-    chip k (``jax.default_device``), not on whatever device 0 is."""
+    chip k (``jax.default_device``), not on whatever device 0 is.
+    None for an id that names no visible device (the injected ids of
+    the unit tests)."""
     import jax
 
-    try:
-        platform, idx = device_id.rsplit(":", 1)
-        for d in jax.devices():
-            if d.platform == platform and d.id == int(idx):
-                return d
-    except Exception:  # noqa: BLE001 — placement is best-effort
+    platform, _, idx = device_id.rpartition(":")
+    if not idx.isdigit():
         return None
+    for d in jax.devices():
+        if d.platform == platform and d.id == int(idx):
+            return d
     return None
+
+
+def device_ids(tree) -> list[str]:
+    """Lease-format ids ("tpu:0") of every device holding a
+    ``jax.Array`` leaf of ``tree``, sorted; host arrays contribute
+    none.  What a job reports next to ``leasedDevices`` so placement
+    can be checked and not only granted."""
+    import jax
+
+    return sorted({
+        f"{d.platform}:{d.id}"
+        for leaf in jax.tree_util.tree_leaves(tree)
+        if isinstance(leaf, jax.Array)
+        for d in leaf.devices()
+    })
+
+
+@contextlib.contextmanager
+def placed_on(devices: Sequence[str]):
+    """Run the with-block under ``jax.default_device`` of the lease's
+    first device, so what the job computes lands on the chip its
+    metadata names.  No-op for an empty lease (CPU backend)."""
+    import jax
+
+    dev = jax_device_for(devices[0]) if devices else None
+    with jax.default_device(dev) if dev is not None \
+            else contextlib.nullcontext():
+        yield

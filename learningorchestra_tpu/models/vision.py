@@ -122,7 +122,7 @@ def space_to_depth(x, block: int = 2):
     >90% of the array on ~12% of ResNet's FLOPs.  Folding 2×2 pixels
     into channels turns the stem into a ≥128-deep contraction at a
     quarter of the spatial positions — the standard public TPU ResNet
-    recipe (see ROOFLINE.md).
+    recipe (never timed here: ROADMAP S5).
     """
     b, h, w, c = x.shape
     pad_h = (-h) % block

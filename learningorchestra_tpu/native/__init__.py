@@ -7,14 +7,15 @@ WAL format is byte-compatible with the pure-Python ``DocumentStore``, so
 either backend can open the other's data directory.
 
 ``ensure_built()`` compiles the library on demand (g++, see
-``native/Makefile``); when no toolchain is available everything falls
-back to the Python backend — the native layer is an accelerator, not a
-dependency.
+``native/Makefile``); when the build fails the compiler's output is
+logged and ``backend="auto"`` opens the Python backend — the native
+layer is an accelerator, not a dependency.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import json
 import subprocess
 from pathlib import Path
@@ -22,6 +23,7 @@ from typing import Any, Iterable
 
 from learningorchestra_tpu import faults
 from learningorchestra_tpu.concurrency_rt import make_lock
+from learningorchestra_tpu.log import get_logger
 from learningorchestra_tpu.store.document_store import (
     DuplicateKey,
     NoSuchCollection,
@@ -38,7 +40,13 @@ _build_failed = False
 
 
 def ensure_built() -> Path | None:
-    """Build (if stale/missing) and return the shared library path."""
+    """Build (if stale/missing) and return the shared library path.
+
+    Staleness is judged by the source's content hash, recorded beside
+    the library at build time — a copy or checkout resets mtimes, so
+    comparing those can keep a stale library or rebuild a fresh one.
+    A failed build is logged with the compiler's output; callers then
+    get None and ``backend="auto"`` opens the Python store."""
     global _build_failed
     with _build_lock:
         if _build_failed:
@@ -47,21 +55,33 @@ def ensure_built() -> Path | None:
         if not src.exists():
             _build_failed = True
             return None
+        digest = hashlib.sha256(src.read_bytes()).hexdigest()
+        stamp = _LIB_PATH.with_suffix(".srchash")
         if (
             not _LIB_PATH.exists()
-            or _LIB_PATH.stat().st_mtime < src.stat().st_mtime
+            or not stamp.exists()
+            or stamp.read_text().strip() != digest
         ):
             try:
+                # -B: the hash says rebuild; make's own mtime rule
+                # must not overrule it.
                 subprocess.run(
-                    ["make", "-C", str(_NATIVE_DIR)],
+                    ["make", "-B", "-C", str(_NATIVE_DIR)],
                     check=True,
                     capture_output=True,
+                    text=True,
                     timeout=120,
                 )
-            except Exception:
+            except (OSError, subprocess.SubprocessError) as exc:
                 _build_failed = True
+                get_logger("native").error(
+                    "native store build failed (%s); the Python store "
+                    "backend will be used:\n%s",
+                    exc, getattr(exc, "stderr", "") or "(no stderr)",
+                )
                 return None
-        return _LIB_PATH if _LIB_PATH.exists() else None
+            stamp.write_text(digest)
+        return _LIB_PATH
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:

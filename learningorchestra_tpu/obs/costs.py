@@ -654,16 +654,13 @@ def _offer_aot(key: str, label: str | None, payload) -> None:
 
 
 def _serialize_payload(compiled):
-    """The full ``serialize_executable`` payload tuple — blob plus the
-    in/out tree defs ``deserialize_and_load`` needs.  None when the
-    backend can't serialize."""
+    """The durable store's payload tuple for ``compiled`` (blob first —
+    train/aot_store.py ``serialize``).  None when the backend can't
+    serialize."""
     try:
-        from jax.experimental import serialize_executable
+        from learningorchestra_tpu.train import aot_store
 
-        payload = serialize_executable.serialize(compiled)
-        if not isinstance(payload, tuple):
-            payload = (payload,)
-        return payload
+        return aot_store.serialize(compiled)
     except Exception:  # noqa: BLE001
         return None
 

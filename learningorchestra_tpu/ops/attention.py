@@ -33,17 +33,12 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_BIG = -1e30  # additive mask value; exp(_NEG_BIG - lse) == 0 in f32
 _LSE_EMPTY = 1e30  # lse sentinel for fully-masked rows: exp(s - 1e30) == 0
 
-# jax renamed TPUCompilerParams → CompilerParams across releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 
 def _tpu_params(n_parallel: int):
     """Mark the trailing grid axis sequential (carry in VMEM scratch)
     and the leading ones parallel, so Mosaic pipelines the K/V block
     DMAs against compute (double buffering)."""
-    return _CompilerParams(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel",) * n_parallel + ("arbitrary",)
     )
 
@@ -581,10 +576,10 @@ def flash_attention(
     Sequences are padded to block multiples internally; padded keys are
     masked out, padded query rows are sliced off the output.
 
-    Default blocks follow the TPU v5e sweep (TPU_EVIDENCE.md): (256,
-    512) wins for T <= 8k (1.20x XLA), (512, 1024) for longer (2.80x at
-    T=32k, where XLA OOMs with masks); 128-sized blocks leave the MXU
-    idle on grid overhead (~4 MFLOP per step).
+    Default blocks follow the round-2 sweep on one TPU v5e (older jax,
+    scalar-readback timing; not re-measured since — PERF.md): (256,
+    512) won for T <= 8k, (512, 1024) for longer; 128-sized blocks
+    leave the MXU idle on grid overhead (~4 MFLOP per step).
     """
     if interpret is None:
         interpret = _auto_interpret()
@@ -611,9 +606,59 @@ def flash_attention(
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         km = jnp.pad(km, ((0, 0), (0, 0), (0, pad_k)))
 
-    out = _flash_core(
-        q, k, v, km, block_q, block_k, interpret, causal, window
+    out = _per_shard(
+        lambda q, k, v, km: _flash_core(
+            q, k, v, km, block_q, block_k, interpret, causal, window
+        ),
+        q, k, v, km,
     )
     if pad_q:
         out = out[:, :, :tq]
     return out
+
+
+# Mesh axis conventions of parallel/mesh.py: the batch is sharded over
+# the data axes, attention heads over the tensor axis.
+_BATCH_AXES = ("dp", "fsdp")
+_HEAD_AXIS = "tp"
+
+
+def _per_shard(core, q, k, v, km):
+    """Run ``core(q, k, v, km)`` once per device shard when tracing
+    under a device mesh (``jax.set_mesh`` — the mesh-sharded trainers).
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so under a mesh the call is wrapped in
+    ``shard_map`` over the axes jit still partitions automatically:
+    batch over the data axes, heads over the tensor axis, each only
+    when it divides the dimension (otherwise that dimension — and the
+    whole sequence, always — is gathered and the kernel's work repeated
+    across the axis, e.g. the batch-of-one init pass).  Inside another
+    ``shard_map`` (ring attention, the pipeline step) every axis is
+    already manual and the kernel is per-device as it stands."""
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = {
+        a for a in mesh.axis_names
+        if a not in mesh.manual_axes and mesh.shape[a] > 1
+    }
+    if not auto:
+        return core(q, k, v, km)
+
+    def axes_for(dim: int, names: tuple):
+        names = tuple(a for a in names if a in auto)
+        size = 1
+        for a in names:
+            size *= mesh.shape[a]
+        return names if names and dim % size == 0 else None
+
+    batch = axes_for(q.shape[0], _BATCH_AXES)
+    heads = axes_for(q.shape[1], (_HEAD_AXIS,))
+    qkv = jax.sharding.PartitionSpec(batch, heads, None, None)
+    # check_vma=False: pallas_call cannot declare vma on its outputs.
+    return jax.shard_map(
+        core,
+        in_specs=(qkv, qkv, qkv,
+                  jax.sharding.PartitionSpec(batch, None, None)),
+        out_specs=qkv,
+        check_vma=False,
+    )(q, k, v, km)

@@ -107,11 +107,14 @@ class MultiHeadSelfAttention(nn.Module):
     ``use_flash``: None → Pallas kernel on TPU, reference elsewhere;
     True/False forces a path (tests force both and compare).
 
-    Default-on is hardware-validated: the streamed-K/V kernel compiles
-    on TPU v5e, matches ``mha_reference`` to bf16 tolerance fwd+bwd
-    across shapes (T 16..128k, D 8..128, padded/masked), and beats
-    XLA's fused attention at long T (1.7x fwd / 3.5x bwd at T=16k;
-    the reference OOMs beyond ~32k where the kernel keeps running).
+    On the chip (TPU v5e, jax 0.9.0; PR 21, PERF.md) the streamed-K/V
+    kernel compiles and matches ``mha_reference`` to bf16 tolerance
+    fwd+bwd for full / causal / window at T 128..16384 (333 unaligned),
+    D 64 and 128, and runs per shard under a device mesh
+    (ops/attention.py ``_per_shard``).  Where it beats XLA's fused
+    attention was last measured in round 2 on an older jax (from
+    T = 16k; behind at T <= 4096) — ROADMAP S6 selects by sequence
+    length once that is re-measured.
     """
 
     num_heads: int

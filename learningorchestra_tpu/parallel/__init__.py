@@ -20,17 +20,6 @@ with the TPU-native equivalents:
   Ray client + GCS (SURVEY §5.8).
 """
 
-import jax as _jax
-
-# ``jax.shard_map`` only graduated out of ``jax.experimental`` in newer
-# releases; on the pinned 0.4.x line the top-level name does not exist.
-# Install it so every call site (and user code written against the new
-# spelling) runs on both.
-if not hasattr(_jax, "shard_map"):  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _jax.shard_map = _shard_map
-
 from learningorchestra_tpu.parallel.mesh import (  # noqa: F401
     MeshSpec,
     build_mesh,

@@ -31,6 +31,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from learningorchestra_tpu.jobs.cancel import cancel_requested
+from learningorchestra_tpu.jobs.leases import device_ids
 from learningorchestra_tpu.obs import tracing as obs_tracing
 from learningorchestra_tpu.parallel.mesh import MeshSpec, build_mesh
 from learningorchestra_tpu.parallel.sharding import param_shardings
@@ -82,6 +83,8 @@ class DistributedTrainer:
         # as they do on the single-device estimator.
         self.params = None
         self.opt_state = None
+        # Devices the last in-memory fit's batches were placed on.
+        self.batch_devices: list[str] = []
         self.history = TrainHistory()
         self._epoch_fn = None
         self._eval_fn = None
@@ -93,14 +96,21 @@ class DistributedTrainer:
         """Mesh-aware models (ring attention over sp) get the mesh bound
         for the duration of a trainer call ONLY — left bound, the
         estimator's own single-device predict/evaluate would hit
-        shard_map divisibility errors on arbitrary batch shapes."""
+        shard_map divisibility errors on arbitrary batch shapes.
+
+        The mesh is also jax's ambient mesh for the call
+        (``jax.set_mesh``): the Pallas kernels read it to run per shard
+        (ops/attention.py ``_per_shard``) — GSPMD cannot partition a
+        Mosaic kernel, so without it a flash-attention model fails to
+        lower on a multi-chip mesh."""
         est = self.estimator
         bindable = hasattr(est, "bind_mesh")
         if bindable and self._bind_depth == 0:
             est.bind_mesh(self.mesh)
         self._bind_depth += 1
         try:
-            yield
+            with jax.set_mesh(self.mesh):
+                yield
         finally:
             self._bind_depth -= 1
             if bindable and self._bind_depth == 0:
@@ -452,6 +462,7 @@ class DistributedTrainer:
                 xs = self._put_global(xb, self._data_sharding(xb.ndim, tokens))
                 ys = self._put_global(yb, self._data_sharding(yb.ndim, False))
                 ms = self._put_global(mb, self._data_sharding(mb.ndim, False))
+                self.batch_devices = device_ids(xs)
                 root_key = jax.random.PRNGKey(est.seed)
                 last_save = time.monotonic()
                 ran = 0  # epochs executed THIS call (early stop may cut short)

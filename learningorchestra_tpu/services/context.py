@@ -11,7 +11,11 @@ from typing import Any
 
 import pandas as pd
 
-from learningorchestra_tpu.config import Config, get_config
+from learningorchestra_tpu.config import (
+    DEFAULT_XLA_CACHE_DIR,
+    Config,
+    get_config,
+)
 from learningorchestra_tpu.jobs import JobEngine
 from learningorchestra_tpu.log import get_logger, kv
 from learningorchestra_tpu.store import (
@@ -80,7 +84,7 @@ class ServiceContext:
         self.leaser = DeviceLeaser()
         self.engine.leaser = self.leaser
         # When the compiled-program cache clears on a device-set change
-        # (TPU restart / tunnel reattach), the engine's warm-start
+        # (TPU runtime restart), the engine's warm-start
         # hints are stale — 'warm' jobs would trace like any other.
         # Weakly bound: short-lived contexts (tests) must not pin dead
         # engines through the process-global cache.
@@ -194,7 +198,7 @@ class ServiceContext:
             self.cluster.join()
         # Backend init FIRST: recovery may re-dispatch train fits,
         # and job threads racing first-time backend init deadlock
-        # inside xla_bridge (the race _init_backend exists to remove).
+        # inside jax (the race _init_backend exists to remove).
         self._init_backend()
         if self.cluster is not None:
             with self.cluster.journal_guard():
@@ -608,27 +612,36 @@ class ServiceContext:
         """Eagerly initialize the JAX backend on the main thread.
 
         Two job threads racing first-time backend init deadlock inside
-        xla_bridge (observed with concurrent fits on worker threads);
-        paying init once at service startup removes the race and also
-        front-loads the TPU client handshake out of the first job's
-        latency.  The persistent compilation cache means a re-submitted
-        job (or a restarted server) skips the 20-40s TPU compile."""
+        jax's backend registry (observed with concurrent fits on worker
+        threads); paying init once at service startup removes the race
+        and also front-loads the TPU client handshake out of the first
+        job's latency.  A backend that cannot initialize fails the boot.
+
+        The persistent compilation cache lets a re-submitted job (or a
+        restarted server) skip the TPU compile.  Where
+        ``JAX_COMPILATION_CACHE_DIR`` is set jax already uses it and
+        nothing is set here; otherwise the cache goes to one fixed path
+        in the checkout (``config.DEFAULT_XLA_CACHE_DIR`` — the path is
+        part of the cache key, so it must not move between runs)."""
         import os
 
         import jax
 
-        cache_dir = self.config.store.xla_cache_dir
-        if cache_dir:
-            try:
-                path = os.path.expanduser(cache_dir)
-                os.makedirs(path, exist_ok=True)
-                jax.config.update("jax_compilation_cache_dir", path)
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 1.0
-                )
-            except Exception:
-                pass  # cache is an optimization, never a failure
-        jax.devices()
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update(
+                "jax_compilation_cache_dir", str(DEFAULT_XLA_CACHE_DIR)
+            )
+        devices = jax.devices()
+        # Logged once per boot: with JAX_PLATFORMS unset jax drops to
+        # the CPU when the TPU client cannot start, and nothing else on
+        # the job path would say so.
+        get_logger("context").info(kv(
+            event="backend",
+            platform=devices[0].platform,
+            kind=devices[0].device_kind,
+            count=len(devices),
+            xlaCacheDir=jax.config.jax_compilation_cache_dir,
+        ))
 
     def close(self) -> None:
         from learningorchestra_tpu.train import compile_cache

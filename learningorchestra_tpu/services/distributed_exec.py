@@ -30,6 +30,7 @@ import concurrent.futures
 import time
 
 from learningorchestra_tpu import dsl
+from learningorchestra_tpu.jobs.leases import device_ids
 from learningorchestra_tpu.services.context import (
     ServiceContext,
     ValidationError,
@@ -310,6 +311,10 @@ class DistributedExecutorService:
             return {
                 "fitTime": fit_time,
                 "meshDevices": trainer.mesh.size,
+                # Where the sharded state and batches actually lived
+                # (the check on leasedDevices / meshDevices).
+                "paramDevices": device_ids(trainer.params),
+                "batchDevices": trainer.batch_devices,
                 "compileCache": cache_delta,
             }
 

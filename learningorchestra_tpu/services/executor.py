@@ -24,6 +24,7 @@ from typing import Any
 import numpy as np
 
 from learningorchestra_tpu import dsl
+from learningorchestra_tpu.jobs.leases import device_ids, placed_on
 from learningorchestra_tpu.train.neural import NeuralEstimator
 from learningorchestra_tpu.services.context import (
     ServiceContext,
@@ -221,16 +222,19 @@ class ExecutorService:
             # the body makes (the fit epoch loop) book against THIS
             # job's ledger entry.
             with obs_costs.job_scope(name):
+                param_devices = []
                 if isinstance(instance, NeuralEstimator):
                     # On-device work: take a chip lease so concurrent
-                    # neural jobs get placed, not interleaved
-                    # (jobs/leases.py).
-                    with self.ctx.leaser.lease(1, label=name) as devs:
+                    # neural jobs get placed, not interleaved, and RUN
+                    # on the leased chip (jobs/leases.py).
+                    with self.ctx.leaser.lease(1, label=name) as devs, \
+                            placed_on(devs):
                         if devs:
                             self.ctx.artifacts.metadata.update(
                                 name, {"leasedDevices": devs}
                             )
                         result = getattr(instance, method)(**params)
+                        param_devices = device_ids(instance.params)
                 else:
                     result = getattr(instance, method)(**params)
             fit_time = time.perf_counter() - t0
@@ -260,6 +264,11 @@ class ExecutorService:
                 self.ctx.notify_artifact_changed(name)
                 extra = {"fitTime": fit_time,
                          "compileCache": cache_delta}
+                if param_devices:
+                    # Where the trained params actually lived — the
+                    # check on leasedDevices, which only says what the
+                    # job was granted.
+                    extra["paramDevices"] = param_devices
                 device_time = obs_costs.job_summary(name)
                 if device_time is not None:
                     # Attributed device seconds/flops (and MFU when a
@@ -397,9 +406,6 @@ class ExecutorService:
             ]
 
             def eval_candidate(idx: int, kwargs: dict):
-                from learningorchestra_tpu.jobs.leases import (
-                    jax_device_for,
-                )
                 from learningorchestra_tpu.obs import (
                     costs as obs_costs,
                 )
@@ -434,16 +440,11 @@ class ExecutorService:
                 else:
                     lease = contextlib.nullcontext([])
                 with lease as devs:
-                    import jax
-
-                    dev = jax_device_for(devs[0]) if devs else None
-                    place = jax.default_device(dev) \
-                        if dev is not None else contextlib.nullcontext()
                     # Re-bind the job scope: trials run on pool
                     # threads, which do not inherit the engine
                     # thread's context — every candidate's epochs
                     # still book against THIS tune job.
-                    with place, obs_costs.job_scope(name):
+                    with placed_on(devs), obs_costs.job_scope(name):
                         t0 = time.perf_counter()
                         getattr(candidate, method)(**trial_params)
                         fit_time = time.perf_counter() - t0

@@ -53,14 +53,31 @@ __all__ = [
     "enabled",
     "get_store",
     "reset_store",
+    "serialize",
     "stats_snapshot",
 ]
 
 logger = get_logger("aot_store")
 
 _MAGIC = b"LOAOT1\n"
-_FORMAT_VERSION = 1
+# 2: the payload carries the executable's device ids (see serialize).
+_FORMAT_VERSION = 2
 _MANIFEST = "manifest.json"
+
+
+def serialize(compiled) -> tuple:
+    """The payload :meth:`AOTExecutableStore.offer` persists for a
+    ``jax.stages.Compiled``: ``serialize_executable``'s ``(blob,
+    in_tree, out_tree)`` plus the ids of the devices the executable
+    runs on, in assignment order.  ``deserialize_and_load`` otherwise
+    assumes EVERY device of the backend, which breaks (or aborts the
+    process) for a single-chip program restored on a multi-chip host."""
+    from jax.experimental import serialize_executable
+
+    return (
+        *serialize_executable.serialize(compiled),
+        [d.id for d in compiled.runtime_executable().local_devices()],
+    )
 
 
 def _faults():
@@ -195,8 +212,8 @@ class AOTExecutableStore:
 
     def offer(self, key: str, payload: Any, *,
               label: str | None = None) -> bool:
-        """Persist one program's serialized-executable ``payload`` (the
-        tuple ``serialize_executable.serialize`` returned).  Best
+        """Persist one program's serialized-executable ``payload``
+        (what :func:`serialize` returned).  Best
         effort: any failure counts ``storeErrors`` and the build it
         rides proceeds untouched.  Re-offering a stored key refreshes
         its label/bytes and bumps its heat."""
@@ -268,12 +285,15 @@ class AOTExecutableStore:
                 raise ValueError("device signature mismatch")
             if hashlib.sha256(blob).hexdigest() != header.get("sha256"):
                 raise ValueError("payload checksum mismatch")
+            import jax
             from jax.experimental import serialize_executable
 
-            parts = pickle.loads(blob)
-            if not isinstance(parts, tuple):
-                parts = (parts,)
-            compiled = serialize_executable.deserialize_and_load(*parts)
+            serialized, in_tree, out_tree, device_ids = pickle.loads(blob)
+            by_id = {d.id: d for d in jax.devices()}
+            compiled = serialize_executable.deserialize_and_load(
+                serialized, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids],
+            )
         except FileNotFoundError:
             with self._lock:
                 self.misses += 1

@@ -35,7 +35,7 @@ Correctness notes:
   ring-attention-for-mesh-X from vanilla automatically; distributed
   entries additionally key on mesh axis names + device assignment.
 - the cache clears itself whenever the visible device set changes
-  (TPU restart, tunnel reattach): compiled executables pin device
+  (TPU runtime restart): compiled executables pin device
   handles that are dead afterwards.
 
 Observability: hit/miss/eviction/trace-time counters (``stats()``)
@@ -273,8 +273,8 @@ def apply_program_key(module: Any, *, rows: int | None = None) -> str:
 
 def _device_signature() -> tuple:
     """Identity of the visible device set; compiled executables are
-    invalid the moment this changes (restarted TPU runtime, reattached
-    tunnel, resized slice)."""
+    invalid the moment this changes (restarted TPU runtime, resized
+    slice)."""
     import jax
 
     try:

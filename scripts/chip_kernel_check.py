@@ -1,0 +1,175 @@
+"""Compile and run each Pallas kernel family once on the chip against
+its jnp reference — the on-chip half of tests/test_tpu_lowering.py
+(lowering on a CPU host proves the Pallas → Mosaic MLIR step; the Mosaic
+compile itself happens in libtpu, only here).
+
+    chiprun -- python scripts/chip_kernel_check.py            # one chip
+    chiprun --chips 4 -- python scripts/chip_kernel_check.py  # + ring flash
+
+Fails without a TPU.  Every case runs; the exit code is non-zero if any
+case failed to compile or missed its tolerance.  One JSON line per case
+goes to stdout and the full list to ``chiprun_out/kernel_check.json``.
+Tolerances: bf16 inputs carry ~3 significant digits, the flash kernels
+round probabilities to bf16 before the second matmul, and gradients sum
+T such terms — 2e-2 absolute on unit-variance inputs is the bound the
+CPU interpret-mode tests already hold the same kernels to.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import traceback
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from learningorchestra_tpu.ops.attention import (
+    flash_attention,
+    mha_reference,
+)
+from learningorchestra_tpu.ops.quant import (
+    dequantize_rowwise,
+    quantize_rowwise,
+)
+
+TOL = 2e-2
+
+
+def _max_err(a, b) -> float:
+    return float(jnp.max(jnp.abs(
+        a.astype(jnp.float32) - b.astype(jnp.float32)
+    )))
+
+
+def _flash_case(t: int, d: int, mode: str, b: int = 2, h: int = 4) -> dict:
+    """Forward and all three gradients of the flash kernel vs the
+    reference, with a key-side padding mask."""
+    rng = np.random.default_rng(t * 131 + d)
+    q, k, v = (
+        jnp.asarray(rng.standard_normal((b, h, t, d)), jnp.bfloat16)
+        for _ in range(3)
+    )
+    mask = np.ones((b, t), np.float32)
+    mask[-1, t - t // 5:] = 0.0  # padded tail on one row
+    mask = jnp.asarray(mask)
+    kw = {
+        "full": {},
+        "causal": {"causal": True},
+        "window": {"causal": True, "window": max(8, t // 4)},
+    }[mode]
+
+    def loss(attend):
+        def fn(q, k, v):
+            out = attend(q, k, v, mask, **kw)
+            return jnp.sum(out.astype(jnp.float32) ** 2), out
+        return jax.jit(jax.value_and_grad(fn, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    (_, out), grads = loss(flash_attention)(q, k, v)
+    (_, ref), ref_grads = loss(mha_reference)(q, k, v)
+    errs = {
+        "fwd": _max_err(out, ref),
+        **{
+            name: _max_err(g, rg) / max(
+                1.0, float(jnp.max(jnp.abs(rg.astype(jnp.float32))))
+            )
+            for name, g, rg in zip(("dq", "dk", "dv"), grads, ref_grads)
+        },
+    }
+    return {"errs": errs, "ok": all(e < TOL for e in errs.values())}
+
+
+def _quant_case() -> dict:
+    """30522x768 (BERT's embedding).  Round-to-nearest must land within
+    half a quantization step of x; stochastic rounding (the hardware
+    PRNG) within one step and unbiased on average; scales and the
+    dequantize kernel must be exact against numpy."""
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.standard_normal((30522, 768)), jnp.float32)
+    step = jnp.max(jnp.abs(x), axis=1, keepdims=True) / 127.0
+    out: dict = {}
+    for name, stochastic, bound in (
+        ("nearest", False, 0.5), ("stochastic", True, 1.0),
+    ):
+        values, scales = jax.jit(
+            lambda a: quantize_rowwise(a, stochastic=stochastic, seed=3)
+        )(x)
+        back = jax.jit(dequantize_rowwise)(values, scales)
+        off = (back - x) / step
+        out[name] = {
+            "max_steps_off": float(jnp.max(jnp.abs(off))),
+            "mean_bias_steps": float(jnp.mean(off)),
+            "dequant_exact": bool(np.array_equal(
+                np.asarray(back),
+                np.asarray(values, np.float32) * np.asarray(scales),
+            )),
+            "scale_err": _max_err(scales, step),
+        }
+        out[name]["ok"] = (
+            out[name]["max_steps_off"] <= bound + 1e-3
+            and abs(out[name]["mean_bias_steps"]) < 1e-2
+            and out[name]["dequant_exact"]
+            and out[name]["scale_err"] < 1e-6
+        )
+    out["ok"] = out["nearest"]["ok"] and out["stochastic"]["ok"]
+    return out
+
+
+def main() -> int:
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"no TPU: jax found {dev.platform}", file=sys.stderr)
+        return 1
+    print(json.dumps({
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": jax.device_count(),
+    }), flush=True)
+    cases = [
+        (f"flash:{mode}:T{t}:D{d}",
+         lambda t=t, d=d, mode=mode: _flash_case(t, d, mode))
+        for d in (64, 128)
+        for t in (128, 333, 2048)
+        for mode in ("full", "causal", "window")
+    ]
+    # The long-sequence default blocks (512, 1024) with D = 128: the
+    # dkv kernel's VMEM high-water mark.  One head: the reference
+    # materializes (T, T) f32 scores per head, 1 GiB here.
+    cases.append(("flash:causal:T16384:D128",
+                  lambda: _flash_case(16384, 128, "causal", b=1, h=1)))
+    cases.append(("quant:30522x768", _quant_case))
+    if jax.device_count() >= 4:
+        # chip_smoke.py's multi-chip phase owns the ring-flash check
+        # (it raises on a miss).
+        from chip_smoke import ring_flash_check
+
+        cases.append(("ring_flash:sp4", lambda: {
+            "errs": ring_flash_check(4), "ok": True,
+        }))
+    results = []
+    for name, fn in cases:
+        try:
+            rec = {"case": name, **fn()}
+        except Exception as exc:  # noqa: BLE001 — every case reports
+            rec = {
+                "case": name, "ok": False,
+                "error": f"{type(exc).__name__}: {exc}"[:2000],
+                "trace": traceback.format_exc()[-3000:],
+            }
+        results.append(rec)
+        print(json.dumps({k: v for k, v in rec.items() if k != "trace"}),
+              flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/kernel_check.json", "w") as fh:
+        json.dump(results, fh, indent=1)
+    failed = [r["case"] for r in results if not r["ok"]]
+    print(json.dumps({"failed": failed, "total": len(results)}))
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -530,8 +530,9 @@ model.create(
 )
 ctx.engine.wait("m", timeout=180)
 # Epochs 0-1 run free (and checkpoint); every later epoch's top delays
-# 400 ms — the parent's SIGKILL lands while the fit provably runs.
-faults.arm("train.epoch", "delay", delay_ms=400, after=2)
+# 4 s — the parent boots engine B (seconds of imports) BEFORE it kills
+# this engine, and the SIGKILL must still land while the fit runs.
+faults.arm("train.epoch", "delay", delay_ms=4000, after=2)
 ex.create(
     "fit1", parent_name="m", method="fit",
     method_parameters={
@@ -608,7 +609,6 @@ def _drill_env(tmp_path, engine_id):
         "JAX_PLATFORMS": "cpu",
         "LO_TPU_STORE_ROOT": str(tmp_path / "store"),
         "LO_TPU_VOLUME_ROOT": str(tmp_path / "vol"),
-        "LO_TPU_XLA_CACHE": "",
         "LO_TPU_CLUSTER_ENABLED": "1",
         "LO_TPU_CLUSTER_ENGINE_ID": engine_id,
         "LO_TPU_CLUSTER_HEARTBEAT_S": "0.2",

@@ -405,7 +405,6 @@ def aot_round_trip(tmp_path):
     singleton for the test, always uninstalled after."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import serialize_executable
 
     from learningorchestra_tpu.train import aot_store
     from learningorchestra_tpu.train import compile_cache as cc
@@ -418,9 +417,7 @@ def aot_round_trip(tmp_path):
         jax.ShapeDtypeStruct((4,), jnp.float32)
     ).compile()
     key = cc.fingerprint("chaos", "aot")
-    store.offer(
-        key, serialize_executable.serialize(compiled), label="chaos"
-    )
+    store.offer(key, aot_store.serialize(compiled), label="chaos")
     yield store, key
     aot_store.reset_store()
 

@@ -114,27 +114,58 @@ def test_rerun_after_restart(artifacts, engine):
     assert len(artifacts.ledger.history("j6")) == 2
 
 
-def test_xla_compilation_cache_configured(tmp_path):
-    """ServiceContext points JAX at the persistent compile cache."""
+def _boot_context_with_cache_env(tmp_path, monkeypatch, env_value):
+    """Boot a ServiceContext with JAX_COMPILATION_CACHE_DIR set to
+    ``env_value`` (None = unset); returns what jax's cache-dir option
+    held afterwards.  The option is global: restored on the way out."""
     import jax
 
     from learningorchestra_tpu.config import Config
     from learningorchestra_tpu.services.context import ServiceContext
 
+    if env_value is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_value)
     cfg = Config()
     cfg.store.root = str(tmp_path / "store")
     cfg.store.volume_root = str(tmp_path / "volumes")
-    cfg.store.xla_cache_dir = str(tmp_path / "xla")
     prev = jax.config.jax_compilation_cache_dir
-    ctx = ServiceContext(cfg)
+    jax.config.update("jax_compilation_cache_dir", "sentinel-untouched")
     try:
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "xla")
-        assert (tmp_path / "xla").is_dir()
+        ServiceContext(cfg).close()
+        return jax.config.jax_compilation_cache_dir
     finally:
-        ctx.close()
-        # Global jax config: restore so later tests don't write compile
-        # cache entries into this (deleted) tmp dir.
         jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_dir_left_alone_when_env_places_it(
+    tmp_path, monkeypatch
+):
+    """Where JAX_COMPILATION_CACHE_DIR is set the program sets no cache
+    directory in code — jax already reads the variable itself."""
+    seen = _boot_context_with_cache_env(
+        tmp_path, monkeypatch, str(tmp_path / "from-env")
+    )
+    assert seen == "sentinel-untouched"
+    assert not (tmp_path / "from-env").exists()
+
+
+def test_compile_cache_dir_defaults_to_fixed_checkout_path(
+    tmp_path, monkeypatch
+):
+    """Unset, the cache goes to ONE fixed, git-ignored path derived from
+    the package location — the path is part of the cache key, so a
+    per-run temp directory would never hit."""
+    from pathlib import Path
+
+    from learningorchestra_tpu.config import DEFAULT_XLA_CACHE_DIR
+
+    repo = Path(__file__).resolve().parent.parent
+    assert DEFAULT_XLA_CACHE_DIR == repo / ".jax_cache"
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+    seen = _boot_context_with_cache_env(tmp_path, monkeypatch, None)
+    assert seen == str(DEFAULT_XLA_CACHE_DIR)
 
 
 def test_stdout_capture_is_thread_scoped():

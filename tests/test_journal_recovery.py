@@ -731,7 +731,6 @@ def test_kill9_drill_resumes_from_newest_checkpoint(tmp_path):
         "JAX_PLATFORMS": "cpu",
         "LO_TPU_STORE_ROOT": str(tmp_path / "store"),
         "LO_TPU_VOLUME_ROOT": str(tmp_path / "vol"),
-        "LO_TPU_XLA_CACHE": "",
     })
     env.pop("LO_TPU_WITNESS", None)
 

@@ -94,6 +94,23 @@ class TestDeviceLeaser:
         with leaser.lease(1, label="host") as devs:
             assert devs == []
 
+    def test_device_discovery_failure_raises(self, monkeypatch):
+        # A backend that cannot be discovered is an error, never "no
+        # devices": a server that lost its chip must fail its jobs, not
+        # run them unplaced under a metadata doc that says nothing.
+        import jax
+
+        def no_backend():
+            raise RuntimeError("Unable to initialize backend 'tpu'")
+
+        monkeypatch.setattr(jax, "devices", no_backend)
+        leaser = DeviceLeaser()
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            with leaser.lease(1, label="job"):
+                pass
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            leaser.device_count
+
     def test_timeout_raises(self):
         leaser = DeviceLeaser(device_ids=["tpu:0"])
         with leaser.lease(1, label="holder"):
@@ -103,10 +120,20 @@ class TestDeviceLeaser:
 
 
 class TestLeaseVisibleInMetadata:
-    def test_train_job_records_lease_in_metadata(self, tmp_path):
+    # "cpu:5" names one of the 8 virtual devices: the job must RUN
+    # there (jax.default_device of its lease) and say so.  "tpu:0"
+    # names no device of this backend: the lease is still granted and
+    # recorded, and the params report the default device they landed on.
+    @pytest.mark.parametrize("device_id, ran_on", [
+        ("cpu:5", ["cpu:5"]), ("tpu:0", ["cpu:0"]),
+    ])
+    def test_train_job_records_lease_in_metadata(
+        self, tmp_path, device_id, ran_on
+    ):
         """Through the service layer: a neural train job on an
         accelerator-leased context stamps leasedDevices into its
-        metadata doc (observable via the ordinary GET/poll path)."""
+        metadata doc (observable via the ordinary GET/poll path), runs
+        on the leased device and reports where its params lived."""
         import numpy as np
 
         from learningorchestra_tpu.config import Config
@@ -120,7 +147,7 @@ class TestLeaseVisibleInMetadata:
         ctx = ServiceContext(cfg)
         try:
             # Simulate an accelerator host: inject lease devices.
-            ctx.leaser._explicit = ["tpu:0"]
+            ctx.leaser._explicit = [device_id]
             ctx.leaser._free = None
             model = ModelService(ctx)
             executor = ExecutorService(ctx)
@@ -150,7 +177,8 @@ class TestLeaseVisibleInMetadata:
             ctx.engine.wait("lease_fit", timeout=120)
             meta = ctx.artifacts.metadata.read("lease_fit")
             assert meta["jobState"] == "finished", meta.get("exception")
-            assert meta.get("leasedDevices") == ["tpu:0"]
+            assert meta.get("leasedDevices") == [device_id]
+            assert meta.get("paramDevices") == ran_on
             assert any(
                 label == "lease_fit" for label, *_ in ctx.leaser.history
             )

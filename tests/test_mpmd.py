@@ -457,7 +457,6 @@ def test_kill9_mpmd_fit_resumes_every_stage(tmp_path):
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
         "LO_TPU_STORE_ROOT": str(tmp_path / "store"),
         "LO_TPU_VOLUME_ROOT": str(tmp_path / "vol"),
-        "LO_TPU_XLA_CACHE": "",
     })
     env.pop("LO_TPU_WITNESS", None)
 

@@ -584,3 +584,63 @@ class TestNativeIngestProperty:
                         )
         finally:
             server.shutdown()
+
+
+class TestBuildOnDemand:
+    """``ensure_built`` decides staleness by the source's content hash
+    (a copy or checkout resets mtimes) and never swallows a compiler
+    failure."""
+
+    def _isolate(self, tmp_path, monkeypatch):
+        monkeypatch.setattr(native, "_LIB_PATH", tmp_path / "lib.so")
+        monkeypatch.setattr(native, "_build_failed", False)
+
+    def test_failed_build_is_logged_with_compiler_stderr(
+        self, tmp_path, monkeypatch
+    ):
+        import subprocess
+
+        self._isolate(tmp_path, monkeypatch)
+
+        def failing_make(cmd, **kw):
+            raise subprocess.CalledProcessError(
+                2, cmd, stderr="docstore.cpp:1:1: error: no such type"
+            )
+
+        monkeypatch.setattr(subprocess, "run", failing_make)
+        # The repo's logger does not propagate to pytest's capture
+        # (log.py): listen on it directly.
+        import logging
+
+        records: list = []
+        handler = logging.Handler()
+        handler.emit = records.append
+        logger = logging.getLogger("lo.native")
+        logger.addHandler(handler)
+        try:
+            assert native.ensure_built() is None
+        finally:
+            logger.removeHandler(handler)
+        assert [r.levelname for r in records] == ["ERROR"]
+        assert "docstore.cpp:1:1: error: no such type" in \
+            records[0].getMessage()
+
+    def test_hash_mismatch_rebuilds_whatever_the_mtimes_say(
+        self, tmp_path, monkeypatch
+    ):
+        import subprocess
+
+        self._isolate(tmp_path, monkeypatch)
+        lib = tmp_path / "lib.so"
+        lib.write_bytes(b"stale")  # newer than the source by mtime
+        lib.with_suffix(".srchash").write_text("not-the-source-hash")
+        calls = []
+        monkeypatch.setattr(
+            subprocess, "run",
+            lambda cmd, **kw: calls.append(cmd),
+        )
+        assert native.ensure_built() == lib
+        assert calls and "-B" in calls[0]
+        # Stamped with the real hash: the next call builds nothing.
+        assert native.ensure_built() == lib
+        assert len(calls) == 1

@@ -30,7 +30,6 @@ def _seed_store(tmp_path, key="warmboot-test", label="wb"):
     """A store holding one REAL serialized executable for ``a * 2``."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import serialize_executable
 
     store = aot_store.reset_store(
         root=str(tmp_path / "aot"), max_entries=8, max_bytes=1 << 30
@@ -39,9 +38,7 @@ def _seed_store(tmp_path, key="warmboot-test", label="wb"):
     compiled = jax.jit(lambda a: a * 2.0).lower(
         jax.ShapeDtypeStruct((4,), jnp.float32)
     ).compile()
-    store.offer(
-        fp, serialize_executable.serialize(compiled), label=label
-    )
+    store.offer(fp, aot_store.serialize(compiled), label=label)
     return store, fp
 
 
