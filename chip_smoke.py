@@ -28,6 +28,7 @@ Wall-clocks printed on the way are smoke output, not metrics.
 from __future__ import annotations
 
 import json
+import resource
 import shutil
 import sys
 import threading
@@ -614,7 +615,11 @@ def run(device: dict, root: Path, *, bert: dict, decode: dict,
     platform = device["platform"]
     server, ctx = boot(root, leaser)
     try:
+        # fileSizeLimit: ``ulimit -f`` in bytes, -1 for none; an
+        # artifact is written as files of at most artifactPartBytes.
         _log("boot", storeBackend=type(server.ctx.documents).__name__,
+             fileSizeLimit=resource.getrlimit(resource.RLIMIT_FSIZE)[0],
+             artifactPartBytes=server.ctx.volumes.part_bytes,
              xlaCacheDir=jax.config.jax_compilation_cache_dir,
              xlaCache=cache_entries())
         _log("bert", **phase_bert(server, ctx, root, platform, **bert),

@@ -13,14 +13,23 @@ tree keyed by service type, with three formats:
   arrays) — the reference's fallback path, kept for parity;
 - ``bytes``: raw streams (generic dataset ingest,
   database_api_image/database.py:61-83).
+
+An object bigger than :data:`PART_BYTES` is written as several files:
+hosts cap the size of one file (``RLIMIT_FSIZE`` — the write fails with
+``EFBIG``), and a BERT-base train artifact is ~1.3 GB with its Adam
+state.  The artifact's path then holds a small manifest naming the part
+files next to it; anything that fits one part stays the plain dill file
+it always was.
 """
 
 from __future__ import annotations
 
 import io
+import json
 import os
 import re
 import shutil
+import uuid
 from pathlib import Path
 from typing import Any
 
@@ -35,6 +44,106 @@ def _validate_name(name: str) -> str:
     if not _NAME_RE.match(name or "") or ".." in name:
         raise ValueError(f"invalid artifact name: {name!r}")
     return name
+
+
+#: Largest file an object artifact is written as (see module docstring).
+PART_BYTES = 16 << 20
+#: First bytes of a manifest; a pickle starts with ``\x80``.
+_MANIFEST_MAGIC = b"LO-ARTIFACT-PARTS\n"
+
+
+def max_file_bytes() -> int:
+    """The most one written file may hold: :data:`PART_BYTES`, or the
+    process's file-size limit if that is lower."""
+    try:
+        import resource
+
+        soft, _ = resource.getrlimit(resource.RLIMIT_FSIZE)
+    except (ImportError, OSError, ValueError):
+        return PART_BYTES
+    if soft != resource.RLIM_INFINITY and 0 < soft < PART_BYTES:
+        return soft
+    return PART_BYTES
+
+
+def _parts_dir(path: Path) -> Path:
+    # Leading '.' can never collide with an artifact binary: _NAME_RE
+    # requires names to start with an alphanumeric.
+    return path.with_name("." + path.name + ".parts")
+
+
+def _manifest_of(path: Path) -> dict | None:
+    """The manifest at ``path``, or None where it is a plain file."""
+    with open(path, "rb") as fh:
+        if fh.read(len(_MANIFEST_MAGIC)) != _MANIFEST_MAGIC:
+            return None
+        return json.loads(fh.read())
+
+
+class _PartWriter:
+    """The file ``dill.dump`` writes to: rolls over to a new part file
+    ``<gen>-<i>`` in ``directory`` every ``part_bytes``."""
+
+    def __init__(self, directory: Path, gen: str, part_bytes: int):
+        self.directory, self.gen, self.part_bytes = directory, gen, part_bytes
+        self.parts: list[Path] = []
+        self._fh = None
+        self._room = 0
+
+    def write(self, data) -> int:
+        view = memoryview(data).cast("B")
+        done = 0
+        while done < len(view):
+            if not self._room:
+                self.close()
+                self.directory.mkdir(exist_ok=True)
+                self.parts.append(
+                    self.directory / f"{self.gen}-{len(self.parts):05d}"
+                )
+                self._fh = open(self.parts[-1], "wb")
+                self._room = self.part_bytes
+            chunk = view[done:done + self._room]
+            self._fh.write(chunk)
+            self._room -= len(chunk)
+            done += len(chunk)
+        return done
+
+    def close(self) -> None:
+        if self._fh is not None:
+            self._fh.close()
+            self._fh = None
+
+
+class _PartReader(io.RawIOBase):
+    """The part files of one manifest as one stream, opened one at a
+    time."""
+
+    def __init__(self, paths: list[Path]):
+        self._paths = iter(paths)
+        self._fh = None
+
+    def readable(self) -> bool:
+        return True
+
+    def readinto(self, buf) -> int:
+        while True:
+            if self._fh is None:
+                path = next(self._paths, None)
+                if path is None:
+                    return 0
+                self._fh = open(path, "rb", buffering=0)
+            n = self._fh.readinto(buf)
+            if n:
+                return n
+            self._fh.close()
+            self._fh = None
+
+    def close(self) -> None:
+        if self._fh is not None:
+            self._fh.close()
+            self._fh = None
+        super().close()
+
 
 # Service-type → volume directory, mirroring the reference's six named
 # volumes (binary_executor_image/Dockerfile:10-13, docker-compose.yml:355-363).
@@ -68,6 +177,7 @@ def volume_key_for_type(artifact_type: str) -> str:
 class VolumeStorage:
     def __init__(self, root: str | Path):
         self.root = Path(root).expanduser()
+        self.part_bytes = max_file_bytes()
         for key in VOLUME_KEYS:
             (self.root / key).mkdir(parents=True, exist_ok=True)
 
@@ -81,24 +191,65 @@ class VolumeStorage:
     def save_object(self, artifact_type: str, name: str, obj: Any) -> Path:
         return self._dump_atomic(self.path_for(artifact_type, name), obj)
 
-    @staticmethod
-    def _dump_atomic(path: Path, obj: Any) -> Path:
-        """tmp + rename publish: a PATCH re-run rewriting a binary
-        while a concurrent job dill-loads it must never expose a torn
-        file (same discipline as the shard writer's os.replace)."""
+    def _dump_atomic(self, path: Path, obj: Any) -> Path:
+        """Publish by rename: a PATCH re-run rewriting a binary while a
+        concurrent job dill-loads it must never expose a torn file
+        (same discipline as the shard writer's os.replace).  The parts
+        are complete before the one rename that makes them the
+        artifact — of the only part where the object fits one, of the
+        manifest otherwise — and the parts it supersedes go after."""
         path.parent.mkdir(parents=True, exist_ok=True)
-        # Leading '.' can never collide with an artifact binary:
-        # _NAME_RE requires names to start with an alphanumeric.
-        tmp = path.with_name("." + path.name + ".tmp")
-        with open(tmp, "wb") as fh:
-            dill.dump(obj, fh)
-        os.replace(tmp, path)
+        directory = _parts_dir(path)
+        old = _manifest_of(path) if path.is_file() else None
+        writer = _PartWriter(directory, uuid.uuid4().hex, self.part_bytes)
+        try:
+            try:
+                dill.dump(obj, writer)
+            finally:
+                writer.close()
+            if len(writer.parts) == 1:
+                os.replace(writer.parts[0], path)
+            else:
+                tmp = directory / f"{writer.gen}-manifest"
+                tmp.write_bytes(_MANIFEST_MAGIC + json.dumps({
+                    "gen": writer.gen, "parts": len(writer.parts),
+                }).encode())
+                os.replace(tmp, path)
+        except BaseException:
+            for part in writer.parts:
+                part.unlink(missing_ok=True)
+            raise
+        if old is not None:
+            for i in range(old["parts"]):
+                (directory / f"{old['gen']}-{i:05d}").unlink(missing_ok=True)
+        try:
+            directory.rmdir()  # nothing left in it: a one-part artifact
+        except OSError:
+            pass
         return path
 
     def read_object(self, artifact_type: str, name: str) -> Any:
         path = self.path_for(artifact_type, name)
-        with open(path, "rb") as fh:
-            return dill.load(fh)
+        directory = _parts_dir(path)
+        for attempt in range(3):
+            with open(path, "rb") as fh:
+                if fh.read(len(_MANIFEST_MAGIC)) != _MANIFEST_MAGIC:
+                    fh.seek(0)
+                    return dill.load(fh)
+                manifest = json.loads(fh.read())
+            stream = io.BufferedReader(_PartReader([
+                directory / f"{manifest['gen']}-{i:05d}"
+                for i in range(manifest["parts"])
+            ]), buffer_size=1 << 20)
+            try:
+                return dill.load(stream)
+            except FileNotFoundError:
+                # A re-run published a new generation and removed this
+                # one's parts mid-read: load what it published.
+                if attempt == 2 or _manifest_of(path) == manifest:
+                    raise
+            finally:
+                stream.close()
 
     # -- pytree checkpoints (TPU-native model persistence) --------------------
 
@@ -142,7 +293,11 @@ class VolumeStorage:
         return self.path_for(artifact_type, name).exists()
 
     def delete(self, artifact_type: str, name: str) -> bool:
-        path = self.path_for(artifact_type, name)
+        return self._remove(self.path_for(artifact_type, name))
+
+    @staticmethod
+    def _remove(path: Path) -> bool:
+        shutil.rmtree(_parts_dir(path), ignore_errors=True)
         if path.is_dir():
             shutil.rmtree(path)
             return True
@@ -154,13 +309,5 @@ class VolumeStorage:
     def delete_everywhere(self, name: str) -> bool:
         """Remove a named binary from whichever volume holds it."""
         _validate_name(name)
-        hit = False
-        for key in VOLUME_KEYS:
-            path = self.root / key / name
-            if path.is_dir():
-                shutil.rmtree(path)
-                hit = True
-            elif path.exists():
-                path.unlink()
-                hit = True
-        return hit
+        hits = [self._remove(self.root / key / name) for key in VOLUME_KEYS]
+        return any(hits)

@@ -48,6 +48,30 @@ def _checkpointer():
     return ocp.StandardCheckpointer()
 
 
+def _save_args(state: dict):
+    """orbax save arguments that keep every file under the size the
+    volumes split artifacts at (store/volumes.py ``max_file_bytes``):
+    orbax's own default packs a step into data files of up to 2 GB,
+    and a host's file-size limit fails that write with EFBIG.  A data
+    file closes once it reaches the target and a chunk is at most as
+    large again, so no file passes twice ``half``.  ``StandardSave``
+    cannot carry the target, hence the PyTree handler; what it writes
+    is what ``StandardCheckpointer`` restores."""
+    import jax
+    import orbax.checkpoint as ocp
+
+    from learningorchestra_tpu.store.volumes import max_file_bytes
+
+    half = max_file_bytes() // 2
+    return ocp.args.PyTreeSave(
+        state,
+        save_args=jax.tree.map(
+            lambda _: ocp.SaveArgs(chunk_byte_size=half), state
+        ),
+        ocdbt_target_data_file_size=half,
+    )
+
+
 def _is_primary() -> bool:
     import jax
 
@@ -178,13 +202,13 @@ def save(directory: str | Path, step: int, state: dict,
                 _publish(directory, p_step, p_history)
             if slot.ckpt is None:
                 slot.ckpt = ocp.AsyncCheckpointer(
-                    ocp.StandardCheckpointHandler()
+                    ocp.PyTreeCheckpointHandler()
                 )
             directory.mkdir(parents=True, exist_ok=True)
             path = directory / f"step_{step}"
             if path.exists():
                 shutil.rmtree(path)
-            slot.ckpt.save(path, args=ocp.args.StandardSave(state))
+            slot.ckpt.save(path, args=_save_args(state))
             slot.pending = (step, history)
         return path
     # Sync path: flush any pending ASYNC save to this directory first —
@@ -198,8 +222,10 @@ def save(directory: str | Path, step: int, state: dict,
             shutil.rmtree(path)
     path = directory / f"step_{step}"
     _barrier(f"ckpt-pre-{step}")
-    with _checkpointer() as ck:
-        ck.save(path, state)
+    import orbax.checkpoint as ocp
+
+    with ocp.Checkpointer(ocp.PyTreeCheckpointHandler()) as ck:
+        ck.save(path, args=_save_args(state))
     # StandardCheckpointer.save commits (atomic rename) before returning,
     # on every process, so the marker write below cannot race the data.
     if _is_primary():

@@ -109,3 +109,72 @@ def test_volume_roundtrip(volumes):
     assert volumes.exists("model/scikitlearn", "m1")
     assert volumes.delete("model/scikitlearn", "m1")
     assert not volumes.exists("model/scikitlearn", "m1")
+
+
+def test_large_object_is_written_as_parts(volumes, monkeypatch):
+    """An object bigger than the part size never becomes one big file
+    (a host's file-size limit fails that write with EFBIG): the
+    artifact's path holds a manifest, the bytes sit in part files, and
+    a rewrite or a delete takes the superseded parts with it."""
+    import numpy as np
+
+    volumes.part_bytes = 1000
+    big = {"w": np.arange(5000, dtype=np.float32), "tag": "x" * 10}
+    path = volumes.save_object("train/tensorflow", "t1", big)
+    parts = path.with_name(".t1.parts")
+    assert path.is_file() and len(list(parts.iterdir())) > 20
+    assert max(p.stat().st_size for p in parts.iterdir()) <= 1000
+    back = volumes.read_object("train/tensorflow", "t1")
+    assert np.array_equal(back["w"], big["w"]) and back["tag"] == big["tag"]
+    assert volumes.exists("train/tensorflow", "t1")
+
+    # Rewritten larger: only the new generation's parts remain.
+    volumes.save_object("train/tensorflow", "t1", {"w": np.arange(9000)})
+    names = {p.name.split("-")[0] for p in parts.iterdir()}
+    assert len(names) == 1
+    assert np.array_equal(
+        volumes.read_object("train/tensorflow", "t1")["w"], np.arange(9000)
+    )
+    # Rewritten to fit one part: the plain dill file it always was.
+    volumes.save_object("train/tensorflow", "t1", {"k": 1})
+    assert not parts.exists()
+    assert volumes.read_object("train/tensorflow", "t1") == {"k": 1}
+
+    # A reader that loses its parts to a concurrent rewrite (here: at
+    # its first read) loads what the rewrite published instead.
+    from learningorchestra_tpu.store import volumes as volumes_mod
+
+    volumes.save_object("train/tensorflow", "t1", big)
+    readinto = volumes_mod._PartReader.readinto
+    rewrites = []
+
+    def racing_readinto(self, buf):
+        if not rewrites:
+            rewrites.append(volumes.save_object(
+                "train/tensorflow", "t1", {"w": np.arange(7000)}
+            ))
+        return readinto(self, buf)
+
+    monkeypatch.setattr(volumes_mod._PartReader, "readinto", racing_readinto)
+    assert np.array_equal(
+        volumes.read_object("train/tensorflow", "t1")["w"], np.arange(7000)
+    )
+
+    assert volumes.delete_everywhere("t1")
+    assert list(path.parent.iterdir()) == []
+
+
+def test_part_size_yields_to_the_file_size_limit():
+    import resource
+
+    from learningorchestra_tpu.store.volumes import PART_BYTES, max_file_bytes
+
+    soft, hard = resource.getrlimit(resource.RLIMIT_FSIZE)
+    limit = PART_BYTES // 4
+    resource.setrlimit(resource.RLIMIT_FSIZE, (limit, hard))
+    try:
+        assert max_file_bytes() == limit
+        resource.setrlimit(resource.RLIMIT_FSIZE, (PART_BYTES * 4, hard))
+        assert max_file_bytes() == PART_BYTES
+    finally:
+        resource.setrlimit(resource.RLIMIT_FSIZE, (soft, hard))

@@ -4,8 +4,10 @@ field or a shape bug is found here and not on chip time — plus the
 contract that ``main()`` refuses to run without a TPU."""
 
 import json
+import resource
 
 import jax
+import pytest
 
 import chip_smoke
 from learningorchestra_tpu.jobs.leases import DeviceLeaser
@@ -30,7 +32,20 @@ TINY_DECODE = dict(
 )
 
 
-def test_every_phase_over_rest_on_virtual_devices(tmp_path, capsys):
+@pytest.fixture
+def file_size_limit():
+    """A process file-size limit (``ulimit -f``) below the size of the
+    tiny models' train artifacts (~0.4 MB with the Adam state), as the
+    machine that checks chip_smoke.py has one below BERT-base's 1.3 GB:
+    a write past it fails with EFBIG."""
+    soft, hard = resource.getrlimit(resource.RLIMIT_FSIZE)
+    resource.setrlimit(resource.RLIMIT_FSIZE, (128 << 10, hard))
+    yield 128 << 10
+    resource.setrlimit(resource.RLIMIT_FSIZE, (soft, hard))
+
+
+def test_every_phase_over_rest_on_virtual_devices(tmp_path, capsys,
+                                                  file_size_limit):
     """``run`` as ``main`` calls it, on the 8 virtual CPU devices (so
     the multi-chip branch runs too), leasing them so the lease and
     placement checks apply here as on the chip."""
@@ -46,6 +61,13 @@ def test_every_phase_over_rest_on_virtual_devices(tmp_path, capsys):
     ]
     out = {line["phase"]: line for line in lines}
     assert list(out) == ["boot", "bert", "decode", "multichip"]
+    # The limit bit: the fit's artifact did not fit one file.
+    parts = tmp_path / "volumes" / "binaries" / ".bert_fit.parts"
+    assert len(list(parts.iterdir())) > 1
+    assert all(
+        p.stat().st_size <= file_size_limit for p in tmp_path.rglob("*")
+        if p.is_file()
+    )
 
     bert = out["bert"]
     assert len(bert["losses"]) == 2

@@ -820,6 +820,40 @@ def test_async_checkpointing_contract(tmp_path):
     )["step"] == 10
 
 
+@pytest.mark.parametrize("async_save", [False, True])
+def test_checkpoint_files_stay_under_the_file_size_limit(
+        tmp_path, async_save):
+    """Under a process file-size limit (``ulimit -f``) smaller than the
+    state, a save still commits — orbax's default of one data file of
+    up to 2 GB fails there with EFBIG — every file it wrote is under
+    the limit, and the step restores bit-identical."""
+    import resource
+
+    import optax
+
+    from learningorchestra_tpu.train import checkpoint as ckpt
+
+    limit = 1 << 20
+    rng = np.random.default_rng(0)  # incompressible: orbax compresses
+    params = {"w": rng.standard_normal((700, 1000)).astype(np.float32),
+              "b": np.zeros(3, np.float32)}
+    state = {"params": params, "opt_state": optax.adam(1e-3).init(params)}
+    soft, hard = resource.getrlimit(resource.RLIMIT_FSIZE)
+    resource.setrlimit(resource.RLIMIT_FSIZE, (limit, hard))
+    try:
+        ckpt.save(tmp_path, 1, state, history={"loss": [0.5]},
+                  async_save=async_save)
+        loaded = ckpt.load_latest(tmp_path, state)
+    finally:
+        resource.setrlimit(resource.RLIMIT_FSIZE, (soft, hard))
+    sizes = [p.stat().st_size for p in tmp_path.rglob("*") if p.is_file()]
+    assert sum(sizes) > 2 * limit and max(sizes) <= limit
+    restored, step, _ = loaded
+    assert step == 1
+    np.testing.assert_array_equal(restored["params"]["w"], params["w"])
+    assert type(restored["opt_state"]) is type(state["opt_state"])
+
+
 class TestOptimizerAndScheduleSpecs:
     """REST-JSON optimizer/learning-rate specs (train/neural.py
     resolve_optimizer / resolve_learning_rate) — the declarative form
