@@ -28,6 +28,8 @@ import ast
 import re
 from typing import Any, Callable, Protocol
 
+from learningorchestra_tpu.obs import tracing as obs_tracing
+
 _DOLLAR_RE = re.compile(r"^\$(?P<name>[A-Za-z0-9_.\-]+)$")
 
 # The ``#`` grammar is expressions built from calls, attributes, names,
@@ -202,10 +204,13 @@ def resolve_params(
 ) -> dict:
     if not params:
         return {}
-    return {
-        k: resolve_value(v, loader, spec_namespace)
-        for k, v in params.items()
-    }
+    # ``$name`` references read datasets and artifacts back: seconds
+    # for a large one, so the job's trace names them.
+    with obs_tracing.span("resolve_params"):
+        return {
+            k: resolve_value(v, loader, spec_namespace)
+            for k, v in params.items()
+        }
 
 
 def _index(instance: Any, key: str) -> Any:

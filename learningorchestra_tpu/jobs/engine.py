@@ -699,25 +699,33 @@ class JobEngine:
                         # Stale-epoch straggler racing a newer
                         # recovery: its completion must not publish.
                         return None
-                    extra = on_success(result) if on_success else None
-                    logger.info(
-                        kv(job=name, state="finished",
-                           dt=f"{time.monotonic() - t_start:.2f}s",
-                           **req)
-                    )
-                    if self.journal is not None:
-                        # Epoch stamp on metadata finalization: which
-                        # engine life committed this artifact —
-                        # readable from the ordinary GET/poll path.
-                        extra = {
-                            **(extra or {}),
-                            "engineEpoch": jobs_journal.current_stamp(),
-                        }
-                    self._journal(name, "finished")
-                    meta.mark_finished(name, extra or None)
-                    jobs_total.inc(
-                        job_class=job_class, state="finished"
-                    )
+                    # ``commit``: from the body's return to the writes
+                    # that make the job ``finished`` for a poller.  The
+                    # ledger record below persists the trace itself, so
+                    # the span ends before it.
+                    with tracing.span("commit"):
+                        extra = on_success(result) if on_success \
+                            else None
+                        logger.info(
+                            kv(job=name, state="finished",
+                               dt=f"{time.monotonic() - t_start:.2f}s",
+                               **req)
+                        )
+                        if self.journal is not None:
+                            # Epoch stamp on metadata finalization:
+                            # which engine life committed this artifact
+                            # — readable from the ordinary GET/poll
+                            # path.
+                            extra = {
+                                **(extra or {}),
+                                "engineEpoch":
+                                    jobs_journal.current_stamp(),
+                            }
+                        self._journal(name, "finished")
+                        meta.mark_finished(name, extra or None)
+                        jobs_total.inc(
+                            job_class=job_class, state="finished"
+                        )
                     ledger.record(
                         name,
                         description=description,

@@ -147,34 +147,39 @@ class DeviceLeaser:
         placement-timeout semantics).
         """
         t_req = time.monotonic()
-        # Chaos probe: an armed schedule can delay every lease request
-        # (contention drills) or fail it outright — the injected error
-        # flows to the job body exactly as a real placement failure.
-        faults.hit("lease.acquire")
-        with self._cv:
-            self._ensure_devices()
-            if not self._all:
-                taken: list[str] = []
-            else:
-                want = len(self._all) if n_devices <= 0 else min(
-                    n_devices, len(self._all)
-                )
-                deadline = (
-                    None if timeout is None
-                    else time.monotonic() + timeout
-                )
-                while len(self._free) < want:
-                    if deadline is None:
-                        self._cv.wait()
-                        continue
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        raise LeaseTimeout(
-                            f"no {want}-device lease within {timeout}s "
-                            f"(job {label!r})"
-                        )
-                    self._cv.wait(remaining)
-                taken = [self._free.pop() for _ in range(want)]
+        # ``lease_wait``: the queue for a free chip (and device
+        # discovery, the first time); the ``lease`` span below covers
+        # the hold.
+        with tracing.span("lease_wait"):
+            # Chaos probe: an armed schedule can delay every lease
+            # request (contention drills) or fail it outright — the
+            # injected error flows to the job body exactly as a real
+            # placement failure.
+            faults.hit("lease.acquire")
+            with self._cv:
+                self._ensure_devices()
+                if not self._all:
+                    taken: list[str] = []
+                else:
+                    want = len(self._all) if n_devices <= 0 else min(
+                        n_devices, len(self._all)
+                    )
+                    deadline = (
+                        None if timeout is None
+                        else time.monotonic() + timeout
+                    )
+                    while len(self._free) < want:
+                        if deadline is None:
+                            self._cv.wait()
+                            continue
+                        remaining = deadline - time.monotonic()
+                        if remaining <= 0:
+                            raise LeaseTimeout(
+                                f"no {want}-device lease within "
+                                f"{timeout}s (job {label!r})"
+                            )
+                        self._cv.wait(remaining)
+                    taken = [self._free.pop() for _ in range(want)]
         t0 = time.monotonic()
         rec = {"label": label, "devices": list(taken),
                "revoked": set()}

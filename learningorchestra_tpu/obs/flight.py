@@ -9,7 +9,9 @@ bounded, lock-cheap per-domain event rings recording
 - ``http``    — one event per completed request (route, status,
   latency, request id);
 - ``decode``  — per-stream lifecycle on the streaming LM engine
-  (admit, pool grow, TTFT, abort, step errors);
+  (admit with its wait, pool grow, TTFT, abort, step errors) and
+  ``slow_step``: a turn of the worker's loop over 0.5 s, with its
+  seconds by phase;
 - ``jobs``    — engine dispatch / preempt-retry / fence / terminal
   decisions;
 - ``compile`` — compiled-program builds and AOT restores;

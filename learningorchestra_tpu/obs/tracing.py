@@ -20,9 +20,16 @@ with the right parent without any of those layers knowing about HTTP.
 Span timestamps anchor to ONE (wall, monotonic) pair captured at trace
 creation: durations are monotonic-accurate, wall times are readable.
 
-Everything here is a no-op when the registry is disabled
+The span bookkeeping is a no-op when the registry is disabled
 (``LO_TPU_OBS_ENABLED=0``) or tracing is off (``LO_TPU_OBS_TRACE=0``);
 the fast path out is a single context-variable read.
+
+Every :func:`span` and every :class:`Phases` block is ALSO a
+``jax.profiler.TraceAnnotation`` named ``lo:<name>``, whether or not a
+job trace is active: a profiler capture (``POST
+/observability/profile/start``, a benchmark's traced run) then shows
+the program's own intervals in its host plane, on the device trace's
+clock.  Outside a profiler session an annotation is a flag check.
 """
 
 from __future__ import annotations
@@ -35,7 +42,10 @@ import uuid
 from learningorchestra_tpu.concurrency_rt import make_lock
 
 __all__ = [
+    "ANNOTATION_PREFIX",
     "JobTrace",
+    "Phases",
+    "annotation",
     "current_trace",
     "get_request_id",
     "new_request_id",
@@ -44,6 +54,7 @@ __all__ = [
     "set_request_id",
     "reset_request_id",
     "sampled",
+    "set_span_attrs",
     "span",
     "span_tree",
     "activate",
@@ -58,6 +69,25 @@ _TRACE: contextvars.ContextVar = contextvars.ContextVar(
 _SPAN: contextvars.ContextVar = contextvars.ContextVar(
     "lo_span", default=None
 )
+
+
+#: What every program interval is called in a profiler trace.
+ANNOTATION_PREFIX = "lo:"
+
+_trace_annotation = None  # jax.profiler.TraceAnnotation, on first use
+
+
+def annotation(name: str, **metadata):
+    """A ``jax.profiler.TraceAnnotation`` named ``lo:<name>`` (a context
+    manager; ``metadata`` rides as the event's stats, and
+    ``set_metadata`` adds more before the block ends)."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        # Not at import: the store-only processes never need jax.
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(ANNOTATION_PREFIX + name, **metadata)
 
 
 # -- request ids --------------------------------------------------------------
@@ -163,6 +193,14 @@ class JobTrace:
             }
             return sid
 
+    def set_attrs(self, sid: int, attrs: dict) -> None:
+        """Merge ``attrs`` into an open or ended span's (what a block
+        only knows at its end: an epoch's measured cost)."""
+        with self._lock:
+            rec = self._spans.get(sid)
+            if rec is not None:
+                rec["attrs"].update(attrs)
+
     def to_doc(self) -> dict:
         """JSON-safe record for the execution ledger.  Unfinished
         spans (a crash mid-interval) keep ``end: None`` — visibly
@@ -235,20 +273,31 @@ def activate(trace: JobTrace | None, root_span: int | None = None):
 
 @contextlib.contextmanager
 def span(name: str, **attrs):
-    """Record the with-block as a named span on the current trace (a
-    no-op when none is active).  Spans opened inside nest under it."""
-    trace = _TRACE.get()
-    if trace is None:
-        yield None
-        return
-    sid = trace.begin(name, parent=_SPAN.get(), attrs=attrs)
-    token = _SPAN.set(sid) if sid >= 0 else None
-    try:
-        yield sid
-    finally:
-        if token is not None:
-            _SPAN.reset(token)
-        trace.end(sid)
+    """Record the with-block as a named span on the current trace (no
+    bookkeeping when none is active) and, trace or none, as the
+    profiler annotation ``lo:<name>``.  Spans opened inside nest under
+    it."""
+    with annotation(name):
+        trace = _TRACE.get()
+        if trace is None:
+            yield None
+            return
+        sid = trace.begin(name, parent=_SPAN.get(), attrs=attrs)
+        token = _SPAN.set(sid) if sid >= 0 else None
+        try:
+            yield sid
+        finally:
+            if token is not None:
+                _SPAN.reset(token)
+            trace.end(sid)
+
+
+def set_span_attrs(**attrs) -> None:
+    """Add ``attrs`` to the innermost open :func:`span` of the calling
+    thread (a no-op when no trace is active)."""
+    trace, sid = _TRACE.get(), _SPAN.get()
+    if trace is not None and sid is not None:
+        trace.set_attrs(sid, attrs)
 
 
 def record_span(name: str, duration_s: float, **attrs) -> None:
@@ -263,6 +312,55 @@ def record_span(name: str, duration_s: float, **attrs) -> None:
         name, t1 - max(0.0, float(duration_s)), t1,
         parent=_SPAN.get(), attrs=attrs,
     )
+
+
+class _Phase:
+    """One named phase of a :class:`Phases`: the reusable with-block."""
+
+    __slots__ = ("_owner", "_name", "_label", "_ann", "_t0")
+
+    def __init__(self, owner: "Phases", name: str):
+        self._owner = owner
+        self._name = name
+        self._label = f"{owner.prefix}.{name}"
+        self._ann = None
+        self._t0 = 0.0
+
+    def __enter__(self):
+        self._ann = annotation(self._label)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        owner, name = self._owner, self._name
+        owner.total[name] += dt
+        if dt > owner.peak[name]:
+            owner.peak[name] = dt
+        self._ann.__exit__(*exc)
+        return False
+
+
+class Phases:
+    """Where ONE thread's loop spends its time, for loops that have no
+    job trace (the decode worker): ``with phases("sync"):`` is the
+    profiler annotation ``lo:<prefix>.sync`` and adds the block's
+    seconds to ``total["sync"]`` (and raises ``peak["sync"]``, the
+    longest single block).  One writer, no lock, no registry call and
+    no allocation beyond the annotation; readers on other threads see
+    floats that are each whole.  A phase is not entered inside itself."""
+
+    __slots__ = ("prefix", "total", "peak", "_blocks")
+
+    def __init__(self, prefix: str, names: tuple):
+        self.prefix = prefix
+        self.total = dict.fromkeys(names, 0.0)
+        self.peak = dict.fromkeys(names, 0.0)
+        self._blocks = {name: _Phase(self, name) for name in names}
+
+    def __call__(self, name: str) -> _Phase:
+        return self._blocks[name]
 
 
 def span_tree(spans: list[dict]) -> list[dict]:

@@ -377,7 +377,9 @@ def _k_index_maps(block_q, block_k, window, nk):
 
 
 def _fwd_call(q, k, v, km, block_q, block_k, interpret, causal,
-              window=None):
+              window=None, prefix="flash"):
+    """``prefix`` names the kernel (``flash_fwd``; the ring passes
+    ``ring``): a device trace tells the kernels apart by that name."""
     b, h, tq, d = q.shape
     tk = k.shape[2]
     nq, nk = tq // block_q, tk // block_k
@@ -389,7 +391,8 @@ def _fwd_call(q, k, v, km, block_q, block_k, interpret, causal,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, window=window
     )
-    return pl.pallas_call(
+    name = f"{prefix}_fwd"
+    call = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk_grid),
         in_specs=[
@@ -419,11 +422,14 @@ def _fwd_call(q, k, v, km, block_q, block_k, interpret, causal,
         ],
         compiler_params=_tpu_params(3),
         interpret=interpret,
-    )(q, k, v, km)
+        name=name,
+    )
+    with jax.named_scope(name):
+        return call(q, k, v, km)
 
 
 def _bwd_call(q, k, v, km, do, lse, delta, block_q, block_k, interpret,
-              causal, window=None):
+              causal, window=None, prefix="flash"):
     b, h, tq, d = q.shape
     tk = k.shape[2]
     nq, nk = tq // block_q, tk // block_k
@@ -433,7 +439,7 @@ def _bwd_call(q, k, v, km, do, lse, delta, block_q, block_k, interpret,
     kv_map, mask_map = _k_index_maps(block_q, block_k, window, nk)
     scale = 1.0 / (d ** 0.5)
 
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, scale=scale, causal=causal, window=window
         ),
@@ -462,7 +468,10 @@ def _bwd_call(q, k, v, km, do, lse, delta, block_q, block_k, interpret,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_tpu_params(3),
         interpret=interpret,
-    )(q, k, v, km, do, lse, delta)
+        name=f"{prefix}_dq",
+    )
+    with jax.named_scope(f"{prefix}_dq"):
+        dq = dq_call(q, k, v, km, do, lse, delta)
 
     if window is None:
         nq_grid = nq
@@ -476,7 +485,7 @@ def _bwd_call(q, k, v, km, do, lse, delta, block_q, block_k, interpret,
             i = (j * block_k) // block_q + ii
             return (bb, hh, jnp.clip(i, 0, nq - 1), 0)
 
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, scale=scale, causal=causal, window=window,
             nq_total=nq,
@@ -513,7 +522,10 @@ def _bwd_call(q, k, v, km, do, lse, delta, block_q, block_k, interpret,
         ],
         compiler_params=_tpu_params(3),
         interpret=interpret,
-    )(q, k, v, km, do, lse, delta)
+        name=f"{prefix}_dkv",
+    )
+    with jax.named_scope(f"{prefix}_dkv"):
+        dk, dv = dkv_call(q, k, v, km, do, lse, delta)
     return dq, dk, dv
 
 

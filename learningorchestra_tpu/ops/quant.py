@@ -91,7 +91,7 @@ def quantize_rowwise(
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
     seed_arr = jnp.asarray([seed], jnp.int32)
-    values, scales = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_quantize_kernel, stochastic=stochastic),
         grid=((n + pad) // bn,),
         in_specs=[
@@ -107,7 +107,10 @@ def quantize_rowwise(
             jax.ShapeDtypeStruct((n + pad, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(seed_arr, x)
+        name="quant_rowwise",
+    )
+    with jax.named_scope("quant_rowwise"):
+        values, scales = call(seed_arr, x)
     return (values[:n], scales[:n]) if pad else (values, scales)
 
 
@@ -122,7 +125,7 @@ def dequantize_rowwise(values, scales, *, interpret: bool | None = None):
     if pad:
         values = jnp.pad(values, ((0, pad), (0, 0)))
         scales = jnp.pad(scales, ((0, pad), (0, 0)))
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         _dequantize_kernel,
         grid=((n + pad) // bn,),
         in_specs=[
@@ -132,7 +135,10 @@ def dequantize_rowwise(values, scales, *, interpret: bool | None = None):
         out_specs=pl.BlockSpec((bn, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n + pad, d), jnp.float32),
         interpret=interpret,
-    )(values, scales)
+        name="dequant_rowwise",
+    )
+    with jax.named_scope("dequant_rowwise"):
+        out = call(values, scales)
     return out[:n] if pad else out
 
 

@@ -246,7 +246,9 @@ def _ring_flash_fwd(q, k, v, km, opts):
     b, h, t, d = q.shape
 
     def call(kb, vb, kmb, diag):
-        o, lse = _fwd_call(q, kb, vb, kmb, bq, bk, interpret, diag)
+        o, lse = _fwd_call(
+            q, kb, vb, kmb, bq, bk, interpret, diag, prefix="ring"
+        )
         return o.astype(jnp.float32), _kernel_lse_to_merge(lse)
 
     def skip(kb, vb, kmb):
@@ -301,7 +303,8 @@ def _ring_flash_core_bwd(opts, res, g):
 
     def call(kb, vb, kmb, diag):
         dq, dk, dv = _bwd_call(
-            q, kb, vb, kmb, do, lse, delta, bq, bk, interpret, diag
+            q, kb, vb, kmb, do, lse, delta, bq, bk, interpret, diag,
+            prefix="ring",
         )
         return (
             dq.astype(jnp.float32),

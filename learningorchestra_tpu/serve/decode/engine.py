@@ -26,6 +26,7 @@ import numpy as np
 from learningorchestra_tpu.concurrency_rt import make_condition, make_lock
 from learningorchestra_tpu.log import get_logger, kv
 from learningorchestra_tpu.obs import flight as obs_flight
+from learningorchestra_tpu.obs import tracing as obs_tracing
 from learningorchestra_tpu.obs.metrics import get_registry
 from learningorchestra_tpu.serve.batcher import QueueFull
 from learningorchestra_tpu.serve.bucketing import bucket_for
@@ -41,6 +42,18 @@ _NONSTREAM_TIMEOUT_S = 300.0
 #: Lazy (non-SSE) pools sync to host every this-many steps so async
 #: dispatch cannot run unboundedly ahead of the device.
 _SYNC_STRIDE = 32
+
+#: What one turn of the worker's loop is made of, in order: ``admit``
+#: (the loop top: pending streams, admission, the abort sweep),
+#: ``dispatch`` (the step's host arrays and the call that enqueues it),
+#: ``sync`` (reading the step's tokens, and finished rows, back),
+#: ``emit`` (tokens to their streams, histograms, the devtime ledger);
+#: ``wait`` is the worker parked with nothing live, outside any turn.
+_PHASES = ("admit", "dispatch", "sync", "emit", "wait")
+
+#: A turn longer than this leaves a ``slow_step`` flight event with its
+#: phase split: an untraced run that stalls says where the loop stood.
+_SLOW_STEP_S = 0.5
 
 
 class _DecodeHists:
@@ -123,6 +136,17 @@ class _ModelDecoder:
         self._thread: threading.Thread | None = None
         self._closed = False
         self.steps = 0
+        # Written by the worker thread alone, read by stats().
+        self.phases = obs_tracing.Phases("decode", _PHASES)
+        self.prompt_steps = 0  # slot-steps that consumed a prompt token
+        self.output_steps = 0  # slot-steps that produced an output token
+        self.keys_attended = 0
+        self.admitted = 0
+        self.admit_wait_s = 0.0
+        # One turn's share of the three counters above and what it
+        # stepped: the ``lo:decode.step`` annotation's metadata.
+        self._turn = {"prompt": 0, "output": 0, "keys": 0, "slots": 0,
+                      "kv": 0}
 
     # -- submission (any thread) --------------------------------------------
 
@@ -180,14 +204,16 @@ class _ModelDecoder:
     def _any_live(self) -> bool:
         return any(p.live for p in self._pools.values())
 
-    def _run(self) -> None:
-        idle_since: float | None = None
-        while True:
-            with self._cv:
-                while (not self._closed and not self._pending
-                       and not self._any_live()):
-                    if idle_since is None:
-                        idle_since = time.monotonic()
+    def _park(self) -> bool:
+        """Wait (phase ``wait``) until there is a stream to serve or
+        the decoder closes.  False when the worker idled past the knob
+        and has stood down: the next submit starts another."""
+        with self._cv:
+            if self._closed or self._pending:
+                return True
+            with self.phases("wait"):
+                idle_since = time.monotonic()
+                while not self._closed and not self._pending:
                     waited = time.monotonic() - idle_since
                     if waited >= self.cfg.idle_timeout_s:
                         # Idle past the knob: free the resident pools
@@ -196,45 +222,77 @@ class _ModelDecoder:
                         self._pools.clear()
                         self._step_state.clear()
                         self._thread = None
-                        return
+                        return False
                     self._cv.wait(
                         timeout=self.cfg.idle_timeout_s - waited
                     )
-                if self._closed:
-                    pending = list(self._pending)
-                    self._pending.clear()
-                    pools = list(self._pools.values())
-                    self._pools.clear()
-                    self._thread = None
-                    break
-                idle_since = None
-                pending = list(self._pending)
-                self._pending.clear()
-            deferred = []
-            for stream in pending:
-                try:
-                    admitted = self._admit(stream)
-                except Exception as exc:  # noqa: BLE001 — a bug in
-                    # admission (or its error handler) costs ONE
-                    # stream, never the model's worker thread: an
-                    # unfinished stream here would stall every
-                    # in-flight SSE client on a no_timeout route.
-                    logger.error("decode admit raised %s", kv(
-                        model=self.name, stream=stream.stream_id,
-                        error=str(exc),
-                    ))
-                    self._finish(
-                        stream, error=f"admission failed: {exc}"
-                    )
-                    continue
-                if not admitted:
-                    deferred.append(stream)
-            self._step_all()
-            if deferred:
-                with self._cv:
-                    # Back to the FRONT: arrival order is admission
-                    # order once capacity frees up.
-                    self._pending.extendleft(reversed(deferred))
+        return True
+
+    def _run(self) -> None:
+        phases = self.phases
+        while True:
+            # Pools are this thread's own: nothing live is read without
+            # the lock, and only then is there anything to wait for.
+            if not self._any_live() and not self._park():
+                return
+            t_turn = time.perf_counter()
+            before = dict(phases.total)
+            turn = self._turn
+            for key in turn:
+                turn[key] = 0
+            with obs_tracing.annotation("decode.step") as step_ann:
+                with phases("admit"):
+                    with self._cv:
+                        if self._closed:
+                            pending = list(self._pending)
+                            self._pending.clear()
+                            pools = list(self._pools.values())
+                            self._pools.clear()
+                            self._thread = None
+                            break
+                        pending = list(self._pending)
+                        self._pending.clear()
+                    deferred = []
+                    for stream in pending:
+                        try:
+                            admitted = self._admit(stream)
+                        except Exception as exc:  # noqa: BLE001 — a
+                            # bug in admission (or its error handler)
+                            # costs ONE stream, never the model's
+                            # worker thread: an unfinished stream here
+                            # would stall every in-flight SSE client on
+                            # a no_timeout route.
+                            logger.error("decode admit raised %s", kv(
+                                model=self.name, stream=stream.stream_id,
+                                error=str(exc),
+                            ))
+                            self._finish(
+                                stream, error=f"admission failed: {exc}"
+                            )
+                            continue
+                        if not admitted:
+                            deferred.append(stream)
+                self._step_all()
+                if deferred:
+                    with phases("admit"), self._cv:
+                        # Back to the FRONT: arrival order is admission
+                        # order once capacity frees up.
+                        self._pending.extendleft(reversed(deferred))
+                step_ann.set_metadata(**turn)
+            turn_s = time.perf_counter() - t_turn
+            if turn_s > _SLOW_STEP_S:
+                split = {
+                    name: round(phases.total[name] - before[name], 4)
+                    for name in _PHASES if name != "wait"
+                }
+                logger.warning("decode slow step %s", kv(
+                    model=self.name, turnS=round(turn_s, 4), **split,
+                ))
+                obs_flight.record(
+                    "decode", "slow_step",
+                    model=self.name, turnS=round(turn_s, 4),
+                    phaseS=split, step=self.steps,
+                )
         # closed: fail whatever never got (or was mid) service.
         for stream in pending:
             stream.fail("decode engine shut down")
@@ -311,11 +369,17 @@ class _ModelDecoder:
             self._finish(stream, error=f"admission failed: {exc}")
             return True
         if slot is not None:
-            obs_flight.record(
-                "decode", "admit",
-                model=self.name, stream=stream.stream_id,
-                kv=kvlen, slot=slot,
-            )
+            wait_s = time.perf_counter() - stream.arrived
+            self.admitted += 1
+            self.admit_wait_s += wait_s
+            with obs_tracing.annotation(
+                "decode.seat", wait_ms=round(wait_s * 1e3, 3)
+            ):
+                obs_flight.record(
+                    "decode", "admit",
+                    model=self.name, stream=stream.stream_id,
+                    kv=kvlen, slot=slot, waitS=round(wait_s, 4),
+                )
         return slot is not None
 
     def _max_len(self) -> int:
@@ -377,21 +441,19 @@ class _ModelDecoder:
         return entry.params
 
     def _step_all(self) -> None:
-        from learningorchestra_tpu import faults
-
         for key in list(self._pools):
             pool = self._pools[key]
             # Abort sweep FIRST: a cancelled stream's pages are freed
             # within one step boundary of the cancel, even if the
             # step itself then faults.
-            for slot, stream in enumerate(pool.streams):
-                if stream is not None and stream.token.cancelled():
-                    pool.release(slot)
-                    self._finish(stream, aborted=True)
+            with self.phases("admit"):
+                for slot, stream in enumerate(pool.streams):
+                    if stream is not None and stream.token.cancelled():
+                        pool.release(slot)
+                        self._finish(stream, aborted=True)
             if not pool.live:
                 continue
             try:
-                faults.hit("serve.decode_step")
                 self._step_pool(pool)
             except Exception as exc:  # noqa: BLE001 — chaos/device
                 # Blast radius = this pool's in-flight streams (the
@@ -414,93 +476,128 @@ class _ModelDecoder:
     def _step_pool(self, pool: PagePool) -> None:
         import jax.numpy as jnp
 
+        from learningorchestra_tpu import faults
         from learningorchestra_tpu.obs import costs as obs_costs
 
-        step, _ = self._step_for(pool.nslots, pool.kv)
-        live = np.array(
-            [s is not None for s in pool.streams], bool
-        )
-        t0s = np.array(
-            [s.t0 if s is not None else pool.kv + 1
-             for s in pool.streams],
-            np.int32,
-        )
-        eager = any(
-            s is not None and s.eager for s in pool.streams
-        )
-        # ``pool.pos`` is host state mutated in place right after this
-        # dispatch; jax's CPU backend may alias numpy buffers
-        # zero-copy, so a lazily-executed step would read positions
-        # from the FUTURE once the host loop runs ahead of the device
-        # (e.g. behind a bucket-grow compile).  Snapshot per dispatch —
-        # ``t0s``/``live`` above are already fresh per-call arrays.
-        pos_now = pool.pos.copy()
-        t_start = time.perf_counter()
-        pool.cache, pool.buf, col = step(
-            self._params_for(pool), pool.cache, pool.buf,
-            jnp.asarray(pos_now), jnp.asarray(t0s),
-            jnp.asarray(live),
-        )
-        pool.steps += 1
-        self.steps += 1
-        col_host = None
-        if eager or pool.steps % _SYNC_STRIDE == 0:
-            # SSE wants the token NOW; lazy pools sync on a stride so
-            # async dispatch pipelines the loop like the solo scan.
-            col_host = np.asarray(col)
-        now = time.perf_counter()
-        synced = col_host is not None
-        for slot, stream in enumerate(pool.streams):
-            if stream is None:
-                continue
-            nxt_pos = int(pool.pos[slot]) + 1
-            pool.pos[slot] = nxt_pos
-            if nxt_pos >= stream.t0 and col_host is not None \
-                    and stream.eager:
-                self._emit(stream, int(col_host[slot]), nxt_pos, now)
-            if nxt_pos >= stream.total - 1:
-                # Terminal: the full row (prompt + continuation) is in
-                # the buffer; lazy streams surface everything here.
-                row = np.asarray(pool.buf[slot])
-                synced = True
-                if not stream.eager:
-                    stream.tokens = [
-                        int(t) for t in row[stream.t0: stream.total]
-                    ]
-                    stream.first_at = stream.first_at or now
-                    _decode_hists.ttft(
-                        stream.first_at - stream.arrived, self.name
+        phases = self.phases
+        with phases("dispatch"):
+            # The chaos probe stands where the step is dispatched: a
+            # delay armed on it reads as dispatch time.
+            faults.hit("serve.decode_step")
+            step, _ = self._step_for(pool.nslots, pool.kv)
+            live = np.array(
+                [s is not None for s in pool.streams], bool
+            )
+            t0s = np.array(
+                [s.t0 if s is not None else pool.kv + 1
+                 for s in pool.streams],
+                np.int32,
+            )
+            eager = any(
+                s is not None and s.eager for s in pool.streams
+            )
+            # ``pool.pos`` is host state mutated in place right after
+            # this dispatch; jax's CPU backend may alias numpy buffers
+            # zero-copy, so a lazily-executed step would read positions
+            # from the FUTURE once the host loop runs ahead of the
+            # device (e.g. behind a bucket-grow compile).  Snapshot per
+            # dispatch — ``t0s``/``live`` above are already fresh
+            # per-call arrays.
+            pos_now = pool.pos.copy()
+            # What this step does, slot by slot: a live slot whose next
+            # position is still inside its prompt consumes a prompt
+            # token (prefill, one token a step), any other live slot
+            # produces an output token; each attends over the keys up
+            # to and with its own position.
+            nxt = pos_now + 1
+            n_live = int(live.sum())
+            n_prompt = int((live & (nxt < t0s)).sum())
+            n_keys = int(nxt[live].sum())
+            # Counted here and not at the turn's end: a stream this
+            # step finishes may read stats() before the turn is over.
+            self.prompt_steps += n_prompt
+            self.output_steps += n_live - n_prompt
+            self.keys_attended += n_keys
+            turn = self._turn
+            turn["prompt"] += n_prompt
+            turn["output"] += n_live - n_prompt
+            turn["keys"] += n_keys
+            turn["slots"] += pool.nslots
+            turn["kv"] = max(turn["kv"], pool.kv)
+            t_start = time.perf_counter()
+            pool.cache, pool.buf, col = step(
+                self._params_for(pool), pool.cache, pool.buf,
+                jnp.asarray(pos_now), jnp.asarray(t0s),
+                jnp.asarray(live),
+            )
+            pool.steps += 1
+            self.steps += 1
+        with phases("sync"):
+            col_host = None
+            if eager or pool.steps % _SYNC_STRIDE == 0:
+                # SSE wants the token NOW; lazy pools sync on a stride
+                # so async dispatch pipelines the loop like the solo
+                # scan.
+                col_host = np.asarray(col)
+            now = time.perf_counter()
+            # Terminal: the full row (prompt + continuation) is in the
+            # buffer; lazy streams surface everything from it.
+            rows = {
+                slot: np.asarray(pool.buf[slot])
+                for slot, stream in enumerate(pool.streams)
+                if stream is not None
+                and nxt[slot] >= stream.total - 1
+            }
+        with phases("emit"):
+            for slot, stream in enumerate(pool.streams):
+                if stream is None:
+                    continue
+                nxt_pos = int(nxt[slot])
+                pool.pos[slot] = nxt_pos
+                if nxt_pos >= stream.t0 and col_host is not None \
+                        and stream.eager:
+                    self._emit(stream, int(col_host[slot]), nxt_pos, now)
+                row = rows.get(slot)
+                if row is not None:
+                    if not stream.eager:
+                        stream.tokens = [
+                            int(t) for t in row[stream.t0: stream.total]
+                        ]
+                        stream.first_at = stream.first_at or now
+                        _decode_hists.ttft(
+                            stream.first_at - stream.arrived, self.name
+                        )
+                        obs_flight.record(
+                            "decode", "ttft",
+                            model=self.name, stream=stream.stream_id,
+                            ttftS=round(
+                                stream.first_at - stream.arrived, 4
+                            ),
+                        )
+                        _decode_hists.tokens(
+                            len(stream.tokens), self.name
+                        )
+                    pool.release(slot)
+                    self._finish(stream, row=row)
+            # Devtime attribution flushes at every host sync, whichever
+            # transport forced it — eager token read (per step), lazy
+            # stride boundary, or a terminal row read — so non-stream
+            # decode feeds the autoscaler's LO_TPU_FLEET_UP_DEVICE_FRAC
+            # signal too.  Between syncs the async backlog's device
+            # work is paid inside the syncing call, so measuring to
+            # HERE (past the row reads above) captures the stride's
+            # full cost as one amortized sample.
+            pool.pending_devtime += time.perf_counter() - t_start
+            synced = col_host is not None or bool(rows)
+            if synced and obs_costs.enabled():
+                led = obs_costs.devtime()
+                weight = led.will_record(self.name)
+                if weight:
+                    led.record_model(
+                        weight, pool.pending_devtime, None, None,
+                        self.name, f"dec{pool.nslots}x{pool.kv}",
                     )
-                    obs_flight.record(
-                        "decode", "ttft",
-                        model=self.name, stream=stream.stream_id,
-                        ttftS=round(
-                            stream.first_at - stream.arrived, 4
-                        ),
-                    )
-                    _decode_hists.tokens(
-                        len(stream.tokens), self.name
-                    )
-                pool.release(slot)
-                self._finish(stream, row=row)
-        # Devtime attribution flushes at every host sync, whichever
-        # transport forced it — eager token read (per step), lazy
-        # stride boundary, or a terminal row read — so non-stream
-        # decode feeds the autoscaler's LO_TPU_FLEET_UP_DEVICE_FRAC
-        # signal too.  Between syncs the async backlog's device work
-        # is paid inside the syncing call, so measuring to HERE (past
-        # the row reads above) captures the stride's full cost as one
-        # amortized sample.
-        pool.pending_devtime += time.perf_counter() - t_start
-        if synced and obs_costs.enabled():
-            led = obs_costs.devtime()
-            weight = led.will_record(self.name)
-            if weight:
-                led.record_model(
-                    weight, pool.pending_devtime, None, None,
-                    self.name, f"dec{pool.nslots}x{pool.kv}",
-                )
-            pool.pending_devtime = 0.0
+                pool.pending_devtime = 0.0
 
     def _emit(self, stream: DecodeStream, tok: int, pos: int,
               now: float) -> None:
@@ -576,6 +673,16 @@ class _ModelDecoder:
             "pending": pending,
             "steps": self.steps,
             "pools": pools,
+            # Cumulative, from the worker's own counts (each step, each
+            # live slot is one slot-step: prompt while it consumes its
+            # prompt, output once it produces tokens).
+            "slotSteps": {"prompt": self.prompt_steps,
+                          "output": self.output_steps},
+            "keysAttended": self.keys_attended,
+            "phaseS": dict(self.phases.total),
+            "phaseMaxS": dict(self.phases.peak),
+            "admitted": self.admitted,
+            "admitWaitS": self.admit_wait_s,
         }
 
     def close(self) -> None:

@@ -38,6 +38,7 @@ from learningorchestra_tpu.services.context import (
 from learningorchestra_tpu.services.executor import (
     ExecutorService,
     _json_safe,
+    publish_object,
     store_history_rows,
 )
 from learningorchestra_tpu.services.monitoring import (
@@ -282,17 +283,7 @@ class DistributedExecutorService:
             # Epoch fence at publication: a stale-epoch straggler must
             # not overwrite the artifact a recovered orchestrator owns.
             self.ctx.require_current_epoch()
-            self.ctx.volumes.save_object(artifact_type, name, instance)
-            # A re-train just replaced this artifact's binary: a
-            # serving registry holding the old params resident must
-            # reload before the next request (same contract as the
-            # single-device executor path).
-            self.ctx.notify_artifact_changed(name)
-            # Replace (not append) history rows on re-runs.
-            for doc in self.ctx.documents.find(
-                name, query={"docType": "history"}
-            ):
-                self.ctx.documents.delete_one(name, doc["_id"])
+            publish_object(self.ctx, artifact_type, name, instance)
             store_history_rows(
                 self.ctx.documents, name, dict(trainer.history)
             )
@@ -481,10 +472,6 @@ class DistributedExecutorService:
             self.ctx.require_current_epoch()
             rank0 = job["results"].get("0") or job["results"].get(0)
             history = (rank0 or {}).get("history") or {}
-            for doc in self.ctx.documents.find(
-                name, query={"docType": "history"}
-            ):
-                self.ctx.documents.delete_one(name, doc["_id"])
             store_history_rows(self.ctx.documents, name, history)
             if session_logdir is not None:
                 write_scalar_logs(session_logdir, history, prefix=name)
@@ -553,7 +540,9 @@ class DistributedExecutorService:
 
             with concurrent.futures.ThreadPoolExecutor(world) as pool:
                 results = list(pool.map(one_rank, range(world)))
-            self.ctx.volumes.save_object(artifact_type, name, results)
+            publish_object(
+                self.ctx, artifact_type, name, results, replaces=False
+            )
             for rank, result in enumerate(results):
                 self.ctx.documents.insert_one(
                     name, {"rank": rank, "result": _json_safe(result)}

@@ -25,6 +25,7 @@ import numpy as np
 
 from learningorchestra_tpu import dsl
 from learningorchestra_tpu.jobs.leases import device_ids, placed_on
+from learningorchestra_tpu.obs import tracing as obs_tracing
 from learningorchestra_tpu.train.neural import NeuralEstimator
 from learningorchestra_tpu.services.context import (
     ServiceContext,
@@ -38,21 +39,43 @@ TRAIN_KINDS = ("train", "tune")
 def store_history_rows(documents, name: str, history: dict) -> int:
     """Persist a TrainHistory-shaped dict ({metric: [per-epoch...]}) as one
     pollable row per epoch — the durable metrics contract (SURVEY §5.5).
-    Shared by the single-device and distributed train paths."""
-    keys = list(history)
-    n = max((len(history[k]) for k in keys), default=0)
-    for i in range(n):
-        documents.insert_one(
-            name,
-            {
-                "docType": "history",
-                "epoch": i,
-                **{
-                    k: history[k][i] for k in keys if len(history[k]) > i
+    Shared by the single-device and distributed train paths.  A re-run
+    re-stores the full history, so the old rows go first (epochs would
+    duplicate); the whole rewrite is the job's ``store_history`` span."""
+    with obs_tracing.span("store_history"):
+        for doc in documents.find(name, query={"docType": "history"}):
+            documents.delete_one(name, doc["_id"])
+        keys = list(history)
+        n = max((len(history[k]) for k in keys), default=0)
+        for i in range(n):
+            documents.insert_one(
+                name,
+                {
+                    "docType": "history",
+                    "epoch": i,
+                    **{
+                        k: history[k][i] for k in keys
+                        if len(history[k]) > i
+                    },
                 },
-            },
-        )
+            )
     return n
+
+
+def publish_object(ctx: ServiceContext, artifact_type: str, name: str,
+                   obj: Any, *, replaces: bool = True) -> None:
+    """Write a job's result binary as the job's ``publish`` span (attr
+    ``bytes``).  ``replaces``: a re-run just replaced this artifact's
+    binary, so a serving registry holding its old params resident must
+    reload before the next request."""
+    with obs_tracing.span("publish"):
+        ctx.volumes.save_object(artifact_type, name, obj)
+        if obs_tracing.current_trace() is not None:
+            obs_tracing.set_span_attrs(
+                bytes=ctx.volumes.object_bytes(artifact_type, name)
+            )
+        if replaces:
+            ctx.notify_artifact_changed(name)
 
 
 class ExecutorService:
@@ -172,7 +195,6 @@ class ExecutorService:
         def run():
             from learningorchestra_tpu.jobs import engine as engine_mod
             from learningorchestra_tpu.obs import costs as obs_costs
-            from learningorchestra_tpu.obs import tracing as obs_tracing
             from learningorchestra_tpu.train import compile_cache
 
             cache_before = compile_cache.counters_snapshot()
@@ -257,11 +279,7 @@ class ExecutorService:
             if kind in TRAIN_KINDS or result is instance:
                 # Train semantics: persist the mutated instance
                 # (binary_execution.py:195-200).
-                self.ctx.volumes.save_object(artifact_type, name, instance)
-                # A PATCH re-train just replaced this artifact's binary:
-                # a serving registry holding its old params resident
-                # must reload before the next request.
-                self.ctx.notify_artifact_changed(name)
+                publish_object(self.ctx, artifact_type, name, instance)
                 extra = {"fitTime": fit_time,
                          "compileCache": cache_delta}
                 if param_devices:
@@ -277,16 +295,12 @@ class ExecutorService:
                     extra["deviceTime"] = device_time
                 hist = getattr(instance, "history", None)
                 if hist:
-                    # Re-runs re-store the full history; drop the old
-                    # rows or epochs would duplicate.
-                    for doc in self.ctx.documents.find(
-                        name, query={"docType": "history"}
-                    ):
-                        self.ctx.documents.delete_one(name, doc["_id"])
                     store_history_rows(self.ctx.documents, name, hist)
                 return extra
             # Evaluate/predict semantics: persist result rows + binary.
-            self.ctx.volumes.save_object(artifact_type, name, result)
+            publish_object(
+                self.ctx, artifact_type, name, result, replaces=False
+            )
             self._store_result_rows(name, result)
             return {"fitTime": fit_time}
 
@@ -501,8 +515,7 @@ class ExecutorService:
                         pending.cancel()
                     raise
             self.ctx.require_current_epoch()
-            self.ctx.volumes.save_object(artifact_type, name, best_instance)
-            self.ctx.notify_artifact_changed(name)
+            publish_object(self.ctx, artifact_type, name, best_instance)
             # Trial checkpoints are per-run scratch: the grid is done,
             # the best candidate is published — keeping them would only
             # let a FUTURE unrelated grid resurrect stale trial state.

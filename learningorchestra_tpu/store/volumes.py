@@ -287,6 +287,19 @@ class VolumeStorage:
     def read_bytes(self, artifact_type: str, name: str) -> bytes:
         return self.path_for(artifact_type, name).read_bytes()
 
+    def object_bytes(self, artifact_type: str, name: str) -> int:
+        """What :meth:`save_object` wrote for this artifact: the one
+        file, or the parts behind its manifest."""
+        path = self.path_for(artifact_type, name)
+        manifest = _manifest_of(path)
+        if manifest is None:
+            return path.stat().st_size
+        directory = _parts_dir(path)
+        return sum(
+            (directory / f"{manifest['gen']}-{i:05d}").stat().st_size
+            for i in range(manifest["parts"])
+        )
+
     # -- lifecycle ------------------------------------------------------------
 
     def exists(self, artifact_type: str, name: str) -> bool:

@@ -1081,82 +1081,90 @@ class NeuralEstimator(Estimator):
                 resume=resume, accumulate_steps=accumulate_steps,
                 checkpoint_async=checkpoint_async,
             )
-        self._set_accumulation(accumulate_steps)
-        x = np.asarray(as_array(x))
-        y_arr = np.asarray(y if not hasattr(y, "to_numpy") else y.to_numpy())
-        y_arr = y_arr.reshape(-1) if y_arr.ndim == 2 and y_arr.shape[1] == 1 \
-            else y_arr
-        loss_kind = self._resolve_loss(y_arr)
-        if loss_kind == "softmax_ce":
-            y_arr = y_arr.astype(np.int32)
-        else:
-            y_arr = y_arr.astype(np.float32)
-
-        if validation_data is None and validation_split > 0:
-            n_val = int(len(x) * validation_split)
-            # Tiny datasets: never let the split empty the train set; skip
-            # validation instead of silently training on nothing.
-            if 0 < n_val < len(x):
-                x, x_val = x[:-n_val], x[-n_val:]
-                y_arr, y_val = y_arr[:-n_val], y_arr[-n_val:]
-                validation_data = (x_val, y_val)
-
-        if len(x) == 0:
-            raise ValueError("cannot batch an empty dataset")
-        if self.params is None:
-            self._init_params(jnp.asarray(x[:1]))
-        elif self.opt_state is None:
-            # Quantized (serving) artifacts drop optimizer state;
-            # continuation training re-inits moments from zero.
-            self.opt_state = jax.jit(self.optimizer.init)(self.params)
-        if self._eval_fn is None or self._eval_loss_kind != loss_kind:
-            _, self._eval_fn = self._build_step(loss_kind)
-            self._eval_loss_kind = loss_kind
-
-        # Upload the dataset once; each epoch is one jitted call that
-        # shuffles/batches on device (see build_device_epoch).
-        epoch_key = (len(x), batch_size, bool(shuffle), loss_kind)
-        if self._device_epoch_key != epoch_key:
-            dtype = jnp.bfloat16 if self.compute_dtype == "bfloat16" else None
-            self._device_epoch, self._device_epoch_cost = _cached_program(
-                "device_epoch", self, loss_kind,
-                shapes=(len(x), batch_size, bool(shuffle)),
-                builder=lambda: build_device_epoch(
-                    self.module,
-                    self.optimizer,
-                    self._loss_and_metrics(loss_kind),
-                    dtype,
-                    n=len(x),
-                    batch_size=batch_size,
-                    shuffle=bool(shuffle),
-                ),
-                # Shape avatars for the cost probe: the whole-epoch
-                # program's flops/HBM, measured once per build.
-                cost_args=lambda: (
-                    self.params, self.opt_state, x, y_arr,
-                    jax.random.PRNGKey(self.seed),
-                ),
-                want_cost=True,
+        # ``fit_init``: everything before the first epoch's dispatch —
+        # host arrays, parameter / optimizer state init, the program
+        # lookup (a ``compile`` span nests here on a miss), the upload
+        # of the dataset, a checkpoint resume.
+        with obs_tracing.span("fit_init"):
+            self._set_accumulation(accumulate_steps)
+            x = np.asarray(as_array(x))
+            y_arr = np.asarray(
+                y if not hasattr(y, "to_numpy") else y.to_numpy()
             )
-            self._device_epoch_key = epoch_key
-        xs = jnp.asarray(x)
-        ys = jnp.asarray(y_arr)
-        root_key = jax.random.PRNGKey(self.seed)
+            if y_arr.ndim == 2 and y_arr.shape[1] == 1:
+                y_arr = y_arr.reshape(-1)
+            loss_kind = self._resolve_loss(y_arr)
+            if loss_kind == "softmax_ce":
+                y_arr = y_arr.astype(np.int32)
+            else:
+                y_arr = y_arr.astype(np.float32)
 
-        start_epoch = 0
-        if checkpoint_dir and resume:
-            from learningorchestra_tpu.train import checkpoint as ckpt
+            if validation_data is None and validation_split > 0:
+                n_val = int(len(x) * validation_split)
+                # Tiny datasets: never let the split empty the train set; skip
+                # validation instead of silently training on nothing.
+                if 0 < n_val < len(x):
+                    x, x_val = x[:-n_val], x[-n_val:]
+                    y_arr, y_val = y_arr[:-n_val], y_arr[-n_val:]
+                    validation_data = (x_val, y_val)
 
-            loaded = ckpt.resume_or_none(
-                checkpoint_dir,
-                {"params": self.params, "opt_state": self.opt_state},
-            )
-            if loaded is not None:
-                state, step, past_history = loaded
-                self.params = state["params"]
-                self.opt_state = state["opt_state"]
-                self.history = TrainHistory(past_history)
-                start_epoch = step
+            if len(x) == 0:
+                raise ValueError("cannot batch an empty dataset")
+            if self.params is None:
+                self._init_params(jnp.asarray(x[:1]))
+            elif self.opt_state is None:
+                # Quantized (serving) artifacts drop optimizer state;
+                # continuation training re-inits moments from zero.
+                self.opt_state = jax.jit(self.optimizer.init)(self.params)
+            if self._eval_fn is None or self._eval_loss_kind != loss_kind:
+                _, self._eval_fn = self._build_step(loss_kind)
+                self._eval_loss_kind = loss_kind
+
+            # Upload the dataset once; each epoch is one jitted call that
+            # shuffles/batches on device (see build_device_epoch).
+            epoch_key = (len(x), batch_size, bool(shuffle), loss_kind)
+            if self._device_epoch_key != epoch_key:
+                dtype = jnp.bfloat16 \
+                    if self.compute_dtype == "bfloat16" else None
+                self._device_epoch, self._device_epoch_cost = _cached_program(
+                    "device_epoch", self, loss_kind,
+                    shapes=(len(x), batch_size, bool(shuffle)),
+                    builder=lambda: build_device_epoch(
+                        self.module,
+                        self.optimizer,
+                        self._loss_and_metrics(loss_kind),
+                        dtype,
+                        n=len(x),
+                        batch_size=batch_size,
+                        shuffle=bool(shuffle),
+                    ),
+                    # Shape avatars for the cost probe: the whole-epoch
+                    # program's flops/HBM, measured once per build.
+                    cost_args=lambda: (
+                        self.params, self.opt_state, x, y_arr,
+                        jax.random.PRNGKey(self.seed),
+                    ),
+                    want_cost=True,
+                )
+                self._device_epoch_key = epoch_key
+            xs = jnp.asarray(x)
+            ys = jnp.asarray(y_arr)
+            root_key = jax.random.PRNGKey(self.seed)
+
+            start_epoch = 0
+            if checkpoint_dir and resume:
+                from learningorchestra_tpu.train import checkpoint as ckpt
+
+                loaded = ckpt.resume_or_none(
+                    checkpoint_dir,
+                    {"params": self.params, "opt_state": self.opt_state},
+                )
+                if loaded is not None:
+                    state, step, past_history = loaded
+                    self.params = state["params"]
+                    self.opt_state = state["opt_state"]
+                    self.history = TrainHistory(past_history)
+                    start_epoch = step
 
         from learningorchestra_tpu.train import checkpoint as ckpt_mod
 
@@ -1175,53 +1183,57 @@ class NeuralEstimator(Estimator):
                 # Chaos probe per epoch: an armed ``preempt`` schedule
                 # models the real TPU event — mid-fit, after some
                 # checkpoints committed — so the engine-retry →
-                # checkpoint-resume path is provable end to end.
+                # checkpoint-resume path is provable end to end.  Ahead
+                # of the span: a preempted epoch trained nothing and
+                # leaves none.
                 _faults().hit("train.epoch")
-                params, opt_state, metrics = self._device_epoch(
-                    params, opt_state, xs, ys,
-                    jax.random.fold_in(root_key, epoch_i),
-                )
-                # Re-anchor the estimator each epoch: the epoch call donates
-                # its (params, opt_state) arguments, so a raise from a
-                # callback/validation below must not strand self.params on
-                # deleted buffers.
-                self.params, self.opt_state = params, opt_state
-                # ONE host transfer for all metric scalars — per-metric
-                # float() pays a device round-trip each.
-                metrics = {
-                    k: float(v) for k, v in jax.device_get(metrics).items()
-                }
-                metrics["epoch_time"] = time.perf_counter() - t0
-                # Device-time attribution (obs/costs.py): the metrics
-                # device_get above synced the dispatch, so epoch_time
-                # IS the device interval; the program's measured flops
-                # ride along, giving the per-job ledger (and the MFU
-                # gauge) real numerators.  One config check when the
-                # costs plane is off.
-                _attribute_epoch_cost(self, metrics["epoch_time"])
-                if validation_data is not None:
-                    vx, vy = validation_data
-                    vy = np.asarray(vy)
-                    # Only flatten single-column matrices — sequence targets
-                    # (B, T) keep their shape (the LM loss path).
-                    if vy.ndim == 2 and vy.shape[1] == 1:
-                        vy = vy.reshape(-1)
-                    vmetrics = self._evaluate_arrays(
-                        params, np.asarray(as_array(vx)), vy,
-                        batch_size, loss_kind,
-                    )
-                    metrics.update({f"val_{k}": v for k, v in vmetrics.items()})
-                self.history.append(metrics)
                 # Trace span per epoch (train step + validation): the
                 # job's span tree shows exactly where fit time went —
-                # now annotated with the program's measured flops/bytes
-                # and achieved-vs-peak utilization, so a trace answers
-                # "what was the hardware doing" per epoch.  Single
-                # contextvar read when no trace is active.
-                obs_tracing.record_span(
-                    "epoch", time.perf_counter() - t0, epoch=epoch_i,
-                    **_epoch_cost_attrs(self, metrics["epoch_time"]),
-                )
+                # annotated at its end with the program's measured
+                # flops/bytes and achieved-vs-peak utilization, so a
+                # trace answers "what was the hardware doing" per epoch.
+                with obs_tracing.span("epoch", epoch=epoch_i):
+                    params, opt_state, metrics = self._device_epoch(
+                        params, opt_state, xs, ys,
+                        jax.random.fold_in(root_key, epoch_i),
+                    )
+                    # Re-anchor the estimator each epoch: the epoch call
+                    # donates its (params, opt_state) arguments, so a
+                    # raise from a callback/validation below must not
+                    # strand self.params on deleted buffers.
+                    self.params, self.opt_state = params, opt_state
+                    # ONE host transfer for all metric scalars — per-metric
+                    # float() pays a device round-trip each.
+                    metrics = {
+                        k: float(v) for k, v in jax.device_get(metrics).items()
+                    }
+                    metrics["epoch_time"] = time.perf_counter() - t0
+                    # Device-time attribution (obs/costs.py): the metrics
+                    # device_get above synced the dispatch, so epoch_time
+                    # IS the device interval; the program's measured flops
+                    # ride along, giving the per-job ledger (and the MFU
+                    # gauge) real numerators.  One config check when the
+                    # costs plane is off.
+                    _attribute_epoch_cost(self, metrics["epoch_time"])
+                    if validation_data is not None:
+                        vx, vy = validation_data
+                        vy = np.asarray(vy)
+                        # Only flatten single-column matrices —
+                        # sequence targets (B, T) keep their shape (the
+                        # LM loss path).
+                        if vy.ndim == 2 and vy.shape[1] == 1:
+                            vy = vy.reshape(-1)
+                        vmetrics = self._evaluate_arrays(
+                            params, np.asarray(as_array(vx)), vy,
+                            batch_size, loss_kind,
+                        )
+                        metrics.update(
+                            {f"val_{k}": v for k, v in vmetrics.items()}
+                        )
+                    self.history.append(metrics)
+                    obs_tracing.set_span_attrs(
+                        **_epoch_cost_attrs(self, metrics["epoch_time"])
+                    )
                 if verbose:
                     _train_logger().info(
                         "epoch %d/%d: %s", epoch_i + 1, epochs, metrics
@@ -1248,13 +1260,16 @@ class NeuralEstimator(Estimator):
                         opt_state = jax.jit(self.optimizer.init)(
                             self.params
                         )
-                    ckpt.save(
-                        checkpoint_dir, epoch_i + 1,
-                        {"params": self.params,
-                         "opt_state": opt_state},
-                        history=dict(self.history),
-                        async_save=checkpoint_async,
-                    )
+                    with obs_tracing.span(
+                        "checkpoint_save", step=epoch_i + 1
+                    ):
+                        ckpt.save(
+                            checkpoint_dir, epoch_i + 1,
+                            {"params": self.params,
+                             "opt_state": opt_state},
+                            history=dict(self.history),
+                            async_save=checkpoint_async,
+                        )
                     last_save = time.monotonic()
                 if self.stop_training:
                     # A callback (e.g. EarlyStopping) may have replaced
@@ -1271,7 +1286,8 @@ class NeuralEstimator(Estimator):
                 # The last async save must be durable when fit returns
                 # (and an exception mid-loop must not strand a pending
                 # write unpublished for a later fit in this process).
-                ckpt_mod.finalize_async(checkpoint_dir)
+                with obs_tracing.span("checkpoint_save", finalize=True):
+                    ckpt_mod.finalize_async(checkpoint_dir)
         return self
 
     def _fit_streaming(
@@ -1319,71 +1335,74 @@ class NeuralEstimator(Estimator):
                 "validation_data must be in-memory arrays, not sharded "
                 "views (validation sets are small by construction)"
             )
-        x, y = sh.resolve_xy_views(x, y)
-        # Remember the feature columns so a later predict on the BARE
-        # dataset ("x": "$big") selects the same features instead of
-        # accidentally feeding the label column too.
-        self._sharded_fit_cols = list(x.cols)
-        self._set_accumulation(accumulate_steps)
+        # ``fit_init``, as in the in-memory path; the shard programs
+        # are looked up at their first use, inside the first epoch.
+        with obs_tracing.span("fit_init"):
+            x, y = sh.resolve_xy_views(x, y)
+            # Remember the feature columns so a later predict on the BARE
+            # dataset ("x": "$big") selects the same features instead of
+            # accidentally feeding the label column too.
+            self._sharded_fit_cols = list(x.cols)
+            self._set_accumulation(accumulate_steps)
 
-        ds = x.dataset
-        y_head = np.asarray(y.head(256))
-        loss_kind = self._resolve_loss(y_head)
-        y_cast = np.int32 if loss_kind == "softmax_ce" else np.float32
-        x_head = np.asarray(x.head(1), np.float32)
-        if self.params is None:
-            self._init_params(jnp.asarray(x_head))
-        elif self.opt_state is None:
-            # Quantized (serving) artifacts drop optimizer state.
-            self.opt_state = jax.jit(self.optimizer.init)(self.params)
-        if self._eval_fn is None or self._eval_loss_kind != loss_kind:
-            _, self._eval_fn = self._build_step(loss_kind)
-            self._eval_loss_kind = loss_kind
+            ds = x.dataset
+            y_head = np.asarray(y.head(256))
+            loss_kind = self._resolve_loss(y_head)
+            y_cast = np.int32 if loss_kind == "softmax_ce" else np.float32
+            x_head = np.asarray(x.head(1), np.float32)
+            if self.params is None:
+                self._init_params(jnp.asarray(x_head))
+            elif self.opt_state is None:
+                # Quantized (serving) artifacts drop optimizer state.
+                self.opt_state = jax.jit(self.optimizer.init)(self.params)
+            if self._eval_fn is None or self._eval_loss_kind != loss_kind:
+                _, self._eval_fn = self._build_step(loss_kind)
+                self._eval_loss_kind = loss_kind
 
-        dtype = jnp.bfloat16 if self.compute_dtype == "bfloat16" else None
-        loss_fn = self._loss_and_metrics(loss_kind)
-        epoch_fns: dict[int, Any] = {}
+            dtype = jnp.bfloat16 if self.compute_dtype == "bfloat16" else None
+            loss_fn = self._loss_and_metrics(loss_kind)
+            epoch_fns: dict[int, Any] = {}
 
-        def fn_for(rows: int):
-            # One compilation per distinct shard length — all full
-            # shards share one executable; the tail adds a second.
-            # Resolved through the process-wide cache so a re-submitted
-            # streaming job (same dataset, same shard layout) skips
-            # every trace.
-            if rows not in epoch_fns:
-                epoch_fns[rows] = _cached_program(
-                    "device_epoch", self, loss_kind,
-                    shapes=(rows, min(batch_size, rows), bool(shuffle)),
-                    builder=lambda: build_device_epoch(
-                        self.module, self.optimizer, loss_fn, dtype,
-                        n=rows, batch_size=min(batch_size, rows),
-                        shuffle=bool(shuffle),
-                    ),
+            def fn_for(rows: int):
+                # One compilation per distinct shard length — all full
+                # shards share one executable; the tail adds a second.
+                # Resolved through the process-wide cache so a re-submitted
+                # streaming job (same dataset, same shard layout) skips
+                # every trace.
+                if rows not in epoch_fns:
+                    epoch_fns[rows] = _cached_program(
+                        "device_epoch", self, loss_kind,
+                        shapes=(rows, min(batch_size, rows), bool(shuffle)),
+                        builder=lambda: build_device_epoch(
+                            self.module, self.optimizer, loss_fn, dtype,
+                            n=rows, batch_size=min(batch_size, rows),
+                            shuffle=bool(shuffle),
+                        ),
+                    )
+                return epoch_fns[rows]
+
+            def load(k: int):
+                # IO thread: disk → host arrays → START the async H2D copy.
+                # Dtypes pass through exactly as the in-memory path's
+                # as_array does (int features stay int — token models).
+                xs = x.load_shard(k)
+                ys = y.load_shard(k).astype(y_cast)
+                return jax.device_put(xs), jax.device_put(ys)
+
+            start_epoch = 0
+            if checkpoint_dir and resume:
+                from learningorchestra_tpu.train import checkpoint as ckpt
+
+                loaded = ckpt.resume_or_none(
+                    checkpoint_dir,
+                    {"params": self.params, "opt_state": self.opt_state},
                 )
-            return epoch_fns[rows]
-
-        def load(k: int):
-            # IO thread: disk → host arrays → START the async H2D copy.
-            # Dtypes pass through exactly as the in-memory path's
-            # as_array does (int features stay int — token models).
-            xs = x.load_shard(k)
-            ys = y.load_shard(k).astype(y_cast)
-            return jax.device_put(xs), jax.device_put(ys)
-
-        start_epoch = 0
-        if checkpoint_dir and resume:
-            from learningorchestra_tpu.train import checkpoint as ckpt
-
-            loaded = ckpt.resume_or_none(
-                checkpoint_dir,
-                {"params": self.params, "opt_state": self.opt_state},
-            )
-            if loaded is not None:
-                state, step, past_history = loaded
-                self.params = state["params"]
-                self.opt_state = state["opt_state"]
-                self.history = TrainHistory(past_history)
-                start_epoch = step
+                if loaded is not None:
+                    state, step, past_history = loaded
+                    self.params = state["params"]
+                    self.opt_state = state["opt_state"]
+                    self.history = TrainHistory(past_history)
+                    start_epoch = step
 
         from learningorchestra_tpu.train import checkpoint as ckpt_mod
 
@@ -1401,53 +1420,52 @@ class NeuralEstimator(Estimator):
                         break
                     t0 = time.perf_counter()
                     _faults().hit("train.epoch")  # see in-memory loop
-                    # Seeded per (seed, epoch), NOT once per fit: a
-                    # checkpoint-resumed epoch 6 must walk the same shard
-                    # order the uninterrupted run would have (and the
-                    # distributed path already does — one convention).
-                    order = (
-                        np.random.default_rng(
-                            [self.seed, 3, epoch_i]
-                        ).permutation(ds.n_shards) if shuffle
-                        else np.arange(ds.n_shards)
-                    )
-                    acc = sh.WeightedMetrics()
-                    nxt = io.submit(load, int(order[0]))
-                    for pos, k in enumerate(order):
-                        xs, ys = nxt.result()
-                        if pos + 1 < len(order):
-                            nxt = io.submit(load, int(order[pos + 1]))
-                        rows = ds.shard_rows[int(k)]
-                        params, opt_state, metrics = fn_for(rows)(
-                            params, opt_state, xs, ys,
-                            jax.random.fold_in(
-                                root_key, epoch_i * ds.n_shards + pos
-                            ),
+                    with obs_tracing.span(
+                        "epoch", epoch=epoch_i, streaming=True
+                    ):
+                        # Seeded per (seed, epoch), NOT once per fit: a
+                        # checkpoint-resumed epoch 6 must walk the same shard
+                        # order the uninterrupted run would have (and the
+                        # distributed path already does — one convention).
+                        order = (
+                            np.random.default_rng(
+                                [self.seed, 3, epoch_i]
+                            ).permutation(ds.n_shards) if shuffle
+                            else np.arange(ds.n_shards)
                         )
-                        # Re-anchor every shard: the epoch fn donates its
-                        # state, so an interrupt must not strand
-                        # self.params on deleted buffers.
-                        self.params, self.opt_state = params, opt_state
-                        acc.add(jax.device_get(metrics), rows)
-                    metrics = acc.result()
-                    metrics["epoch_time"] = time.perf_counter() - t0
-                    if validation_data is not None:
-                        vx, vy = validation_data
-                        vy = np.asarray(vy)
-                        if vy.ndim == 2 and vy.shape[1] == 1:
-                            vy = vy.reshape(-1)
-                        vmetrics = self._evaluate_arrays(
-                            params, np.asarray(as_array(vx)), vy,
-                            batch_size, loss_kind,
-                        )
-                        metrics.update(
-                            {f"val_{k2}": v for k2, v in vmetrics.items()}
-                        )
-                    self.history.append(metrics)
-                    obs_tracing.record_span(
-                        "epoch", time.perf_counter() - t0,
-                        epoch=epoch_i, streaming=True,
-                    )
+                        acc = sh.WeightedMetrics()
+                        nxt = io.submit(load, int(order[0]))
+                        for pos, k in enumerate(order):
+                            xs, ys = nxt.result()
+                            if pos + 1 < len(order):
+                                nxt = io.submit(load, int(order[pos + 1]))
+                            rows = ds.shard_rows[int(k)]
+                            params, opt_state, metrics = fn_for(rows)(
+                                params, opt_state, xs, ys,
+                                jax.random.fold_in(
+                                    root_key, epoch_i * ds.n_shards + pos
+                                ),
+                            )
+                            # Re-anchor every shard: the epoch fn donates its
+                            # state, so an interrupt must not strand
+                            # self.params on deleted buffers.
+                            self.params, self.opt_state = params, opt_state
+                            acc.add(jax.device_get(metrics), rows)
+                        metrics = acc.result()
+                        metrics["epoch_time"] = time.perf_counter() - t0
+                        if validation_data is not None:
+                            vx, vy = validation_data
+                            vy = np.asarray(vy)
+                            if vy.ndim == 2 and vy.shape[1] == 1:
+                                vy = vy.reshape(-1)
+                            vmetrics = self._evaluate_arrays(
+                                params, np.asarray(as_array(vx)), vy,
+                                batch_size, loss_kind,
+                            )
+                            metrics.update(
+                                {f"val_{k2}": v for k2, v in vmetrics.items()}
+                            )
+                        self.history.append(metrics)
                     if verbose:
                         _train_logger().info(
                             "epoch %d/%d: %s", epoch_i + 1, epochs, metrics
@@ -1471,13 +1489,16 @@ class NeuralEstimator(Estimator):
                             opt_state = jax.jit(self.optimizer.init)(
                                 self.params
                             )
-                        ckpt.save(
-                            checkpoint_dir, epoch_i + 1,
-                            {"params": self.params,
-                             "opt_state": opt_state},
-                            history=dict(self.history),
-                            async_save=checkpoint_async,
-                        )
+                        with obs_tracing.span(
+                            "checkpoint_save", step=epoch_i + 1
+                        ):
+                            ckpt.save(
+                                checkpoint_dir, epoch_i + 1,
+                                {"params": self.params,
+                                 "opt_state": opt_state},
+                                history=dict(self.history),
+                                async_save=checkpoint_async,
+                            )
                         last_save = time.monotonic()
                     if self.stop_training:
                         # Per-shard re-anchor above already synced
@@ -1492,7 +1513,8 @@ class NeuralEstimator(Estimator):
             if checkpoint_dir:
                 # Same durability contract as the in-memory
                 # loop, incl. the exception path.
-                ckpt_mod.finalize_async(checkpoint_dir)
+                with obs_tracing.span("checkpoint_save", finalize=True):
+                    ckpt_mod.finalize_async(checkpoint_dir)
         return self
 
     def _evaluate_arrays(self, params, x, y, batch_size, loss_kind):

@@ -20,6 +20,7 @@ import pytest
 import requests
 
 from learningorchestra_tpu import faults
+from learningorchestra_tpu.obs import flight as obs_flight
 from learningorchestra_tpu.obs import metrics as obs_metrics
 from learningorchestra_tpu.obs import rollup as obs_rollup
 from learningorchestra_tpu.obs import slo as obs_slo
@@ -358,6 +359,106 @@ class TestDecodeObservability:
         assert resp.status_code == 200, resp.text
         after = obs_costs.devtime().model_device_s("lm_srv")
         assert after > before
+
+
+class TestDecodeLoopAccounting:
+    """What the worker's loop counts and times from inside (ROADMAP
+    D12): slot-steps by kind, keys attended, seconds by phase, the
+    wait for a slot, and a ``slow_step`` flight event for a turn that
+    stalls."""
+
+    @staticmethod
+    def _model_stats(eng):
+        return eng.stats()["models"].get("lm_srv") or {
+            "slotSteps": {"prompt": 0, "output": 0}, "keysAttended": 0,
+            "admitted": 0, "admitWaitS": 0.0, "steps": 0,
+        }
+
+    def test_slot_steps_phases_and_admission_wait(self, decode_api):
+        server, _, _ = decode_api
+        eng = server.serving.decode
+        before = self._model_stats(eng)
+        started_at = time.monotonic()
+        shapes = [([4, 4, 2, 1], 6), ([9, 2, 5], 8), ([1, 2, 3, 4, 5], 4)]
+        streams = [
+            eng.generate("lm_srv", prompt, max_new_tokens=new,
+                         stream=True)
+            for prompt, new in shapes
+        ]
+        for stream in streams:
+            assert stream.wait_done(60)
+            assert stream.error is None
+        after = self._model_stats(eng)
+
+        prompt_steps = sum(len(p) - 1 for p, _ in shapes)
+        output_steps = sum(new for _, new in shapes)
+        grew = {
+            kind: after["slotSteps"][kind] - before["slotSteps"][kind]
+            for kind in ("prompt", "output")
+        }
+        # A stream of t0 prompt tokens and n outputs takes t0 - 1
+        # prompt steps (each feeds the next prompt token) and n output
+        # steps; every step attends the keys up to its own position.
+        assert grew == {"prompt": prompt_steps, "output": output_steps}
+        assert after["keysAttended"] - before["keysAttended"] == sum(
+            pos + 1
+            for p, new in shapes for pos in range(len(p) - 1 + new)
+        )
+        assert after["admitted"] - before["admitted"] == len(shapes)
+        assert after["admitWaitS"] >= before["admitWaitS"] >= 0.0
+        assert set(after["phaseS"]) == set(after["phaseMaxS"]) == {
+            "admit", "dispatch", "sync", "emit", "wait",
+        }
+        for name, seconds in after["phaseS"].items():
+            assert seconds >= 0.0
+            assert after["phaseMaxS"][name] <= seconds + 1e-9
+            if name != "wait":
+                assert seconds > 0.0, name
+        # The keys stats() had stay.
+        assert {"activeStreams", "pending", "steps", "pools"} <= set(after)
+        admits = [
+            e for e in obs_flight.snapshot(["decode"])["events"]["decode"]
+            if e["kind"] == "admit" and e["t"] >= started_at
+        ]
+        assert len(admits) == len(shapes)
+        assert all(e["waitS"] >= 0.0 for e in admits)
+
+    def test_a_stalled_turn_leaves_one_slow_step_event(self, decode_api):
+        server, _, _ = decode_api
+        eng = server.serving.decode
+
+        def run_one():
+            stream = eng.generate(
+                "lm_srv", [7, 3, 1], max_new_tokens=4, stream=True
+            )
+            assert stream.wait_done(60) and stream.error is None
+
+        run_one()  # the step program is built: no turn waits for it
+
+        armed_at = time.monotonic()
+        try:
+            faults.arm(
+                "serve.decode_step", "delay", delay_ms=600,
+                max_triggers=1,
+            )
+            run_one()
+        finally:
+            faults.reset()
+        events = [
+            e for e in obs_flight.snapshot(["decode"])["events"]["decode"]
+            if e["kind"] == "slow_step" and e["t"] >= armed_at
+        ]
+        assert len(events) == 1, events
+        event = events[0]
+        assert event["model"] == "lm_srv" and event["turnS"] >= 0.6
+        split = event["phaseS"]
+        assert set(split) == {"admit", "dispatch", "sync", "emit"}
+        # The probe stands in the dispatch phase, and the four phases
+        # account for the turn.
+        assert split["dispatch"] >= 0.6
+        assert sum(split.values()) == pytest.approx(
+            event["turnS"], abs=0.05
+        )
 
 
 class TestDecodeSLO:

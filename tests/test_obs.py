@@ -217,11 +217,15 @@ def test_trace_span_tree_for_finished_train_job(trained_job):
     by_id = {s["id"]: s for s in doc["spans"]}
     job = next(s for s in doc["spans"] if s["name"] == "job")
     lease = next(s for s in doc["spans"] if s["name"] == "lease")
-    # Nesting: lease under job; compile and every epoch under lease.
+    fit_init = next(s for s in doc["spans"] if s["name"] == "fit_init")
+    # Nesting: lease under job; fit_init and every epoch under lease;
+    # the program build under the fit's set-up, where it happens.
     assert lease["parent"] == job["id"]
     for span in doc["spans"]:
-        if span["name"] in ("compile", "epoch"):
+        if span["name"] in ("fit_init", "epoch"):
             assert span["parent"] == lease["id"], span
+        if span["name"] == "compile":
+            assert span["parent"] == fit_init["id"], span
     # The rendered tree mirrors the parent links.
     roots = {node["name"] for node in doc["tree"]}
     assert roots == {"queue_wait", "job"}
@@ -230,8 +234,12 @@ def test_trace_span_tree_for_finished_train_job(trained_job):
         c for c in job_node["children"] if c["name"] == "lease"
     )
     assert {c["name"] for c in lease_node["children"]} >= {
-        "compile", "epoch",
+        "fit_init", "epoch",
     }
+    init_node = next(
+        c for c in lease_node["children"] if c["name"] == "fit_init"
+    )
+    assert "compile" in {c["name"] for c in init_node["children"]}
 
     # Duration consistency: children nest WITHIN their parents, and
     # queue_wait + job account for the submit→finish wall time the
@@ -262,6 +270,204 @@ def test_trace_span_tree_for_finished_train_job(trained_job):
     ]
     assert ledger_traces
     assert ledger_traces[-1]["requestId"] == "req-obs-roundtrip"
+
+
+def test_every_second_of_a_fit_job_has_a_named_span(trained_job):
+    """ROADMAP D12: between ``job`` start and end every interval has a
+    named child — parameter resolution, the fit's set-up, the epochs,
+    the publish, the history rows, the terminal commit."""
+    base, _meta = trained_job
+    doc = requests.get(
+        f"{base}/observability/jobs/obs_fit/trace", timeout=30
+    ).json()
+    by_id = {s["id"]: s for s in doc["spans"]}
+    job = next(s for s in doc["spans"] if s["name"] == "job")
+
+    def under_job(span):
+        while span is not None and span["id"] != job["id"]:
+            span = by_id.get(span["parent"])
+        return span is not None
+
+    named = [s for s in doc["spans"] if s is not job and under_job(s)]
+    names = {s["name"] for s in named}
+    assert {"load_artifact", "resolve_params", "lease_wait", "lease",
+            "fit_init", "epoch", "publish", "store_history",
+            "commit"} <= names, names
+    for name in ("load_artifact", "resolve_params", "lease_wait",
+                 "lease", "publish", "store_history", "commit"):
+        span = next(s for s in named if s["name"] == name)
+        assert span["parent"] == job["id"], span
+    publish = next(s for s in named if s["name"] == "publish")
+    assert publish["attrs"]["bytes"] > 0
+    epochs = [s for s in named if s["name"] == "epoch"]
+    assert [s["attrs"]["epoch"] for s in epochs] == [0, 1, 2]
+    # What the named pieces cover of the job's wall: ``lease`` only
+    # holds the fit's pieces and a ``compile`` lies inside ``fit_init``,
+    # so neither is counted beside them.
+    covered = sum(
+        s["durationS"] for s in named
+        if s["name"] not in ("lease", "compile")
+    )
+    assert covered >= 0.9 * job["durationS"], (
+        covered, job["durationS"],
+        {s["name"]: s["durationS"] for s in named},
+    )
+    assert covered <= job["durationS"] + 0.05
+
+
+def test_a_streaming_fit_names_the_same_pieces(tmp_path):
+    """The shard-streaming fit (``_fit_streaming``) leaves the spans
+    the in-memory fit does: ``fit_init``, one with-block ``epoch`` an
+    epoch, ``checkpoint_save`` for each save and the final wait."""
+    import numpy as np
+
+    from learningorchestra_tpu.models.mlp import MLPClassifier
+    from learningorchestra_tpu.store.sharded import (
+        ShardedDataset,
+        ShardedDatasetWriter,
+    )
+
+    rng = np.random.default_rng(0)
+    writer = ShardedDatasetWriter(
+        tmp_path / "ds", ["a", "b", "label"], rows_per_shard=16
+    )
+    for a, b in rng.standard_normal((48, 2)):
+        writer.append([float(a), float(b), int(a + b > 0)])
+    writer.close()
+    ds = ShardedDataset(tmp_path / "ds")
+
+    trace = obs_tracing.JobTrace("streaming")
+    with obs_tracing.activate(trace):
+        MLPClassifier(hidden_layer_sizes=[4], num_classes=2).fit(
+            ds, ds["label"], epochs=2, batch_size=16,
+            checkpoint_dir=str(tmp_path / "ck"),
+            checkpoint_min_interval_s=0.0,
+        )
+    spans = trace.to_doc()["spans"]
+    names = [s["name"] for s in spans]
+    assert names.count("fit_init") == 1
+    epochs = [s for s in spans if s["name"] == "epoch"]
+    assert [s["attrs"] for s in epochs] == [
+        {"epoch": 0, "streaming": True}, {"epoch": 1, "streaming": True},
+    ]
+    saves = [s["attrs"] for s in spans if s["name"] == "checkpoint_save"]
+    assert saves == [{"step": 1}, {"step": 2}, {"finalize": True}]
+    # The shard programs are built at their first use, inside the
+    # first epoch; the evaluation step in ``fit_init``.
+    by_id = {s["id"]: s for s in spans}
+    built_under = {
+        (s["attrs"]["label"].split(":")[0], by_id[s["parent"]]["name"])
+        for s in spans if s["name"] == "compile"
+    }
+    assert ("device_epoch", "epoch") in built_under, built_under
+    assert {under for _label, under in built_under} <= {
+        "fit_init", "epoch",
+    }
+    assert all(s["end"] is not None for s in spans)
+
+
+class _Probe:
+    """Stands in for ``jax.profiler.TraceAnnotation``: the names that
+    were entered and left."""
+
+    entered: list = []
+
+    def __init__(self, name, **metadata):
+        self.name, self.metadata = name, metadata
+
+    def __enter__(self):
+        _Probe.entered.append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        _Probe.entered.append("/" + self.name)
+        return False
+
+    def set_metadata(self, **metadata):
+        self.metadata.update(metadata)
+
+
+@pytest.mark.parametrize("with_trace", [True, False])
+def test_span_is_a_profiler_annotation(monkeypatch, with_trace):
+    """``span()`` enters ``lo:<name>`` whether or not a job trace is
+    active, and records a JobTrace span only when one is."""
+    monkeypatch.setattr(obs_tracing, "_trace_annotation", _Probe)
+    monkeypatch.setattr(_Probe, "entered", [])
+    trace = obs_tracing.JobTrace("j") if with_trace else None
+    with obs_tracing.activate(trace):
+        with obs_tracing.span("outer", k=1):
+            with obs_tracing.span("inner"):
+                obs_tracing.set_span_attrs(late=2)
+    assert _Probe.entered == [
+        "lo:outer", "lo:inner", "/lo:inner", "/lo:outer",
+    ]
+    if trace is None:
+        assert obs_tracing.current_trace() is None
+        return
+    outer, inner = trace.to_doc()["spans"]
+    assert (outer["name"], outer["attrs"]) == ("outer", {"k": 1})
+    assert inner["parent"] == outer["id"]
+    assert inner["attrs"] == {"late": 2}
+
+
+def test_spans_and_phases_land_in_a_profiler_capture(tmp_path):
+    """The real thing, on the CPU backend: a ``jax.profiler`` capture
+    holds the program's intervals as ``lo:`` host events, a phase's
+    metadata with them."""
+    import jax
+    from jax.profiler import ProfileData
+
+    phases = obs_tracing.Phases("loop", ("work", "rest"))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_tracing.span("captured_span"):
+            time.sleep(0.002)
+        with obs_tracing.annotation("loop.turn") as turn:
+            with phases("work"):
+                time.sleep(0.003)
+            turn.set_metadata(prompt=3, output=5)
+    finally:
+        jax.profiler.stop_trace()
+    assert phases.total["work"] >= 0.003 and phases.total["rest"] == 0.0
+    assert phases.peak["work"] == phases.total["work"]
+    path = next(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    events = {
+        ev.name.split("#")[0]: ev
+        for plane in ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host:CPU")
+        for line in plane.lines for ev in line.events
+        if ev.name.startswith("lo:")
+    }
+    assert {"lo:captured_span", "lo:loop.turn", "lo:loop.work"} \
+        <= set(events)
+    assert events["lo:captured_span"].duration_ns >= 2_000_000
+    # metadata rides as the event's stats
+    assert dict(events["lo:loop.turn"].stats) == {"prompt": 3, "output": 5}
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dq", "flash_dkv"])
+def test_each_flash_kernel_is_named_in_the_lowered_program(
+        monkeypatch, kernel):
+    """A device trace tells the three kernels apart by these names
+    (``ops/attention.py``): each must reach the program lowered for
+    the TPU."""
+    import jax
+    import jax.numpy as jnp
+    from jax import export
+
+    from learningorchestra_tpu.ops.attention import flash_attention
+
+    monkeypatch.setenv("LO_TPU_FLASH_INTERPRET", "0")
+    q = jnp.zeros((1, 2, 256, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    text = export.export(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))), platforms=["tpu"]
+    )(q, q, q).mlir_module()
+    assert text.count("tpu_custom_call") >= 3
+    assert kernel in text
 
 
 def test_trace_404_for_untraced_artifact(api):
