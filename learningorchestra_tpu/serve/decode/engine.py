@@ -30,7 +30,11 @@ from learningorchestra_tpu.obs import tracing as obs_tracing
 from learningorchestra_tpu.obs.metrics import get_registry
 from learningorchestra_tpu.serve.batcher import QueueFull
 from learningorchestra_tpu.serve.bucketing import bucket_for
-from learningorchestra_tpu.serve.decode.pages import PagePool, build_step
+from learningorchestra_tpu.serve.decode.pages import (
+    PagePool,
+    build_step,
+    key_pages,
+)
 from learningorchestra_tpu.serve.decode.streams import DecodeStream
 from learningorchestra_tpu.serve.registry import ServeError
 
@@ -136,6 +140,10 @@ class _ModelDecoder:
         self._thread: threading.Thread | None = None
         self._closed = False
         self.steps = 0
+        # Steps after which the cache that went in was gone: the step
+        # updated the pool's pages in place (jax leaves a donated
+        # argument alive when XLA could not alias it).
+        self.steps_in_place = 0
         # Written by the worker thread alone, read by stats().
         self.phases = obs_tracing.Phases("decode", _PHASES)
         self.prompt_steps = 0  # slot-steps that consumed a prompt token
@@ -146,7 +154,7 @@ class _ModelDecoder:
         # One turn's share of the three counters above and what it
         # stepped: the ``lo:decode.step`` annotation's metadata.
         self._turn = {"prompt": 0, "output": 0, "keys": 0, "slots": 0,
-                      "kv": 0}
+                      "kv": 0, "inplace": 0}
 
     # -- submission (any thread) --------------------------------------------
 
@@ -294,13 +302,7 @@ class _ModelDecoder:
                     phaseS=split, step=self.steps,
                 )
         # closed: fail whatever never got (or was mid) service.
-        for stream in pending:
-            stream.fail("decode engine shut down")
-        for pool in pools:
-            for slot, stream in enumerate(pool.streams):
-                if stream is not None:
-                    pool.release(slot)
-                    stream.fail("decode engine shut down")
+        self._shut_down(pending, pools)
         with self._cv:
             self._streams.clear()
 
@@ -408,6 +410,9 @@ class _ModelDecoder:
                 loss="-",
                 dtype="-",
                 shapes=("decode_step", nslots, kvlen),
+                # The step consumes its cache and buffer: a store keyed
+                # without this must not hand back one that copies.
+                donate=(1, 2),
             )
             label = (
                 f"decode:{type(module).__name__}"
@@ -466,16 +471,17 @@ class _ModelDecoder:
                     "decode", "step_error",
                     model=self.name, pool=f"{key}", error=str(exc),
                 )
-                for slot, stream in enumerate(pool.streams):
-                    if stream is not None:
-                        pool.release(slot)
-                        self._finish(
-                            stream, error=f"decode step failed: {exc}"
-                        )
+                # The step consumes the pool's cache and buffer, so
+                # after one that raised they may be gone: the pool
+                # forgets its device state whole (nothing here may
+                # touch the old buffer) and the next admission
+                # allocates afresh.
+                for stream in pool.drop():
+                    self._finish(
+                        stream, error=f"decode step failed: {exc}"
+                    )
 
     def _step_pool(self, pool: PagePool) -> None:
-        import jax.numpy as jnp
-
         from learningorchestra_tpu import faults
         from learningorchestra_tpu.obs import costs as obs_costs
 
@@ -525,13 +531,18 @@ class _ModelDecoder:
             turn["slots"] += pool.nslots
             turn["kv"] = max(turn["kv"], pool.kv)
             t_start = time.perf_counter()
+            # The step consumes the cache and the buffer: from here on
+            # the pool holds only what it returned.
+            went_in = key_pages(pool.cache)
             pool.cache, pool.buf, col = step(
                 self._params_for(pool), pool.cache, pool.buf,
-                jnp.asarray(pos_now), jnp.asarray(t0s),
-                jnp.asarray(live),
+                pos_now, t0s, live,
             )
             pool.steps += 1
             self.steps += 1
+            if went_in.is_deleted():
+                self.steps_in_place += 1
+                turn["inplace"] += 1
         with phases("sync"):
             col_host = None
             if eager or pool.steps % _SYNC_STRIDE == 0:
@@ -634,8 +645,6 @@ class _ModelDecoder:
         replica's placed params — pays the per-device executable
         load/compile before the router may pick the replica (the
         decode leg of PR-16 replica pre-warm)."""
-        import jax.numpy as jnp
-
         for (nslots, kvlen) in sorted(entry.decode_warm):
             step, cache_shapes = self._step_for(nslots, kvlen)
             pool = PagePool(kvlen, nslots, replica_idx=replica.idx)
@@ -645,9 +654,9 @@ class _ModelDecoder:
             )
             step(
                 params, pool.cache, pool.buf,
-                jnp.zeros(nslots, jnp.int32),
-                jnp.full(nslots, kvlen + 1, jnp.int32),
-                jnp.zeros(nslots, bool),
+                np.zeros(nslots, np.int32),
+                np.full(nslots, kvlen + 1, np.int32),
+                np.zeros(nslots, bool),
             )
 
     def stats(self) -> dict:
@@ -672,6 +681,7 @@ class _ModelDecoder:
             "activeStreams": active,
             "pending": pending,
             "steps": self.steps,
+            "stepsInPlace": self.steps_in_place,
             "pools": pools,
             # Cumulative, from the worker's own counts (each step, each
             # live slot is one slot-step: prompt while it consumes its
@@ -699,13 +709,19 @@ class _ModelDecoder:
             pools = list(self._pools.values())
             self._pools.clear()
             self._step_state.clear()
+        self._shut_down(pending, pools)
+
+    @staticmethod
+    def _shut_down(pending, pools) -> None:
+        """Fail the streams of a decoder that closed.  The pools are
+        already out of ``_pools``; they forget their device state
+        untouched (a worker that outlived ``close``'s join may be
+        inside a step that has consumed it)."""
         for stream in pending:
             stream.fail("decode engine shut down")
         for pool in pools:
-            for slot, stream in enumerate(pool.streams):
-                if stream is not None:
-                    pool.release(slot)
-                    stream.fail("decode engine shut down")
+            for stream in pool.drop():
+                stream.fail("decode engine shut down")
 
 
 class DecodeEngine:

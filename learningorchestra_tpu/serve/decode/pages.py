@@ -18,6 +18,13 @@ same dispatch that extends its neighbours.  Freed slots are simply
 zeroed in the token buffer: an all-pad row masks to an exact-zero
 attention output (the masked-softmax double-where), so stale KV pages
 cost nothing and need no scrubbing.
+
+The step program OWNS the pool's device state: it consumes the cache
+and the token buffer it is called with (both are donated, XLA updates
+them in place) and hands back the ones to use from then on.  After a
+call the arrays that went in are deleted; whoever holds a pool holds
+only what the last step returned, and no caller keeps a reference to a
+cache or a buffer across a step.
 """
 
 from __future__ import annotations
@@ -25,23 +32,59 @@ from __future__ import annotations
 import numpy as np
 
 
-def set_index(cache, pos):
-    """Rebind every ``cache_index`` leaf of a decode cache tree to the
-    per-slot position vector ``pos`` (S,) — the step's single source of
-    truth for where each slot writes and how far it may attend."""
-    out = {}
-    for key, val in cache.items():
-        if isinstance(val, dict):
-            out[key] = set_index(val, pos)
-        elif key == "cache_index":
-            out[key] = pos
-        else:
-            out[key] = val
+def set_index(pages, pos):
+    """The decode cache tree the module applies on: ``pages`` (the K/V
+    leaves a pool carries) with a ``cache_index`` beside every
+    ``cached_key``, each the per-slot position vector ``pos`` (S,) —
+    the step's single source of truth for where each slot writes and
+    how far it may attend."""
+    out = {
+        key: set_index(val, pos) if isinstance(val, dict) else val
+        for key, val in pages.items()
+    }
+    if "cached_key" in pages:
+        out["cache_index"] = pos
     return out
 
 
+def strip_index(cache):
+    """``cache`` without its ``cache_index`` leaves: what a pool
+    carries from step to step.  The step sets every index from ``pos``
+    before anything reads one, so carrying them would only be one more
+    argument and one more output a layer."""
+    return {
+        key: strip_index(val) if isinstance(val, dict) else val
+        for key, val in cache.items() if key != "cache_index"
+    }
+
+
+def key_pages(cache):
+    """The first K leaf of a decode cache tree: the one the engine asks
+    ``is_deleted()`` after a step, to count the steps that updated the
+    pages in place."""
+    for key, val in cache.items():
+        if key == "cached_key":
+            return val
+        if isinstance(val, dict):
+            found = key_pages(val)
+            if found is not None:
+                return found
+    return None
+
+
 def build_step(module, nslots: int, kv: int):
-    """(jitted step fn, cache shape tree) for one (arch, S, Tk) cell.
+    """(step fn, shape tree of the K/V pages a pool carries) for one
+    (arch, S, Tk) cell.  ``step(variables, cache, buf, pos, t0s, live)``
+    returns ``(cache, buf, col)``.
+
+    The step CONSUMES its ``cache`` and ``buf`` arguments (donated: the
+    K/V pages and the token buffer are updated in place, no second copy
+    is allocated) and returns their successors; a caller must not keep
+    or touch a reference to what it passed in.  ``variables`` are the
+    registry's and never donated.  The three per-slot host vectors go
+    to the device as ONE (3, S) int32 array: every argument the runtime
+    has to place is a transfer and an allocation of its own, and on the
+    chip their number, not their size, is what a call costs.
 
     The step replicates the solo ``GreedyDecodeMixin.generate`` scan
     body exactly — same token gather, same key mask, same f32 argmax,
@@ -53,12 +96,13 @@ def build_step(module, nslots: int, kv: int):
     import jax.numpy as jnp
 
     decode_mod = module.clone(decode=True)
-    cache_shapes = jax.eval_shape(
+    cache_shapes = strip_index(jax.eval_shape(
         decode_mod.init, jax.random.PRNGKey(0),
         jnp.zeros((nslots, kv), jnp.int32),
-    )["cache"]
+    )["cache"])
 
-    def step(variables, cache, buf, pos, t0s, live):
+    def step(variables, cache, buf, slots):
+        pos, t0s, live = slots[0], slots[1], slots[2] != 0
         cache = set_index(cache, pos)
         tok = jnp.take_along_axis(buf, pos[:, None], axis=1)
         kmask = (jnp.arange(kv)[None, :] <= pos[:, None]) & (buf != 0)
@@ -78,9 +122,14 @@ def build_step(module, nslots: int, kv: int):
         # ``i + 1 >= t0`` select.
         col = jnp.where(live & (nxt_pos >= t0s), nxt, prev)
         buf = buf.at[jnp.arange(nslots), nxt_pos].set(col)
-        return mut["cache"], buf, col
+        return strip_index(mut["cache"]), buf, col
 
-    return jax.jit(step), cache_shapes
+    jitted = jax.jit(step, donate_argnums=(1, 2))
+
+    def call(variables, cache, buf, pos, t0s, live):
+        return jitted(variables, cache, buf, np.stack((pos, t0s, live)))
+
+    return call, cache_shapes
 
 
 class PagePool:
@@ -97,12 +146,9 @@ class PagePool:
     def __init__(self, kv: int, max_slots: int,
                  replica_idx: int | None = None):
         self.kv = int(kv)
-        self.nslots = 0
         self.max_slots = int(max_slots)
-        self.cache = None  # device tree, allocated on first admit
-        self.buf = None    # (S, Tk) int32 token buffer
-        self.pos = np.zeros(0, np.int32)
         self.streams: list = []
+        self.drop()
         self.steps = 0
         self.replica_idx = replica_idx
         # Step wall time not yet flushed to the devtime ledger: lazy
@@ -118,27 +164,40 @@ class PagePool:
         return sum(1 for s in self.streams if s is not None)
 
     def page_bytes(self) -> int:
-        """Resident KV bytes — observability for the freeing tests."""
+        """Resident KV bytes — observability for the freeing tests.
+        Shapes only (``nbytes`` is the aval's): the REST thread may
+        call this while the worker is inside a step, when the tree it
+        finds here has just been donated."""
         import jax
 
-        if self.cache is None:
+        cache = self.cache
+        if cache is None:
             return 0
         return sum(
-            leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.cache)
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(cache)
         )
+
+    def drop(self) -> list:
+        """Forget the device state, back to unallocated (the next admit
+        allocates afresh), and return the streams that were seated.
+        What a failed step leaves behind: it may have consumed the
+        cache and the buffer before it raised, so nothing touches
+        them."""
+        seated = [s for s in self.streams if s is not None]
+        self.cache = None  # device tree, allocated on first admit
+        self.buf = None    # (S, Tk) int32 token buffer
+        self.nslots = 0
+        self.pos = np.zeros(0, np.int32)
+        self.streams = []
+        return seated
 
     def _alloc(self, cache_shapes, nslots: int) -> None:
         import jax
         import jax.numpy as jnp
 
-        def leaf(s):
-            if s.ndim == 0:
-                # cache_index: scalar in the shape probe, per-slot
-                # vector in the pool (the batched decode branch).
-                return jnp.zeros((nslots,), jnp.int32)
-            return jnp.zeros(s.shape, s.dtype)
-
-        self.cache = jax.tree_util.tree_map(leaf, cache_shapes)
+        self.cache = jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), cache_shapes
+        )
         self.buf = jnp.zeros((nslots, self.kv), jnp.int32)
         self.pos = np.zeros(nslots, np.int32)
         self.streams = [None] * nslots

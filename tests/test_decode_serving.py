@@ -461,6 +461,162 @@ class TestDecodeLoopAccounting:
         )
 
 
+class TestStepOwnsItsState:
+    """The step consumes the pool's cache and token buffer (donated,
+    updated in place): what went into a step is gone after it, the
+    engine counts the steps for which that held, and a step that fails
+    AFTER it took its arguments costs its pool's streams and nothing
+    else."""
+
+    @staticmethod
+    def _wrap_steps(monkeypatch, decoder, wrap):
+        """Every step the decoder resolves from here on goes through
+        ``wrap(step)`` (the compiled programs stay in the cache)."""
+        real = decoder._step_for
+
+        def step_for(nslots, kvlen):
+            step, shapes = real(nslots, kvlen)
+            return wrap(step), shapes
+
+        monkeypatch.setattr(decoder, "_step_for", step_for)
+
+    def test_every_step_updates_the_pages_in_place(
+            self, decode_api, monkeypatch):
+        from learningorchestra_tpu.serve.decode.pages import key_pages
+
+        server, _, est = decode_api
+        eng = server.serving.decode
+        decoder = eng._decoder_for("lm_srv")
+        went_in = []
+
+        def spy(step):
+            def spied(variables, cache, buf, *rest):
+                went_in.append((key_pages(cache), buf))
+                return step(variables, cache, buf, *rest)
+            return spied
+
+        self._wrap_steps(monkeypatch, decoder, spy)
+        before = eng.stats()["models"].get("lm_srv") or {
+            "steps": 0, "stepsInPlace": 0,
+        }
+        assert before["stepsInPlace"] == before["steps"]
+        stream = eng.generate(
+            "lm_srv", [5, 3, 2], max_new_tokens=6, stream=True
+        )
+        assert stream.wait_done(60) and stream.error is None
+        after = eng.stats()["models"]["lm_srv"]
+        # 2 prompt steps + 6 output steps, each in place.
+        assert after["steps"] - before["steps"] == len(went_in) == 8
+        assert after["stepsInPlace"] == after["steps"]
+        for pages, buf in went_in:
+            assert pages.is_deleted() and buf.is_deleted()
+        # What the pool holds now is what the last step returned, and
+        # stats() reads its size from shapes alone.
+        pool = decoder._pools[(None, 16)]
+        assert not key_pages(pool.cache).is_deleted()
+        assert not pool.buf.is_deleted()
+        assert pool.page_bytes() > 0
+        solo = np.asarray(est.generate(
+            np.asarray([[5, 3, 2]], np.int32), max_new_tokens=6
+        ))[0].tolist()
+        assert [5, 3, 2] + stream.tokens == solo
+
+    def test_a_step_that_fails_after_donation_costs_its_pool_only(
+            self, decode_api, monkeypatch):
+        import jax
+
+        server, _, est = decode_api
+        eng = server.serving.decode
+        decoder = eng._decoder_for("lm_srv")
+        broken = threading.Event()
+
+        def breaking(step):
+            def stepped(variables, cache, buf, *rest):
+                if not broken.is_set():
+                    return step(variables, cache, buf, *rest)
+                # What a device fault mid-step leaves: the arguments
+                # taken, nothing returned.
+                for leaf in jax.tree_util.tree_leaves((cache, buf)):
+                    leaf.delete()
+                raise RuntimeError("chip fell over")
+            return stepped
+
+        self._wrap_steps(monkeypatch, decoder, breaking)
+        try:
+            faults.arm(
+                "serve.decode_step", "delay", delay_ms=30,
+                max_triggers=256,
+            )
+            streams = [
+                eng.generate("lm_srv", prompt, max_new_tokens=12,
+                             stream=True)
+                for prompt in ([7, 2, 4, 1], [3, 9, 1, 5])
+            ]
+            deadline = time.monotonic() + 30
+            while time.monotonic() < deadline and not all(
+                len(s.tokens) >= 2 for s in streams
+            ):
+                time.sleep(0.005)
+            assert all(0 < len(s.tokens) < 12 for s in streams)
+            broken.set()
+            for s in streams:
+                assert s.wait_done(30)
+        finally:
+            faults.reset()
+        for s in streams:
+            assert "chip fell over" in (s.error or ""), s.error
+        st = eng.stats()["models"]["lm_srv"]
+        assert st["activeStreams"] == 0
+        dropped = [p for p in st["pools"] if p["kv"] == 16]
+        assert [(p["pageBytes"], p["slots"], p["live"]) for p in dropped] \
+            == [(0, 0, 0)]
+        assert decoder._thread is not None and decoder._thread.is_alive()
+        steps_failed = st["steps"]
+
+        # The next request is seated in a pool allocated afresh.
+        broken.clear()
+        prompt = [3, 9, 1, 5, 2, 8, 4, 6]
+        out = eng.generate("lm_srv", [prompt], max_new_tokens=8)
+        solo = np.asarray(est.generate(
+            np.asarray([prompt], np.int32), max_new_tokens=8
+        ))[0].tolist()
+        assert out["tokens"][0] == solo
+        st = eng.stats()["models"]["lm_srv"]
+        assert st["steps"] > steps_failed
+        assert [p["pageBytes"] > 0 for p in st["pools"] if p["kv"] == 16] \
+            == [True]
+
+
+    def test_warming_a_replica_steps_a_throwaway_pool(self, decode_api):
+        """``warm_replica`` runs every recorded (S, Tk) step once on a
+        pool of its own: what that step consumes the warm-up owned, and
+        the pools that serve are as they were."""
+        from types import SimpleNamespace
+
+        server, _, est = decode_api
+        eng = server.serving.decode
+        prompt = [4, 4, 2, 1]
+        solo = np.asarray(est.generate(
+            np.asarray([prompt], np.int32), max_new_tokens=6
+        ))[0].tolist()
+        assert eng.generate(
+            "lm_srv", [prompt], max_new_tokens=6
+        )["tokens"][0] == solo
+        entry = server.serving.registry.get("lm_srv")
+        assert entry.decode_warm
+        before = eng.stats()["models"]["lm_srv"]
+        replica = SimpleNamespace(
+            idx=0, place=lambda entry, _x: (entry.params, None)
+        )
+        eng.warm_replica("lm_srv", replica)
+        after = eng.stats()["models"]["lm_srv"]
+        assert after["steps"] == before["steps"]
+        assert after["pools"] == before["pools"]
+        assert eng.generate(
+            "lm_srv", [prompt], max_new_tokens=6
+        )["tokens"][0] == solo
+
+
 class TestDecodeSLO:
     def test_ttft_objective_fires_on_slow_decode(self):
         """The decode-TTFT objective drives the same burn-rate
