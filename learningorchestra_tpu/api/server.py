@@ -1316,6 +1316,16 @@ class APIServer:
                 kwargs["top_k"] = int(body["topK"])
             if body.get("topP") is not None:
                 kwargs["top_p"] = float(body["topP"])
+            # Generation by diffusion over blocks; any other model
+            # answers 406 to them.
+            if body.get("denoisingSteps") is not None:
+                kwargs["denoising_steps"] = int(body["denoisingSteps"])
+            if body.get("remasking") is not None:
+                kwargs["remasking"] = str(body["remasking"])
+            if body.get("confidenceThreshold") is not None:
+                kwargs["confidence_threshold"] = float(
+                    body["confidenceThreshold"]
+                )
             try:
                 result = self.serving.generate(
                     m.group("name"), prompts, **kwargs
@@ -1350,11 +1360,15 @@ class APIServer:
         add("DELETE",
             rf"/serve/{NAME}/generate/(?P<stream>[A-Za-z0-9]+)",
             serve_generate_abort)
+        # ``no_timeout``: a load lasts as long as the artifact is large
+        # (10 GB of parameters: ten seconds to read and place), and it
+        # is the call that takes that wait off the first request.
         add(
             "POST", rf"/serve/{NAME}/load",
             lambda m, b, q: (
                 200, {"result": self.serving.load(m.group("name"))},
             ),
+            no_timeout=True,
         )
 
         def serve_unload(m, body, query):

@@ -815,7 +815,10 @@ class _Serve:
                  max_new_tokens: int = 32, stream: bool = False,
                  temperature: float | None = None,
                  top_k: int | None = None, top_p: float | None = None,
-                 seed: int = 0, timeout: float | None = None):
+                 seed: int = 0, timeout: float | None = None,
+                 denoising_steps: int | None = None,
+                 remasking: str | None = None,
+                 confidence_threshold: float | None = None):
         """Autoregressive decode against a resident LM.
 
         Non-stream (default): POST /serve/<model>/generate, returns
@@ -827,7 +830,13 @@ class _Serve:
         terminated by ``("done", summary)`` / ``("error", ...)`` /
         ``("aborted", ...)``.  Closing the generator drops the socket,
         which the server treats as a client abort (KV pages freed at
-        the next decode step)."""
+        the next decode step).
+
+        ``denoising_steps``, ``remasking`` and ``confidence_threshold``
+        go to a model that generates by diffusion over blocks (its
+        token events carry ``s``, the denoising step each token was
+        fixed at, and a block's tokens arrive together on its commit);
+        any other model refuses them."""
         body: dict = {
             "prompts": prompts,
             "maxNewTokens": int(max_new_tokens),
@@ -839,6 +848,12 @@ class _Serve:
             body["topK"] = top_k
         if top_p is not None:
             body["topP"] = top_p
+        if denoising_steps is not None:
+            body["denoisingSteps"] = int(denoising_steps)
+        if remasking is not None:
+            body["remasking"] = remasking
+        if confidence_threshold is not None:
+            body["confidenceThreshold"] = float(confidence_threshold)
         if not stream:
             return self.ctx.request(
                 "POST", f"/serve/{model}/generate", body

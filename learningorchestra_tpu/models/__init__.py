@@ -26,6 +26,7 @@ from learningorchestra_tpu.models.text import (
 )
 from learningorchestra_tpu.models.longcontext import LongContextTransformer
 from learningorchestra_tpu.models.moe import (
+    BlockDiffusionMoELM,
     MoEDecoderLM,
     MoETransformerClassifier,
 )
@@ -44,5 +45,6 @@ __all__ = [
     "DecoderLM",
     "LongContextTransformer",
     "MoEDecoderLM",
+    "BlockDiffusionMoELM",
     "MoETransformerClassifier",
 ]

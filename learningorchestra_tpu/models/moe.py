@@ -26,8 +26,8 @@ from learningorchestra_tpu.models.text import (
     cls_head,
     embed_tokens,
 )
-from learningorchestra_tpu.ops.layers import MultiHeadSelfAttention
-from learningorchestra_tpu.ops.moe import MoEMlp
+from learningorchestra_tpu.ops.layers import MultiHeadSelfAttention, RMSNorm
+from learningorchestra_tpu.ops.moe import MoEMlp, RoutedExperts
 from learningorchestra_tpu.toolkit.registry import register
 from learningorchestra_tpu.train.neural import NeuralEstimator
 
@@ -247,3 +247,257 @@ class MoEDecoderLM(GreedyDecodeMixin, NeuralEstimator):
             learning_rate=learning_rate,
             seed=seed,
         )
+
+
+class RoutedExpertBlock(nn.Module):
+    """Pre-RMSNorm block: grouped-query attention with per-head q/k
+    norms and rotary positions, then dropless routed SwiGLU experts."""
+
+    hidden_dim: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    expert_dim: int
+    num_experts: int
+    top_k: int
+    experts_held: tuple | None = None
+    block: int | None = None
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype | None = None
+    param_dtype: jnp.dtype = jnp.float32
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, key_mask=None):
+        def norm(name):
+            return RMSNorm(self.norm_eps, dtype=self.dtype,
+                           param_dtype=self.param_dtype, name=name)
+
+        y = MultiHeadSelfAttention(
+            num_heads=self.num_heads,
+            qkv_features=self.hidden_dim,
+            num_kv_heads=self.num_kv_heads,
+            head_dim=self.head_dim,
+            use_bias=False,
+            qk_norm=True,
+            qk_norm_eps=self.norm_eps,
+            rope=True,
+            rope_theta=self.rope_theta,
+            causal=self.block is None,
+            block=self.block,
+            dtype=self.dtype,
+            param_dtype=self.param_dtype,
+            decode=self.decode,
+        )(norm("attn_norm")(x), key_mask=key_mask)
+        x = x + y
+        y = RoutedExperts(
+            num_experts=self.num_experts,
+            expert_dim=self.expert_dim,
+            top_k=self.top_k,
+            held=self.experts_held,
+            dtype=self.dtype,
+            param_dtype=self.param_dtype,
+        )(norm("moe_norm")(x))
+        return x + y
+
+
+class _BlockDiffusionMoE(nn.Module):
+    """Token embedding, :class:`RoutedExpertBlock` layers, final
+    RMSNorm and an untied bias-free head.  ``block_length`` positions
+    form a block: attention is causal over blocks and full inside one,
+    and a position's own logits predict it (no shift).  With
+    ``block_length`` None the same stack is a plain causal LM."""
+
+    vocab_size: int
+    hidden_dim: int
+    num_layers: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    expert_dim: int
+    num_experts: int
+    top_k: int
+    max_len: int
+    block_length: int | None = 4
+    experts_held: tuple | None = None
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype | None = None
+    param_dtype: jnp.dtype = jnp.float32
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, tokens, positions=None, key_mask=None):
+        # ``positions`` is the decode step's; attention takes them from
+        # its own cache index.
+        del positions
+        tokens = tokens.astype(jnp.int32)
+        x = nn.Embed(
+            self.vocab_size, self.hidden_dim, dtype=self.dtype,
+            param_dtype=self.param_dtype,
+        )(tokens)
+        if key_mask is None:
+            key_mask = tokens != 0  # (B, T), pad id 0
+        for i in range(self.num_layers):
+            x = RoutedExpertBlock(
+                hidden_dim=self.hidden_dim,
+                num_heads=self.num_heads,
+                num_kv_heads=self.num_kv_heads,
+                head_dim=self.head_dim,
+                expert_dim=self.expert_dim,
+                num_experts=self.num_experts,
+                top_k=self.top_k,
+                experts_held=self.experts_held,
+                block=self.block_length,
+                rope_theta=self.rope_theta,
+                norm_eps=self.norm_eps,
+                dtype=self.dtype,
+                param_dtype=self.param_dtype,
+                decode=self.decode,
+                name=f"RoutedExpertBlock_{i}",
+            )(x, key_mask=key_mask)
+        x = RMSNorm(self.norm_eps, dtype=self.dtype,
+                    param_dtype=self.param_dtype, name="final_norm")(x)
+        return nn.Dense(
+            self.vocab_size, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, name="head",
+        )(x)  # (B, T, V)
+
+
+@register(_MODULE)
+class BlockDiffusionMoELM(NeuralEstimator):
+    """Sparse-expert LM that generates by diffusion over blocks
+    (``serve/decode/blocks.py`` has the procedure): RMSNorm, grouped-
+    query attention with q/k norms and rotary positions, dropless
+    top-k routed SwiGLU experts, an untied head.
+
+    ``param_dtype`` is the dtype its parameters are held in, in the
+    artifact, in the serving registry and on the device alike
+    (``bfloat16`` as such models are published); K/V pages follow it.
+    Router, norm statistics, attention's softmax and the confidences
+    are float32 whatever it says.
+
+    ``generate`` here is the plain procedure, one full forward of the
+    whole buffer a denoising step; the decode engine serves the same
+    tokens through its page pools, a block a step.
+    """
+
+    def __init__(
+        self,
+        vocab_size: int = 32000,
+        hidden_dim: int = 256,
+        num_layers: int = 4,
+        num_heads: int = 8,
+        num_kv_heads: int = 2,
+        head_dim: int = 32,
+        expert_dim: int = 128,
+        num_experts: int = 8,
+        experts_per_token: int = 2,
+        max_len: int = 1024,
+        block_length: int = 4,
+        denoising_steps: int = 4,
+        remasking: str = "low_confidence_dynamic",
+        confidence_threshold: float = 0.9,
+        mask_token_id: int | None = None,
+        experts_held: tuple | None = None,
+        rope_theta: float = 1e6,
+        norm_eps: float = 1e-6,
+        param_dtype: str = "bfloat16",
+        learning_rate: float = 3e-4,
+        seed: int = 0,
+    ):
+        if max_len % block_length:
+            raise ValueError("max_len must be a multiple of block_length")
+        self.vocab_size = vocab_size
+        self.hidden_dim = hidden_dim
+        self.num_layers = num_layers
+        self.num_heads = num_heads
+        self.num_kv_heads = num_kv_heads
+        self.head_dim = head_dim
+        self.expert_dim = expert_dim
+        self.num_experts = num_experts
+        self.experts_per_token = experts_per_token
+        self.max_len = max_len
+        self.block_length = block_length
+        self.denoising_steps = denoising_steps
+        self.remasking = remasking
+        self.confidence_threshold = confidence_threshold
+        self.mask_token_id = vocab_size - 1 if mask_token_id is None \
+            else mask_token_id
+        self.experts_held = None if experts_held is None \
+            else tuple(experts_held)
+        self.rope_theta = rope_theta
+        self.norm_eps = norm_eps
+        self.param_dtype = param_dtype
+        dtype = jnp.dtype(param_dtype)
+        super().__init__(
+            _BlockDiffusionMoE(
+                vocab_size=vocab_size,
+                hidden_dim=hidden_dim,
+                num_layers=num_layers,
+                num_heads=num_heads,
+                num_kv_heads=num_kv_heads,
+                head_dim=head_dim,
+                expert_dim=expert_dim,
+                num_experts=num_experts,
+                top_k=experts_per_token,
+                max_len=max_len,
+                block_length=block_length,
+                experts_held=self.experts_held,
+                rope_theta=rope_theta,
+                norm_eps=norm_eps,
+                dtype=dtype,
+                param_dtype=dtype,
+            ),
+            loss="softmax_ce",
+            learning_rate=learning_rate,
+            seed=seed,
+            compute_dtype=param_dtype,
+        )
+
+    def generate(self, prompts, max_new_tokens: int = 32,
+                 denoising_steps: int | None = None,
+                 remasking: str | None = None,
+                 confidence_threshold: float | None = None,
+                 temperature=None, top_k=None, top_p=None, seed: int = 0):
+        """Greedy continuation of int32 prompts (B, T0) by diffusion
+        over blocks, the whole buffer forwarded anew each step."""
+        import jax
+        import numpy as np
+
+        from learningorchestra_tpu.serve.decode.blocks import (
+            BlockPlan,
+            BlockState,
+        )
+
+        if temperature is not None or top_k is not None \
+                or top_p is not None:
+            raise ValueError(
+                "block-diffusion generation is greedy: no temperature, "
+                "top_k or top_p"
+            )
+        plan = BlockPlan(self, denoising_steps, remasking,
+                         confidence_threshold)
+        prompts = np.asarray(prompts, np.int32)
+        bsz, t0 = prompts.shape
+        b = plan.block
+        total = min(self.max_len, -(-(t0 + max_new_tokens) // b) * b)
+        apply = jax.jit(self.module.apply)
+        out = np.full((bsz, total), plan.mask_id, np.int32)
+        out[:, :t0] = prompts
+        for row in out:
+            for start in range(t0 // b * b, total, b):
+                state = BlockState(plan, row[start: min(max(t0, start), start + b)])
+                while not state.final:
+                    row[start: start + b] = state.tokens
+                    logits = np.asarray(
+                        apply(self.params, row[None])[0, start: start + b],
+                        np.float32,
+                    )
+                    x0 = logits.argmax(-1)
+                    top = logits.max(-1, keepdims=True)
+                    conf = 1.0 / np.exp(logits - top).sum(-1)
+                    state.denoise(x0, conf)
+                row[start: start + b] = state.tokens
+        return out[:, : min(total, t0 + max_new_tokens)]

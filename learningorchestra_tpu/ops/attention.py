@@ -69,7 +69,7 @@ def _validate_window(window, causal) -> None:
 
 
 def mha_reference(q, k, v, key_mask=None, causal: bool = False,
-                  window: int | None = None):
+                  window: int | None = None, block: int | None = None):
     """Plain multi-head attention. q,k,v: (B, H, T, D); key_mask: (B, Tk).
 
     Fully-masked rows output exactly 0 with exactly-0 gradients.  The
@@ -79,8 +79,12 @@ def mha_reference(q, k, v, key_mask=None, causal: bool = False,
     ``causal=True`` additionally masks keys beyond each query's position
     (decoder self-attention; Tq must equal Tk); ``window`` restricts each
     query to its last ``window`` positions (sliding-window attention).
+    ``block`` masks by blocks of that many positions instead: query i
+    sees key j iff ``j // block <= i // block`` (Tq must equal Tk).
     """
     _validate_window(window, causal)
+    if block is not None and causal:
+        raise ValueError("block attention is its own mask: causal=False")
     scale = 1.0 / (q.shape[-1] ** 0.5)
     s = jnp.einsum(
         "bhqd,bhkd->bhqk",
@@ -99,6 +103,12 @@ def mha_reference(q, k, v, key_mask=None, causal: bool = False,
             tri = tri & (cols > rows - window)
         maskb = tri[None, None] if maskb is None else (
             maskb & tri[None, None]
+        )
+    if block is not None:
+        blk = (jnp.arange(tk)[None, :] // block) \
+            <= (jnp.arange(tq)[:, None] // block)
+        maskb = blk[None, None] if maskb is None else (
+            maskb & blk[None, None]
         )
     if maskb is None:
         p = jax.nn.softmax(s, axis=-1)

@@ -68,18 +68,43 @@ def apply_rope(x, positions, theta: float = 10000.0):
     return out.astype(x.dtype)
 
 
-def _grouped_decode_attend(q, k, v, key_mask):
-    """Single-position attention against a (possibly grouped) KV cache.
+class RMSNorm(nn.Module):
+    """Weight-only root-mean-square norm over the last axis; the mean
+    of squares is taken in float32 whatever the input's dtype."""
 
-    q: (B, H, 1, hd); k/v: (B, H_kv, Tk, hd) with H_kv | H.  Queries
-    attend their group's KV head DIRECTLY — no jnp.repeat widening of
-    the cache, so per-step HBM traffic stays at H_kv (the point of
-    GQA).  key_mask (B, Tk) always marks at least the current position.
+    eps: float = 1e-6
+    dtype: jnp.dtype | None = None
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param(
+            "scale", nn.initializers.ones, (x.shape[-1],), self.param_dtype
+        )
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps
+        )
+        dt = self.dtype if self.dtype is not None else x.dtype
+        return (y * scale.astype(jnp.float32)).astype(dt)
+
+
+def _grouped_decode_attend(q, k, v, key_mask):
+    """A chunk of query positions against a (possibly grouped) KV cache.
+
+    q: (B, H, t, hd), t = 1 for the one-token step; k/v: (B, H_kv, Tk,
+    hd) with H_kv | H.  Queries attend their group's KV head DIRECTLY
+    — no jnp.repeat widening of the cache, so per-step HBM traffic
+    stays at H_kv (the point of GQA).  key_mask is (B, Tk), one mask
+    for the whole chunk, or (B, t, Tk), one a query position; it always
+    marks at least the current position.
     """
-    b, h, _, hd = q.shape
+    b, h, t, hd = q.shape
     kv_heads, tk = k.shape[1], k.shape[2]
     gsz = h // kv_heads
-    qg = q.reshape(b, kv_heads, gsz, hd)
+    # (group, position) ride one axis: at t = 1 the program is the
+    # one-token step's, einsum for einsum.
+    qg = q.reshape(b, kv_heads, gsz * t, hd)
     s = jnp.einsum(
         "bhgd,bhkd->bhgk",
         qg.astype(jnp.float32), k.astype(jnp.float32),
@@ -90,7 +115,13 @@ def _grouped_decode_attend(q, k, v, key_mask):
         # Same double-where contract as mha_reference: fully-masked
         # rows (left-padded prompts at step 0) output exactly 0, not
         # the mean of the cache buffer.
-        maskb = key_mask.astype(bool)[:, None, None, :]
+        maskb = key_mask.astype(bool)
+        if maskb.ndim == 2:
+            maskb = maskb[:, None, None, :]
+        else:  # (B, t, Tk): the same mask for every head of a group
+            maskb = jnp.broadcast_to(
+                maskb[:, None, None], (b, 1, gsz, t, tk)
+            ).reshape(b, 1, gsz * t, tk)
         m = jnp.max(jnp.where(maskb, s, -1e30), axis=-1, keepdims=True)
         m = jnp.where(m > -5e29, m, 0.0)
         p = jnp.exp(jnp.where(maskb, s - m, -1e30))
@@ -98,7 +129,7 @@ def _grouped_decode_attend(q, k, v, key_mask):
             jnp.sum(p, axis=-1, keepdims=True), 1e-30
         )
     out = jnp.einsum("bhgk,bhkd->bhgd", p, v.astype(jnp.float32))
-    return out.reshape(b, h, 1, hd).astype(q.dtype)
+    return out.reshape(b, h, t, hd).astype(q.dtype)
 
 
 class MultiHeadSelfAttention(nn.Module):
@@ -152,13 +183,34 @@ class MultiHeadSelfAttention(nn.Module):
     # ops.layers.migrate_separate_qkv (applied automatically on the
     # estimator load paths).
     fused_qkv: bool = True
+    # Width of a head where it is not qkv_features / num_heads (the
+    # heads' concatenation is then wider or narrower than the model).
+    head_dim: int | None = None
+    use_bias: bool = True
+    rope_theta: float = 10000.0
+    # Weight-only RMS norm of every head of q and k (over head_dim,
+    # statistics in float32) before the rotation.
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-6
+    # Attention in blocks of this many positions: position i sees j iff
+    # block(j) <= block(i) (causal over blocks, full inside one).  In
+    # decode mode a chunk of t positions is then one block: all of its
+    # queries see every slot up to the chunk's last.  None is plain
+    # causal, also inside a decode chunk.
+    block: int | None = None
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x, key_mask=None):
         b, t, _ = x.shape
-        head_dim = self.qkv_features // self.num_heads
-        if head_dim * self.num_heads != self.qkv_features:
-            raise ValueError("qkv_features must be divisible by num_heads")
+        head_dim = self.head_dim
+        if head_dim is None:
+            head_dim = self.qkv_features // self.num_heads
+            if head_dim * self.num_heads != self.qkv_features:
+                raise ValueError(
+                    "qkv_features must be divisible by num_heads"
+                )
+        inner = self.num_heads * head_dim
         kv_heads = self.num_heads if self.num_kv_heads is None \
             else self.num_kv_heads
         if kv_heads < 1:
@@ -172,7 +224,8 @@ class MultiHeadSelfAttention(nn.Module):
         if self.fused_qkv:
             qkv = nn.DenseGeneral(
                 (self.num_heads + 2 * kv_heads, head_dim),
-                dtype=self.dtype, name="qkv",
+                dtype=self.dtype, name="qkv", use_bias=self.use_bias,
+                param_dtype=self.param_dtype,
             )(x).transpose(0, 2, 1, 3)  # (B, H+2H_kv, T, hd)
             q = qkv[:, : self.num_heads]
             k = qkv[:, self.num_heads: self.num_heads + kv_heads]
@@ -180,20 +233,33 @@ class MultiHeadSelfAttention(nn.Module):
         else:
             def proj(name, heads):
                 y = nn.DenseGeneral(
-                    (heads, head_dim), dtype=self.dtype, name=name
+                    (heads, head_dim), dtype=self.dtype, name=name,
+                    use_bias=self.use_bias, param_dtype=self.param_dtype,
                 )(x)
                 return y.transpose(0, 2, 1, 3)  # (B, heads, T, hd)
 
             q = proj("query", self.num_heads)
             k = proj("key", kv_heads)
             v = proj("value", kv_heads)
+        if self.qk_norm:
+            q = RMSNorm(self.qk_norm_eps, param_dtype=self.param_dtype,
+                        name="q_norm")(q)
+            k = RMSNorm(self.qk_norm_eps, param_dtype=self.param_dtype,
+                        name="k_norm")(k)
         is_initialized = self.decode and self.has_variable(
             "cache", "cached_key"
         )
         if self.rope and not is_initialized:
             pos = jnp.arange(t)
-            q = apply_rope(q, pos)
-            k = apply_rope(k, pos)
+            q = apply_rope(q, pos, self.rope_theta)
+            k = apply_rope(k, pos, self.rope_theta)
+
+        def out_proj(out):
+            out = out.transpose(0, 2, 1, 3).reshape(b, t, inner)
+            return nn.DenseGeneral(
+                self.qkv_features, dtype=self.dtype, name="out",
+                use_bias=self.use_bias, param_dtype=self.param_dtype,
+            )(out)
 
         def widen(kv):
             # Broadcast each KV head to its query-head group.  The
@@ -227,22 +293,34 @@ class MultiHeadSelfAttention(nn.Module):
                     # Rotate at the CURRENT position before caching —
                     # the cache holds rotated keys, so lookups need no
                     # re-rotation.
-                    pos1 = idx[:, None] if batched_idx \
-                        else jnp.full((1,), idx)
-                    q = apply_rope(q, pos1)
-                    k = apply_rope(k, pos1)
-                if t != 1:
-                    # Multi-token chunks would need an intra-chunk
-                    # causal mask (the per-batch key_mask has no
-                    # per-query component) — without one, position 0 of
-                    # the chunk would attend to positions 1..t-1.
+                    pos1 = (idx[:, None] if batched_idx
+                            else jnp.full((1,), idx)) + jnp.arange(t)
+                    q = apply_rope(q, pos1, self.rope_theta)
+                    k = apply_rope(k, pos1, self.rope_theta)
+                if t != 1 and not batched_idx:
                     raise ValueError(
-                        "decode mode feeds ONE position per step; got "
-                        f"a {t}-token chunk (prefill runs through the "
-                        "scan one token at a time)"
+                        "a scalar cache_index feeds ONE position per "
+                        f"step; got a {t}-token chunk (chunks need the "
+                        "per-row index of the page pools)"
                     )
                 tk_cache = ck.value.shape[2]
-                if batched_idx:
+                if t != 1:
+                    # A chunk of t positions a row, at idx .. idx+t-1:
+                    # every cache lane takes the chunk's K/V of its own
+                    # offset where it lies inside the chunk, in one
+                    # pass over the pages.
+                    rel = jnp.arange(tk_cache)[None, :] - idx[:, None]
+                    inside = ((rel >= 0) & (rel < t))[:, None, :, None]
+                    lane = jnp.clip(rel, 0, t - 1)[:, None, :, None]
+                    ck.value = jnp.where(
+                        inside, jnp.take_along_axis(k, lane, axis=2),
+                        ck.value,
+                    )
+                    cv.value = jnp.where(
+                        inside, jnp.take_along_axis(v, lane, axis=2),
+                        cv.value,
+                    )
+                elif batched_idx:
                     # Per-row one-hot select writes: row r lands at
                     # slot idx[r].  jnp.where is bit-exact against
                     # dynamic_update_slice for the written lane and
@@ -268,22 +346,41 @@ class MultiHeadSelfAttention(nn.Module):
                 # the layer's invariant, not each decode loop's.
                 slot = jnp.arange(tk_cache)[None, :]
                 bound = idx[:, None] if batched_idx else idx
-                valid = slot <= bound
-                if self.window is not None:
-                    valid = valid & (slot > (bound - self.window))
+                if t == 1:
+                    valid = slot <= bound
+                    if self.window is not None:
+                        valid = valid & (slot > (bound - self.window))
+                else:
+                    # (B, t, Tk): the in-chunk mask.  A block's queries
+                    # all see up to the chunk's last slot; a causal
+                    # chunk's query i sees up to its own.
+                    last = bound[:, :, None] + (
+                        t - 1 if self.block is not None
+                        else jnp.arange(t)[None, :, None]
+                    )
+                    valid = slot[:, None, :] <= last
+                    if self.window is not None:
+                        valid = valid & (
+                            slot[:, None, :] > (last - self.window)
+                        )
+                    if key_mask is not None:
+                        key_mask = key_mask[:, None, :]
                 key_mask = valid if key_mask is None else (
                     key_mask & valid
                 )
-                out = _grouped_decode_attend(
-                    q, ck.value, cv.value, key_mask
-                )
-                out = out.transpose(0, 2, 1, 3).reshape(
-                    b, t, self.qkv_features
-                )
-                return nn.DenseGeneral(
-                    self.qkv_features, dtype=self.dtype, name="out"
-                )(out)
+                with jax.named_scope("block_attend"):
+                    out = _grouped_decode_attend(
+                        q, ck.value, cv.value, key_mask
+                    )
+                return out_proj(out)
 
+        if self.block is not None:
+            # The flash kernel has no block mask: the full forward of a
+            # block model is the plain path (serving runs the cache).
+            out = mha_reference(
+                q, widen(k), widen(v), key_mask, block=self.block
+            )
+            return out_proj(out)
         use_flash = self.use_flash
         if use_flash is None:
             use_flash = jax.default_backend() == "tpu"
@@ -292,10 +389,7 @@ class MultiHeadSelfAttention(nn.Module):
             q, widen(k), widen(v), key_mask,
             causal=self.causal, window=self.window,
         )  # (B,H,T,hd)
-        out = out.transpose(0, 2, 1, 3).reshape(b, t, self.qkv_features)
-        return nn.DenseGeneral(
-            self.qkv_features, dtype=self.dtype, name="out"
-        )(out)
+        return out_proj(out)
 
 
 def migrate_separate_qkv(tree):

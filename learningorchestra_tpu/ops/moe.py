@@ -133,3 +133,128 @@ class MoEMlp(nn.Module):
         h2 = jnp.einsum("ebcm,emh->ebch", h1, w2.astype(dt))
         h2 = h2 + b2.astype(dt)[:, None, None, :]
         return jnp.einsum("btec,ebch->bth", combine.astype(dt), h2)
+
+
+def route_top_k(logits, top_k: int):
+    """Token-choice routing in float32: softmax over every expert, the
+    ``top_k`` largest, their gates renormalised to sum to 1.  Returns
+    (gates (N, k) float32, expert ids (N, k) int32)."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    gates, ids = jax.lax.top_k(probs, top_k)
+    return gates / jnp.sum(gates, axis=-1, keepdims=True), ids
+
+
+def grouped_matmul(rows, weights, sizes):
+    """``rows`` (M, K), sorted so that group ``g`` is a run of
+    ``sizes[g]`` rows, times that group's matrix of ``weights`` (G, K,
+    N): (M, N).  Rows behind the last group come out undefined.
+
+    On the TPU this is jax's shipped megablox kernel where its tiles
+    divide the shapes, with whole-K tiles: with a few rows a group the
+    kernel is bound by the expert matrices it streams, and at the
+    routed layer's shapes (256 rows over 128 experts of 2048 x 768) it
+    ran the three matmuls in 1.52 ms against ``jax.lax.ragged_dot``'s
+    3.08 (88% against 43% of the HBM roofline; PERF.md, PR 29).
+    Anywhere else, and for shapes the tiles do not divide, it is
+    ``ragged_dot``, which XLA lowers on every backend."""
+    m, k = rows.shape
+    n = weights.shape[-1]
+    tiling = (min(m, 128), min(k, 2048), min(n, 1024))
+    if jax.default_backend() == "tpu" and m % 16 == 0 and all(
+        size % tile == 0 and tile % 128 == 0
+        for size, tile in ((k, tiling[1]), (n, tiling[2]))
+    ) and m % tiling[0] == 0:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        return gmm(rows, weights, sizes, rows.dtype, tiling)
+    return jax.lax.ragged_dot(rows, weights, sizes)
+
+
+class RoutedExperts(nn.Module):
+    """Dropless routed SwiGLU experts: every token reaches each of its
+    ``top_k`` experts, whatever the load.
+
+    The (token, choice) pairs are sorted by expert and each of the three
+    expert matrices is ONE grouped matmul over the sorted rows
+    (:func:`grouped_matmul`: group ``e`` is the run of rows routed to
+    expert ``e``), so there is no capacity, no ``(N, E, cap)`` tensor
+    and no dropped token; an expert nobody chose is an empty group.
+
+    ``held`` = (first, count) names the experts whose weights live
+    here, out of the ``num_experts`` the router scores (None: all of
+    them).  The layer routes over all ``num_experts`` and returns the
+    part of the result its own experts give; a choice that lands on an
+    absent expert adds nothing here (its chip adds it).
+
+    The router's matmul and softmax run in float32; the experts in
+    ``dtype``.  Sows ``moe_stats`` (``experts_hit``: experts with at
+    least one row; ``load_max``: rows of the busiest) when that
+    collection is mutable.
+    """
+
+    num_experts: int
+    expert_dim: int
+    top_k: int = 2
+    held: tuple | None = None
+    dtype: jnp.dtype | None = None
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        shape = x.shape
+        h = shape[-1]
+        x = x.reshape(-1, h)
+        n = x.shape[0]
+        first, count = self.held or (0, self.num_experts)
+        k = min(self.top_k, self.num_experts)
+        dt = self.dtype if self.dtype is not None else x.dtype
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+
+        with jax.named_scope("moe_router"):
+            router = self.param(
+                "router", nn.initializers.lecun_normal(),
+                (h, self.num_experts), self.param_dtype,
+            )
+            logits = jnp.matmul(
+                x.astype(jnp.float32), router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            )
+            gates, ids = route_top_k(logits, k)  # (N, k)
+
+        w_gate = self.param("w_gate", init, (count, h, self.expert_dim),
+                            self.param_dtype)
+        w_up = self.param("w_up", init, (count, h, self.expert_dim),
+                          self.param_dtype)
+        w_down = self.param("w_down", init, (count, self.expert_dim, h),
+                            self.param_dtype)
+
+        with jax.named_scope("moe_experts"):
+            local = ids.reshape(-1) - first  # (N*k,)
+            here = (local >= 0) & (local < count)
+            # Choices of absent experts sort behind every group: the
+            # grouped matmul never reads them.
+            local = jnp.where(here, local, count)
+            order = jnp.argsort(local, stable=True)
+            sizes = jnp.bincount(local, length=count + 1)[:count] \
+                .astype(jnp.int32)
+            rows = x.astype(dt)[order // k]  # (N*k, H), sorted by expert
+            up = grouped_matmul(rows, w_up.astype(dt), sizes)
+            gate = grouped_matmul(rows, w_gate.astype(dt), sizes)
+            y = grouped_matmul(
+                nn.silu(gate) * up, w_down.astype(dt), sizes
+            )  # (N*k, H)
+            weight = jnp.where(here, gates.reshape(-1), 0.0)[order]
+            y = jnp.where(
+                weight[:, None] > 0, y.astype(jnp.float32), 0.0
+            ) * weight[:, None]
+            out = jnp.zeros((n * k, h), jnp.float32).at[order].set(y)
+            out = out.reshape(n, k, h).sum(axis=1).astype(dt)
+
+        if self.is_mutable_collection("moe_stats") \
+                and not self.is_initializing():
+            for name, value in (("experts_hit", jnp.sum(sizes > 0)),
+                                ("load_max", jnp.max(sizes))):
+                self.sow("moe_stats", name, value.astype(jnp.int32),
+                         reduce_fn=lambda _, new: new,
+                         init_fn=lambda: jnp.int32(0))
+        return out.reshape(shape)

@@ -30,10 +30,12 @@ from learningorchestra_tpu.obs import tracing as obs_tracing
 from learningorchestra_tpu.obs.metrics import get_registry
 from learningorchestra_tpu.serve.batcher import QueueFull
 from learningorchestra_tpu.serve.bucketing import bucket_for
+from learningorchestra_tpu.serve.decode.blocks import BlockPlan
 from learningorchestra_tpu.serve.decode.pages import (
     PagePool,
     build_step,
     key_pages,
+    step_width,
 )
 from learningorchestra_tpu.serve.decode.streams import DecodeStream
 from learningorchestra_tpu.serve.registry import ServeError
@@ -155,6 +157,19 @@ class _ModelDecoder:
         # stepped: the ``lo:decode.step`` annotation's metadata.
         self._turn = {"prompt": 0, "output": 0, "keys": 0, "slots": 0,
                       "kv": 0, "inplace": 0}
+        # Generation by diffusion over blocks: slot-steps by phase
+        # (whole prompt blocks prefilled, denoising forwards, commits),
+        # positions processed, tokens the denoising forwards fixed, and
+        # what the step program counted of its routed experts
+        # (distinct experts a layer, summed over layers and steps; the
+        # busiest expert's rows in any one step).
+        self.block_steps = {"prefill": 0, "denoise": 0, "commit": 0}
+        self.positions = 0
+        self.tokens_fixed = 0
+        self.experts_hit = 0
+        self.expert_load_max = 0
+        self._block_turn = {"positions": 0, "fixed": 0, "denoise": 0,
+                            "commit": 0, "prefill": 0, "experts_hit": 0}
 
     # -- submission (any thread) --------------------------------------------
 
@@ -246,8 +261,9 @@ class _ModelDecoder:
             t_turn = time.perf_counter()
             before = dict(phases.total)
             turn = self._turn
-            for key in turn:
-                turn[key] = 0
+            for counts in (turn, self._block_turn):
+                for key in counts:
+                    counts[key] = 0
             with obs_tracing.annotation("decode.step") as step_ann:
                 with phases("admit"):
                     with self._cv:
@@ -287,6 +303,8 @@ class _ModelDecoder:
                         # order once capacity frees up.
                         self._pending.extendleft(reversed(deferred))
                 step_ann.set_metadata(**turn)
+                if self._block_turn["positions"]:
+                    step_ann.set_metadata(**self._block_turn)
             turn_s = time.perf_counter() - t_turn
             if turn_s > _SLOW_STEP_S:
                 split = {
@@ -340,13 +358,15 @@ class _ModelDecoder:
             replica = self._route_replica()
             ridx = None if replica is None else replica.idx
             kvlen = bucket_for(
-                stream.total,
+                stream.span,
                 min(self.cfg.max_kv, self._max_len()),
             )
             pool = self._pools.get((ridx, kvlen))
             if pool is None:
                 pool = self._pools[(ridx, kvlen)] = PagePool(
                     kvlen, self.cfg.max_slots, replica_idx=ridx,
+                    width=1 if stream.plan is None
+                    else stream.plan.block,
                 )
                 obs_flight.record(
                     "decode", "pool_grow",
@@ -483,8 +503,9 @@ class _ModelDecoder:
 
     def _step_pool(self, pool: PagePool) -> None:
         from learningorchestra_tpu import faults
-        from learningorchestra_tpu.obs import costs as obs_costs
 
+        if pool.width > 1:
+            return self._step_blocks(pool)
         phases = self.phases
         with phases("dispatch"):
             # The chaos probe stands where the step is dispatched: a
@@ -519,30 +540,9 @@ class _ModelDecoder:
             n_live = int(live.sum())
             n_prompt = int((live & (nxt < t0s)).sum())
             n_keys = int(nxt[live].sum())
-            # Counted here and not at the turn's end: a stream this
-            # step finishes may read stats() before the turn is over.
-            self.prompt_steps += n_prompt
-            self.output_steps += n_live - n_prompt
-            self.keys_attended += n_keys
-            turn = self._turn
-            turn["prompt"] += n_prompt
-            turn["output"] += n_live - n_prompt
-            turn["keys"] += n_keys
-            turn["slots"] += pool.nslots
-            turn["kv"] = max(turn["kv"], pool.kv)
+            self._count(pool, n_prompt, n_live - n_prompt, n_keys)
             t_start = time.perf_counter()
-            # The step consumes the cache and the buffer: from here on
-            # the pool holds only what it returned.
-            went_in = key_pages(pool.cache)
-            pool.cache, pool.buf, col = step(
-                self._params_for(pool), pool.cache, pool.buf,
-                pos_now, t0s, live,
-            )
-            pool.steps += 1
-            self.steps += 1
-            if went_in.is_deleted():
-                self.steps_in_place += 1
-                turn["inplace"] += 1
+            col = self._call(pool, step, pos_now, t0s, live)
         with phases("sync"):
             col_host = None
             if eager or pool.steps % _SYNC_STRIDE == 0:
@@ -599,19 +599,138 @@ class _ModelDecoder:
             # HERE (past the row reads above) captures the stride's
             # full cost as one amortized sample.
             pool.pending_devtime += time.perf_counter() - t_start
-            synced = col_host is not None or bool(rows)
-            if synced and obs_costs.enabled():
-                led = obs_costs.devtime()
-                weight = led.will_record(self.name)
-                if weight:
-                    led.record_model(
-                        weight, pool.pending_devtime, None, None,
-                        self.name, f"dec{pool.nslots}x{pool.kv}",
-                    )
-                pool.pending_devtime = 0.0
+            if col_host is not None or rows:
+                self._flush_devtime(pool)
+
+    def _count(self, pool: PagePool, prompt: int, output: int,
+               keys: int) -> None:
+        """A step's slot-steps and attended keys, into the cumulative
+        counters and the turn's annotation.  Counted at dispatch and
+        not at the turn's end: a stream this step finishes may read
+        stats() before the turn is over."""
+        self.prompt_steps += prompt
+        self.output_steps += output
+        self.keys_attended += keys
+        turn = self._turn
+        turn["prompt"] += prompt
+        turn["output"] += output
+        turn["keys"] += keys
+        turn["slots"] += pool.nslots
+        turn["kv"] = max(turn["kv"], pool.kv)
+
+    def _call(self, pool: PagePool, step, *slots, **block):
+        """Enqueue the pool's step.  The step consumes the cache and
+        the buffer: from here on the pool holds only what it
+        returned."""
+        went_in = key_pages(pool.cache)
+        pool.cache, pool.buf, col = step(
+            self._params_for(pool), pool.cache, pool.buf, *slots, **block
+        )
+        pool.steps += 1
+        self.steps += 1
+        if went_in.is_deleted():
+            self.steps_in_place += 1
+            self._turn["inplace"] += 1
+        return col
+
+    def _flush_devtime(self, pool: PagePool) -> None:
+        from learningorchestra_tpu.obs import costs as obs_costs
+
+        if obs_costs.enabled():
+            led = obs_costs.devtime()
+            weight = led.will_record(self.name)
+            if weight:
+                led.record_model(
+                    weight, pool.pending_devtime, None, None,
+                    self.name, f"dec{pool.nslots}x{pool.kv}",
+                )
+            pool.pending_devtime = 0.0
+
+    def _step_blocks(self, pool: PagePool) -> None:
+        """One turn of a pool whose model generates by diffusion over
+        blocks (``blocks.py``): every live slot forwards its current
+        block, ``pool.width`` positions.  What the forward was depends
+        on the block's state when it was dispatched: with a mask left
+        it was a denoising forward and the strategy now fixes tokens
+        from its proposals; with none it made the block's K/V final (a
+        whole prompt block's prefill, or a generated block's commit),
+        so the block's tokens go out in order and the slot moves on."""
+        from learningorchestra_tpu import faults
+
+        phases = self.phases
+        q = pool.width
+        with phases("dispatch"):
+            faults.hit("serve.decode_step")
+            step, _ = self._step_for(pool.nslots, pool.kv)
+            live = np.array([s is not None for s in pool.streams], bool)
+            pos_now = pool.pos.copy()
+            block = np.zeros((pool.nslots, q), np.int32)
+            kinds: list = [None] * pool.nslots
+            for slot, state in enumerate(pool.blocks):
+                if state is None:
+                    continue
+                block[slot] = state.tokens
+                kinds[slot] = (
+                    "denoise" if not state.final
+                    else "prefill"
+                    if pos_now[slot] + q <= pool.streams[slot].t0
+                    else "commit"
+                )
+            n_live = int(live.sum())
+            n_keys = q * int((pos_now[live] + q).sum())
+            bturn = self._block_turn
+            by_kind = {kind: kinds.count(kind) for kind in self.block_steps}
+            for kind, n in by_kind.items():
+                self.block_steps[kind] += n
+                bturn[kind] += n
+            self._count(pool, by_kind["prefill"],
+                        n_live - by_kind["prefill"], n_keys)
+            self.positions += q * n_live
+            bturn["positions"] += q * n_live
+            t_start = time.perf_counter()
+            col = self._call(
+                pool, step, pos_now, np.zeros(pool.nslots, np.int32),
+                live, block=block,
+            )
+        with phases("sync"):
+            # The strategy is the host's: every turn reads its step.
+            col_host = np.asarray(col)
+            now = time.perf_counter()
+        with phases("emit"):
+            x0 = col_host[:-1, :q]
+            conf = col_host[:-1, q:].view(np.float32)
+            hit, busiest = int(col_host[-1, 0]), int(col_host[-1, 1])
+            self.experts_hit += hit
+            self.expert_load_max = max(self.expert_load_max, busiest)
+            bturn["experts_hit"] += hit
+            for slot, stream in enumerate(pool.streams):
+                if stream is None:
+                    continue
+                state = pool.blocks[slot]
+                if kinds[slot] == "denoise":
+                    fixed = state.denoise(x0[slot], conf[slot])
+                    self.tokens_fixed += fixed
+                    bturn["fixed"] += fixed
+                    continue
+                start = int(pos_now[slot])
+                if kinds[slot] == "commit":
+                    for j in range(q):
+                        if stream.t0 <= start + j < stream.total:
+                            self._emit(
+                                stream, int(state.tokens[j]), start + j,
+                                now, step=int(state.fixed_at[j]),
+                            )
+                pool.pos[slot] = start + q
+                if start + q >= stream.total:
+                    pool.release(slot)
+                    self._finish(stream)
+                else:
+                    pool.blocks[slot] = stream.block_at(start + q)
+            pool.pending_devtime += time.perf_counter() - t_start
+            self._flush_devtime(pool)
 
     def _emit(self, stream: DecodeStream, tok: int, pos: int,
-              now: float) -> None:
+              now: float, step=None) -> None:
         if stream.first_at is None:
             stream.first_at = now
             _decode_hists.ttft(now - stream.arrived, self.name)
@@ -623,7 +742,7 @@ class _ModelDecoder:
         else:
             _decode_hists.itl(now - stream.last_at, self.name)
         stream.last_at = now
-        stream.push_token(tok, pos)
+        stream.push_token(tok, pos, step)
         _decode_hists.tokens(1, self.name)
 
     def _finish(self, stream: DecodeStream, *, row=None,
@@ -652,11 +771,14 @@ class _ModelDecoder:
             params, _ = replica.place(
                 entry, np.zeros((1, 1), np.int32)
             )
+            width = step_width(entry.estimator.module)
             step(
                 params, pool.cache, pool.buf,
                 np.zeros(nslots, np.int32),
                 np.full(nslots, kvlen + 1, np.int32),
                 np.zeros(nslots, bool),
+                block=None if width == 1
+                else np.zeros((nslots, width), np.int32),
             )
 
     def stats(self) -> dict:
@@ -693,6 +815,16 @@ class _ModelDecoder:
             "phaseMaxS": dict(self.phases.peak),
             "admitted": self.admitted,
             "admitWaitS": self.admit_wait_s,
+            # Generation by diffusion over blocks (all 0 for a
+            # next-token model): slot-steps by phase, positions
+            # processed, tokens the denoising forwards fixed, experts
+            # the steps' rows reached (distinct a layer, summed over
+            # layers and steps), the busiest expert's rows in a step.
+            "blockSteps": dict(self.block_steps),
+            "positions": self.positions,
+            "tokensFixed": self.tokens_fixed,
+            "expertsHit": self.experts_hit,
+            "expertLoadMax": self.expert_load_max,
         }
 
     def close(self) -> None:
@@ -780,9 +912,15 @@ class DecodeEngine:
 
     def _open_stream(self, name: str, decoder: _ModelDecoder,
                      prompt: np.ndarray, max_new: int, max_len: int,
-                     *, eager: bool) -> DecodeStream:
+                     *, eager: bool, plan=None) -> DecodeStream:
         t0 = int(prompt.shape[0])
         cap = min(max_len, self.cfg.max_kv)
+        if plan is not None:
+            cap -= cap % plan.block  # whole blocks of pages
+            if (prompt == plan.mask_id).any():
+                raise ServeError(
+                    f"prompts must not contain the mask id {plan.mask_id}"
+                )
         if t0 >= cap:
             raise ServeError(
                 f"prompt length {t0} exceeds decode capacity {cap} "
@@ -790,20 +928,27 @@ class DecodeEngine:
             )
         max_new = max(1, min(int(max_new), self.cfg.max_new_tokens))
         total = min(cap, t0 + max_new)
-        stream = DecodeStream(name, prompt, t0, total, eager=eager)
+        stream = DecodeStream(name, prompt, t0, total, eager=eager,
+                              plan=plan)
         decoder.submit(stream)
         return stream
 
     def generate(self, name: str, prompts, *,
                  max_new_tokens: int = 32, stream: bool = False,
                  temperature=None, top_k=None, top_p=None,
-                 seed: int = 0):
+                 seed: int = 0, denoising_steps=None, remasking=None,
+                 confidence_threshold=None):
         """Entry point behind ``POST /serve/<model>/generate``.
 
         Greedy decodes run on the resident engine (stream or not);
         sampling parameters fall back to the solo jitted scan
         (non-stream only — a sampled decode has no per-step identity
-        to stream against the engine's greedy executables)."""
+        to stream against the engine's greedy executables).
+
+        ``denoising_steps`` (1..block length), ``remasking`` and
+        ``confidence_threshold`` belong to a model that generates by
+        diffusion over blocks, which is greedy only; any other model
+        refuses them."""
         entry = self.service.registry.get(name)
         estimator = entry.estimator
         if not hasattr(estimator, "generate"):
@@ -816,6 +961,27 @@ class DecodeEngine:
             temperature is not None or top_k is not None
             or top_p is not None
         )
+        block_args = {
+            "denoising_steps": denoising_steps, "remasking": remasking,
+            "confidence_threshold": confidence_threshold,
+        }
+        plan = None
+        if step_width(estimator.module) > 1:
+            if sampling:
+                raise ServeError(
+                    "generation by diffusion over blocks is greedy: "
+                    "drop temperature / topK / topP"
+                )
+            try:
+                plan = BlockPlan(estimator, *block_args.values())
+            except ValueError as exc:
+                raise ServeError(str(exc)) from None
+        elif any(v is not None for v in block_args.values()):
+            raise ServeError(
+                f"{type(estimator).__name__} generates token by token: "
+                "denoisingSteps, remasking and confidenceThreshold "
+                "belong to a block-diffusion model"
+            )
         rows = self._as_prompt_rows(prompts)
         if sampling or not self.cfg.enabled:
             if stream:
@@ -824,10 +990,12 @@ class DecodeEngine:
                     "(greedy only, LO_TPU_DECODE_ENABLED=1); drop the "
                     "sampling parameters or set stream=false"
                 )
+            solo = block_args if plan is not None else {
+                "temperature": temperature, "top_k": top_k,
+                "top_p": top_p, "seed": int(seed),
+            }
             return self._solo_generate(
-                name, entry, rows, max_new_tokens,
-                temperature=temperature, top_k=top_k, top_p=top_p,
-                seed=seed,
+                name, entry, rows, max_new_tokens, **solo
             )
         if stream and len(rows) != 1:
             raise ServeError(
@@ -838,7 +1006,7 @@ class DecodeEngine:
         streams = [
             self._open_stream(
                 name, decoder, row, max_new_tokens, max_len,
-                eager=stream,
+                eager=stream, plan=plan,
             )
             for row in rows
         ]
@@ -874,17 +1042,17 @@ class DecodeEngine:
             "streams": [s.summary() for s in streams],
         }
 
-    def _solo_generate(self, name, entry, rows, max_new_tokens, *,
-                       temperature, top_k, top_p, seed):
-        """Per-shape solo scan fallback (sampling / engine disabled):
-        one call per distinct prompt length so ragged rows stay legal."""
+    def _solo_generate(self, name, entry, rows, max_new_tokens,
+                       temperature=None, **kwargs):
+        """Per-shape solo fallback (sampling / engine disabled), the
+        estimator's own ``generate``: one call per distinct prompt
+        length so ragged rows stay legal."""
         out_tokens: list[list[int]] = []
         for row in rows:
             try:
                 buf = entry.estimator.generate(
                     row[None, :], max_new_tokens=int(max_new_tokens),
-                    temperature=temperature, top_k=top_k, top_p=top_p,
-                    seed=int(seed),
+                    temperature=temperature, **kwargs,
                 )
             except ValueError as exc:
                 # Bad sampling spec (top_k without temperature, ...)

@@ -72,6 +72,24 @@ def key_pages(cache):
     return None
 
 
+def _named(tree, name: str) -> list:
+    """Every value stored under key ``name`` in a nested dict."""
+    out = []
+    for key, val in tree.items():
+        if key == name:
+            out.append(val)
+        elif isinstance(val, dict):
+            out += _named(val, name)
+    return out
+
+
+def step_width(module) -> int:
+    """Positions a slot-step of ``module`` processes: its
+    ``block_length`` where it generates by diffusion over blocks, else
+    1."""
+    return int(getattr(module, "block_length", None) or 1)
+
+
 def build_step(module, nslots: int, kv: int):
     """(step fn, shape tree of the K/V pages a pool carries) for one
     (arch, S, Tk) cell.  ``step(variables, cache, buf, pos, t0s, live)``
@@ -91,17 +109,66 @@ def build_step(module, nslots: int, kv: int):
     same write-at-``pos+1`` — but with per-slot positions, so a slot
     admitted mid-flight produces bit-identical tokens to a solo decode
     of the same prompt (greedy only; sampling stays on the solo path).
+
+    A slot-step is ``q`` positions wide: 1 for a next-token model, the
+    module's ``block_length`` for one that generates by diffusion over
+    blocks (:func:`step_width`).  Such a step takes the block's current
+    tokens from the host, ``q`` more rows of the packed array (the
+    host's turn keeps the block's state), writes them to the buffer at
+    ``pos .. pos+q-1`` and their K/V to the pages (every forward of a
+    block rewrites them; the commit's stay), attends with ``slot <=
+    pos+q-1`` and full sight inside the block, and returns ONE
+    ``(S + 1, 2q)`` int32 array: per slot the ``q`` proposals ``x0``
+    and the bits of their float32 confidences, and in the last row the
+    experts the step's rows reached (distinct experts a layer, summed
+    over layers) and the busiest expert's rows.
     """
     import jax
     import jax.numpy as jnp
 
+    q = step_width(module)
     decode_mod = module.clone(decode=True)
     cache_shapes = strip_index(jax.eval_shape(
         decode_mod.init, jax.random.PRNGKey(0),
         jnp.zeros((nslots, kv), jnp.int32),
     )["cache"])
 
-    def step(variables, cache, buf, slots):
+    def block_step(variables, cache, buf, slots):
+        pos, live = slots[0], slots[2] != 0
+        tok = slots[3:].T  # (S, q): the block as the host has it
+        lanes = pos[:, None] + jnp.arange(q)[None, :]
+        rows = jnp.arange(nslots)[:, None]
+        # A free slot's row stays all-pad: its mask stays empty.
+        buf = buf.at[rows, lanes].set(
+            jnp.where(live[:, None], tok, buf[rows, lanes])
+        )
+        kmask = (jnp.arange(kv)[None, :] <= pos[:, None] + (q - 1)) \
+            & (buf != 0)
+        logits, mut = decode_mod.apply(
+            {**variables, "cache": set_index(cache, pos)}, tok,
+            positions=lanes, key_mask=kmask,
+            mutable=["cache", "moe_stats"],
+        )
+        logits = logits.astype(jnp.float32)  # (S, q, V)
+        top = jnp.max(logits, -1)
+        x0 = jnp.argmax(logits, -1).astype(jnp.int32)
+        # softmax(logits)[x0], in float32
+        conf = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), -1)
+        stats = jnp.zeros(2 * q, jnp.int32)
+        hit = _named(mut.get("moe_stats", {}), "experts_hit")
+        if hit:
+            busiest = _named(mut["moe_stats"], "load_max")
+            stats = stats.at[0].set(sum(hit)) \
+                .at[1].set(jnp.max(jnp.stack(busiest)))
+        col = jnp.concatenate([
+            jnp.concatenate(
+                [x0, jax.lax.bitcast_convert_type(conf, jnp.int32)], 1
+            ),
+            stats[None],
+        ])
+        return strip_index(mut["cache"]), buf, col
+
+    def token_step(variables, cache, buf, slots):
         pos, t0s, live = slots[0], slots[1], slots[2] != 0
         cache = set_index(cache, pos)
         tok = jnp.take_along_axis(buf, pos[:, None], axis=1)
@@ -124,10 +191,19 @@ def build_step(module, nslots: int, kv: int):
         buf = buf.at[jnp.arange(nslots), nxt_pos].set(col)
         return strip_index(mut["cache"]), buf, col
 
+    def step(variables, cache, buf, slots):
+        # One name for the program whatever its width: the trace's
+        # readers find a pool's steps as ``jit_step``.
+        return (token_step if q == 1 else block_step)(
+            variables, cache, buf, slots
+        )
+
     jitted = jax.jit(step, donate_argnums=(1, 2))
 
-    def call(variables, cache, buf, pos, t0s, live):
-        return jitted(variables, cache, buf, np.stack((pos, t0s, live)))
+    def call(variables, cache, buf, pos, t0s, live, block=None):
+        packed = (pos, t0s, live) if block is None \
+            else (pos, t0s, live, *block.T)
+        return jitted(variables, cache, buf, np.stack(packed))
 
     return call, cache_shapes
 
@@ -141,12 +217,17 @@ class PagePool:
     """
 
     __slots__ = ("kv", "nslots", "max_slots", "cache", "buf", "pos",
-                 "streams", "steps", "replica_idx", "pending_devtime")
+                 "streams", "steps", "replica_idx", "pending_devtime",
+                 "width", "blocks")
 
     def __init__(self, kv: int, max_slots: int,
-                 replica_idx: int | None = None):
+                 replica_idx: int | None = None, width: int = 1):
         self.kv = int(kv)
         self.max_slots = int(max_slots)
+        # Positions a slot-step processes (``step_width``); ``pos`` is
+        # then the start of each slot's current block, and ``blocks``
+        # holds that block's state beside each stream.
+        self.width = int(width)
         self.streams: list = []
         self.drop()
         self.steps = 0
@@ -189,6 +270,7 @@ class PagePool:
         self.nslots = 0
         self.pos = np.zeros(0, np.int32)
         self.streams = []
+        self.blocks: list = []
         return seated
 
     def _alloc(self, cache_shapes, nslots: int) -> None:
@@ -201,6 +283,7 @@ class PagePool:
         self.buf = jnp.zeros((nslots, self.kv), jnp.int32)
         self.pos = np.zeros(nslots, np.int32)
         self.streams = [None] * nslots
+        self.blocks = [None] * nslots
         self.nslots = nslots
 
     def _grow(self, cache_shapes, nslots: int) -> None:
@@ -222,6 +305,7 @@ class PagePool:
             [self.pos, np.zeros(extra, np.int32)]
         )
         self.streams.extend([None] * extra)
+        self.blocks.extend([None] * extra)
         self.nslots = nslots
 
     # -- slot lifecycle ------------------------------------------------------
@@ -255,6 +339,7 @@ class PagePool:
         self.buf = self.buf.at[slot].set(row)
         self.pos[slot] = 0
         self.streams[slot] = stream
+        self.blocks[slot] = stream.block_at(0)
         return slot
 
     def release(self, slot: int) -> None:
@@ -263,6 +348,7 @@ class PagePool:
         still hold is unreachable — the pages are free for the next
         admit without a scrub pass."""
         self.streams[slot] = None
+        self.blocks[slot] = None
         self.pos[slot] = 0
         if self.buf is not None:
             self.buf = self.buf.at[slot].set(0)

@@ -22,6 +22,7 @@ import time
 import uuid
 
 from learningorchestra_tpu.jobs.cancel import CancelToken
+from learningorchestra_tpu.serve.decode.blocks import BlockState
 
 #: Event-queue bound: ``total`` tokens plus lifecycle events always
 #: fit, but a reader that stopped draining must not grow memory.
@@ -34,16 +35,22 @@ class DecodeStream:
     __slots__ = (
         "stream_id", "model", "prompt", "t0", "total", "eager",
         "token", "events", "arrived", "first_at", "last_at",
-        "tokens", "error", "_done",
+        "tokens", "error", "_done", "plan", "span",
     )
 
     def __init__(self, model: str, prompt, t0: int, total: int,
-                 *, eager: bool):
+                 *, eager: bool, plan=None):
         self.stream_id = uuid.uuid4().hex[:12]
         self.model = model
         self.prompt = prompt  # int32 (t0,) host array
         self.t0 = int(t0)
         self.total = int(total)
+        # Generation by diffusion over blocks (``blocks.BlockPlan``;
+        # None for a next-token model): the sequence then takes whole
+        # blocks of pages, ``span`` positions for ``total`` tokens.
+        self.plan = plan
+        self.span = self.total if plan is None \
+            else -(-self.total // plan.block) * plan.block
         # eager: the transport wants every token as it lands (SSE), so
         # the worker syncs the step's token column to host each step.
         # Lazy streams let dispatch run ahead; tokens surface at done.
@@ -66,9 +73,24 @@ class DecodeStream:
             pass  # reader stopped draining; terminal state still lands
         # via _done / token, which the transports consult.
 
-    def push_token(self, tok: int, pos: int) -> None:
+    def block_at(self, start: int):
+        """The state of the block that starts at ``start``, with the
+        prompt's tokens that fall in it already fixed; None for a
+        next-token model."""
+        if self.plan is None:
+            return None
+        return BlockState(
+            self.plan, self.prompt[start: start + self.plan.block]
+        )
+
+    def push_token(self, tok: int, pos: int, step=None) -> None:
+        """``step``: the denoising step the token was fixed at, where
+        the model generates by diffusion over blocks."""
         self.tokens.append(tok)
-        self._push("token", {"t": tok, "i": pos})
+        doc = {"t": tok, "i": pos}
+        if step is not None:
+            doc["s"] = step
+        self._push("token", doc)
 
     def finish(self) -> None:
         self._push("done", self.summary())
