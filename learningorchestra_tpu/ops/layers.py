@@ -17,6 +17,13 @@ from learningorchestra_tpu.ops.attention import (
     flash_attention,
     mha_reference,
 )
+from learningorchestra_tpu.ops.decode_attention import (
+    cached_attend,
+    grouped_decode_attend,
+    pack_pages,
+    page_shape,
+    unpack_pages,
+)
 
 
 def remat_block(cls, remat):
@@ -87,49 +94,6 @@ class RMSNorm(nn.Module):
         )
         dt = self.dtype if self.dtype is not None else x.dtype
         return (y * scale.astype(jnp.float32)).astype(dt)
-
-
-def _grouped_decode_attend(q, k, v, key_mask):
-    """A chunk of query positions against a (possibly grouped) KV cache.
-
-    q: (B, H, t, hd), t = 1 for the one-token step; k/v: (B, H_kv, Tk,
-    hd) with H_kv | H.  Queries attend their group's KV head DIRECTLY
-    — no jnp.repeat widening of the cache, so per-step HBM traffic
-    stays at H_kv (the point of GQA).  key_mask is (B, Tk), one mask
-    for the whole chunk, or (B, t, Tk), one a query position; it always
-    marks at least the current position.
-    """
-    b, h, t, hd = q.shape
-    kv_heads, tk = k.shape[1], k.shape[2]
-    gsz = h // kv_heads
-    # (group, position) ride one axis: at t = 1 the program is the
-    # one-token step's, einsum for einsum.
-    qg = q.reshape(b, kv_heads, gsz * t, hd)
-    s = jnp.einsum(
-        "bhgd,bhkd->bhgk",
-        qg.astype(jnp.float32), k.astype(jnp.float32),
-    ) * (1.0 / hd ** 0.5)  # (B, H_kv, G, Tk)
-    if key_mask is None:
-        p = jax.nn.softmax(s, axis=-1)
-    else:
-        # Same double-where contract as mha_reference: fully-masked
-        # rows (left-padded prompts at step 0) output exactly 0, not
-        # the mean of the cache buffer.
-        maskb = key_mask.astype(bool)
-        if maskb.ndim == 2:
-            maskb = maskb[:, None, None, :]
-        else:  # (B, t, Tk): the same mask for every head of a group
-            maskb = jnp.broadcast_to(
-                maskb[:, None, None], (b, 1, gsz, t, tk)
-            ).reshape(b, 1, gsz * t, tk)
-        m = jnp.max(jnp.where(maskb, s, -1e30), axis=-1, keepdims=True)
-        m = jnp.where(m > -5e29, m, 0.0)
-        p = jnp.exp(jnp.where(maskb, s - m, -1e30))
-        p = p / jnp.maximum(
-            jnp.sum(p, axis=-1, keepdims=True), 1e-30
-        )
-    out = jnp.einsum("bhgk,bhkd->bhgd", p, v.astype(jnp.float32))
-    return out.reshape(b, h, t, hd).astype(q.dtype)
 
 
 class MultiHeadSelfAttention(nn.Module):
@@ -274,10 +238,14 @@ class MultiHeadSelfAttention(nn.Module):
             # an uninitialized pass (module.init / eval_shape on the
             # FULL-length input) merely sizes them and falls through to
             # the normal forward below.
+            # The K/V pages: ``page_pack`` positions a row of 128
+            # lanes where a head is narrower (ops/decode_attention.py),
+            # the same bytes row-major as (B, H_kv, T, hd).
+            pages = page_shape(b, kv_heads, t, head_dim)
             ck = self.variable("cache", "cached_key", jnp.zeros,
-                               k.shape, k.dtype)
+                               pages, k.dtype)
             cv = self.variable("cache", "cached_value", jnp.zeros,
-                               v.shape, v.dtype)
+                               pages, v.dtype)
             ci = self.variable(
                 "cache", "cache_index",
                 lambda: jnp.zeros((), jnp.int32),
@@ -303,39 +271,8 @@ class MultiHeadSelfAttention(nn.Module):
                         f"step; got a {t}-token chunk (chunks need the "
                         "per-row index of the page pools)"
                     )
-                tk_cache = ck.value.shape[2]
-                if t != 1:
-                    # A chunk of t positions a row, at idx .. idx+t-1:
-                    # every cache lane takes the chunk's K/V of its own
-                    # offset where it lies inside the chunk, in one
-                    # pass over the pages.
-                    rel = jnp.arange(tk_cache)[None, :] - idx[:, None]
-                    inside = ((rel >= 0) & (rel < t))[:, None, :, None]
-                    lane = jnp.clip(rel, 0, t - 1)[:, None, :, None]
-                    ck.value = jnp.where(
-                        inside, jnp.take_along_axis(k, lane, axis=2),
-                        ck.value,
-                    )
-                    cv.value = jnp.where(
-                        inside, jnp.take_along_axis(v, lane, axis=2),
-                        cv.value,
-                    )
-                elif batched_idx:
-                    # Per-row one-hot select writes: row r lands at
-                    # slot idx[r].  jnp.where is bit-exact against
-                    # dynamic_update_slice for the written lane and
-                    # leaves every other lane untouched.
-                    hot = jnp.arange(tk_cache)[None, :] == idx[:, None]
-                    sel = hot[:, None, :, None]
-                    ck.value = jnp.where(sel, k, ck.value)
-                    cv.value = jnp.where(sel, v, cv.value)
-                else:
-                    ck.value = jax.lax.dynamic_update_slice(
-                        ck.value, k, (0, 0, idx, 0)
-                    )
-                    cv.value = jax.lax.dynamic_update_slice(
-                        cv.value, v, (0, 0, idx, 0)
-                    )
+                pack = ck.value.shape[3] // head_dim
+                tk_cache = ck.value.shape[2] * pack
                 ci.value = idx + t
                 # Causality is enforced HERE — the layer owns
                 # cache_index, so it ANDs a validity mask (slots beyond
@@ -369,9 +306,28 @@ class MultiHeadSelfAttention(nn.Module):
                     key_mask & valid
                 )
                 with jax.named_scope("block_attend"):
-                    out = _grouped_decode_attend(
-                        q, ck.value, cv.value, key_mask
-                    )
+                    if batched_idx:
+                        # Row r's t rows land at idx[r] .. idx[r]+t-1
+                        # (one beyond the bucket is dropped) and the
+                        # queries attend over the pages with them in:
+                        # one pass, a kernel on the TPU.
+                        out, ck.value, cv.value = cached_attend(
+                            q, k, v, ck.value, cv.value, idx, key_mask
+                        )
+                    else:
+                        k_all = jax.lax.dynamic_update_slice(
+                            unpack_pages(ck.value, head_dim), k,
+                            (0, 0, idx, 0),
+                        )
+                        v_all = jax.lax.dynamic_update_slice(
+                            unpack_pages(cv.value, head_dim), v,
+                            (0, 0, idx, 0),
+                        )
+                        ck.value = pack_pages(k_all, pack)
+                        cv.value = pack_pages(v_all, pack)
+                        out = grouped_decode_attend(
+                            q, k_all, v_all, key_mask
+                        )
                 return out_proj(out)
 
         if self.block is not None:

@@ -205,6 +205,8 @@ def build_step(module, nslots: int, kv: int):
             else (pos, t0s, live, *block.T)
         return jitted(variables, cache, buf, np.stack(packed))
 
+    # What ``call`` runs, for whoever compiles it without running it.
+    call.program = jitted
     return call, cache_shapes
 
 

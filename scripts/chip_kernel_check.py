@@ -32,6 +32,7 @@ from learningorchestra_tpu.ops.attention import (
     flash_attention,
     mha_reference,
 )
+from learningorchestra_tpu.ops import decode_attention
 from learningorchestra_tpu.ops.quant import (
     dequantize_rowwise,
     quantize_rowwise,
@@ -120,6 +121,41 @@ def _quant_case() -> dict:
     return out
 
 
+def _decode_case(h: int, kvh: int, t: int, d: int, dtype) -> dict:
+    """The decode step's pass over 8 slots x 512 KV pages against the
+    plain insert + attend: slots at different lengths, one on the
+    bucket's last rows, one free.  The pages must come out equal bit
+    for bit; float32 pages are multiplied in float32."""
+    rng = np.random.default_rng(h * 7 + d)
+    b, tk = 8, 512
+    pack = decode_attention.page_pack(d, tk)
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), dtype)
+
+    q, k, v = draw(b, h, t, d), draw(b, kvh, t, d), draw(b, kvh, t, d)
+    pages = [
+        decode_attention.pack_pages(draw(b, kvh, tk, d), pack)
+        for _ in range(2)
+    ]
+    idx = jnp.asarray([0, 300, tk - t, 255, 384, 0, 100, 509], jnp.int32)
+    buf = jnp.asarray(rng.integers(0, 5, (b, tk)) != 0).at[5].set(False)
+    last = idx[:, None, None] + jnp.arange(t)[None, :, None]
+    mask = buf[:, None] & (jnp.arange(tk)[None, None] <= last)
+    # the kernel's pages are donated, as the engine's step donates them
+    ref, got = (
+        jax.jit(fn, donate_argnums=(3, 4))(
+            q, k, v, *(p + 0 for p in pages), idx,
+            mask if t > 1 else mask[:, 0],
+        )
+        for fn in (decode_attention.plain_attend, decode_attention.decode_attend)
+    )
+    err = _max_err(ref[0], got[0])
+    same = all(bool(jnp.array_equal(r, g)) for r, g in zip(ref[1:], got[1:]))
+    return {"out_err": err, "pages_equal": same,
+            "ok": same and err < (1e-4 if dtype == jnp.float32 else TOL)}
+
+
 def main() -> int:
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -142,6 +178,10 @@ def main() -> int:
     cases.append(("flash:causal:T16384:D128",
                   lambda: _flash_case(16384, 128, "causal", b=1, h=1)))
     cases.append(("quant:30522x768", _quant_case))
+    cases.append(("decode_attend:mha25:t1:D64:f32", lambda: _decode_case(
+        25, 25, 1, 64, jnp.float32)))
+    cases.append(("decode_attend:32over4:t4:D128:bf16", lambda: _decode_case(
+        32, 4, 4, 128, jnp.bfloat16)))
     if jax.device_count() >= 4:
         # chip_smoke.py's multi-chip phase owns the ring-flash check
         # (it raises on a miss).

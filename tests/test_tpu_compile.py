@@ -53,3 +53,101 @@ def test_grouped_matmul_compiles_at_the_routed_layers_shapes(
     ).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 2 and "ragged-dot" not in text
+
+
+def _on(one_chip, tree):
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree,
+    )
+
+
+def _assert_one_pass_over_the_pages(text, pages, layers):
+    """The compiled step: one ``decode_attend`` kernel a layer, every
+    page leaf (and the token buffer, where there is one) updated in
+    place, and nothing but the kernel that makes an array of a page
+    leaf's shape (no ``copy``, no fusion, no scatter)."""
+    import re
+
+    calls = re.findall(r"%(\S+) = \([^=]*\) custom-call\(", text)
+    assert sum(c.startswith("decode_attend") for c in calls) == layers
+    header = text.split("\n", 1)[0]
+    leaves = jax.tree_util.tree_leaves(pages)
+    assert header.count("-alias)") >= len(leaves)
+    shapes = {
+        f"{leaf.dtype.name.replace('float', 'f')}"
+        f"[{','.join(map(str, leaf.shape))}]"
+        for leaf in leaves
+    }
+    makers = {
+        op for shape, op in re.findall(
+            r"= (\w+\[[\d,]*\])\{[^ ]*\} ([\w-]+)\(", text
+        ) if shape in shapes
+    }
+    assert makers <= {"parameter", "get-tuple-element"}, makers
+
+
+@pytest.mark.parametrize("slots", [1, 2, 4, 8])
+def test_decode_step_is_one_pass_over_the_pages_at_gpt2_xl_widths(
+        slots, one_chip, monkeypatch):
+    """The engine's step program of a next-token model, two layers at
+    GPT-2 XL's widths over a 512 bucket, at every slot bucket."""
+    from learningorchestra_tpu.models.text import _DecoderLM
+    from learningorchestra_tpu.serve.decode.pages import build_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    module = _DecoderLM(
+        vocab_size=50257, hidden_dim=1600, num_layers=2, num_heads=25,
+        mlp_dim=6400, max_len=1024,
+    )
+    step, pages = build_step(module, slots, 512)
+    variables = jax.eval_shape(
+        module.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )
+    text = step.program.lower(
+        _on(one_chip, variables), _on(one_chip, pages),
+        jax.ShapeDtypeStruct((slots, 512), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((3, slots), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+    _assert_one_pass_over_the_pages(text, pages, layers=2)
+
+
+def test_block_step_of_an_sdar_attention_layer_is_one_pass(
+        one_chip, monkeypatch):
+    """One attention layer of SDAR-30B-A3B at its widths (32 query
+    heads over 4 KV heads of 128, bfloat16, q/k norms, rotary, block
+    mask) stepping 8 slots by a block of 4 over a 512 bucket."""
+    from learningorchestra_tpu.ops.layers import MultiHeadSelfAttention
+    from learningorchestra_tpu.serve.decode.pages import (
+        set_index, strip_index,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = MultiHeadSelfAttention(
+        num_heads=32, qkv_features=2048, num_kv_heads=4, head_dim=128,
+        use_bias=False, qk_norm=True, rope=True, rope_theta=1e6,
+        block=4, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+        decode=True,
+    )
+    variables = jax.eval_shape(
+        layer.init, jax.random.PRNGKey(0),
+        jnp.zeros((8, 512, 2048), jnp.bfloat16),
+    )
+    pages = strip_index(variables["cache"])
+
+    def step(params, pages, x, pos, kmask):
+        out, mut = layer.apply(
+            {"params": params, "cache": set_index(pages, pos)}, x,
+            key_mask=kmask, mutable=["cache"],
+        )
+        return strip_index(mut["cache"]), out
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(step, donate_argnums=1).lower(
+        _on(one_chip, variables["params"]), _on(one_chip, pages),
+        arg((8, 4, 2048), jnp.bfloat16), arg((8,), jnp.int32),
+        arg((8, 512), jnp.bool_),
+    ).compile().as_text()
+    _assert_one_pass_over_the_pages(text, pages, layers=1)
