@@ -75,10 +75,7 @@ class DriftPaths:
             client=pkg / "client.py",
             plane=pkg / "faults" / "plane.py",
             tests_dir=root / "tests",
-            scripts=tuple(
-                sorted((root / "scripts").glob("*"))
-            ) + ((root / "bench.py"),) if (root / "scripts").exists()
-            else (),
+            scripts=tuple(sorted((root / "scripts").glob("*"))),
         )
 
 

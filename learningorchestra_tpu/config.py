@@ -328,8 +328,7 @@ class ObsConfig:
     Prometheus exposition at GET /metrics.prom + end-to-end job trace
     spans.  Env knobs: LO_TPU_OBS_*."""
 
-    # Master switch: off makes every metric/span primitive a no-op
-    # (the bench's overhead probe measures exactly this delta).
+    # Master switch: off makes every metric/span primitive a no-op.
     # Env: LO_TPU_OBS_ENABLED.
     enabled: bool = True
     # Job tracing (request-id propagation + spans persisted into the

@@ -54,7 +54,6 @@ plane's own per-point counters (served by :func:`status`).
 
 Zero-cost disabled path: :func:`hit` is a truthiness check on an empty
 module-level dict and a return — no lock, no lookup, no allocation.
-bench.py's ``_faults_probe`` pins the number.
 """
 
 from __future__ import annotations
@@ -330,8 +329,8 @@ def _fire(point: str) -> None:
 
 def _trigger_counter():
     """Obs-registry counter, resolved per trigger so a registry reset
-    (tests, the bench's on/off probe) takes effect immediately —
-    triggers are rare, the lookup cost is irrelevant."""
+    (tests) takes effect immediately — triggers are rare, the lookup
+    cost is irrelevant."""
     from learningorchestra_tpu.obs.metrics import get_registry
 
     return get_registry().counter(

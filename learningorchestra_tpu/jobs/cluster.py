@@ -41,8 +41,8 @@ requires the **python** store backend — the native backend has no
 refresh primitive (services/context.py disables clustering loudly when
 it is missing).  Claim/heartbeat wall-time comparisons assume the
 engines' clocks agree to within ``ttl_s`` (same-host processes or
-NTP-disciplined hosts); bench.py's ``_claim_probe`` banks the claim
-path's cost against a minimal dispatch.
+NTP-disciplined hosts).  The claim path's cost against a dispatch is
+not measured on a chip.
 
 Fault points: ``cluster.claim`` (claim CAS), ``cluster.heartbeat``
 (renew) and ``cluster.steal`` (expired-claim takeover) — seeded chaos

@@ -93,7 +93,7 @@ def _bundle():
 
 def _job_metrics():
     """Engine instrumentation handles, resolved per use so a registry
-    reset (tests, the bench's on/off probe) takes effect immediately."""
+    reset (tests) takes effect immediately."""
     from learningorchestra_tpu.obs.metrics import get_registry
 
     reg = get_registry()

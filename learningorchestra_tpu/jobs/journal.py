@@ -44,9 +44,9 @@ opens is harmless by construction: recovery is metadata-authoritative
 inline, with the request parameters stamped at submit), so a crash
 inside the window can at worst demote a job from auto-re-dispatch to
 the explicit orphaned-by-restart path — never lose or double-run
-one.  ``bench._journal_probe`` banks the resulting
-submit/dispatch-path cost below 2% of a minimal job dispatch.  Fence
-checks re-read a one-line file and run only at terminal
+one.  A job's life is four records (tests/test_journal_recovery.py);
+their cost on the submit/dispatch path is not measured on a chip.
+Fence checks re-read a one-line file and run only at terminal
 commits/publications, never per epoch.
 """
 

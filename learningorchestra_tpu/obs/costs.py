@@ -41,8 +41,7 @@ Two ledgers, both process-wide singletons sized from config
   bookkeeping arbitrarily far down.
 
 Everything here is disabled by ``LO_TPU_COSTS_ENABLED=0``: probes
-return immediately and builders skip analysis — the bench's
-``_costs_probe`` measures exactly that delta.
+return immediately and builders skip analysis.
 """
 
 from __future__ import annotations
@@ -260,8 +259,8 @@ class DeviceTimeLedger:
         # Entries are 4-slot lists [device_s, flops, bytes,
         # dispatches], not dicts: record() sits on the serving
         # dispatch hot path and list indexing keeps the recorded hit
-        # ~1 µs — the bench's _costs_probe pins the number.  Jobs AND
-        # models ride bounded freshest-N rings (a multi-tenant server
+        # short (not measured on a chip).  Jobs AND models ride
+        # bounded freshest-N rings (a multi-tenant server
         # churning model names must not grow these — or the per-model
         # metric cardinality — without limit); a model's bucket
         # entries die with it.
@@ -313,9 +312,9 @@ class DeviceTimeLedger:
     def record_model(self, weight, duration_s, flops, nbytes, model,
                      bucket) -> None:
         """Positional fast path for the serving dispatch hook (no
-        kwargs parsing, no job branch) — the bench's _costs_probe
-        pins this exact call at <1% of a serving dispatch, which is
-        why the accumulate blocks stay hand-inlined here."""
+        kwargs parsing, no job branch), which is why the accumulate
+        blocks stay hand-inlined here; its share of a serving dispatch
+        is not measured on a chip."""
         d = duration_s * weight
         f = (flops or 0.0) * weight
         b = (nbytes or 0.0) * weight

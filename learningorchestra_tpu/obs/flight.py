@@ -30,9 +30,8 @@ tripped fault point Y, job Z preempted, lock W stalled").
 
 Hot-path contract: ``record()`` takes NO locks.  Rings are
 ``collections.deque(maxlen=N)`` — appends are atomic under the GIL —
-and the disabled path is a single module-global check, so the recorder
-rides every dispatch at well under 1% of a single-row batcher dispatch
-(bench.py ``_flight_probe`` banks the numbers).  ``configure()`` /
+and the disabled path is a single module-global check (its share of a
+dispatch is not measured on a chip).  ``configure()`` /
 ``snapshot()`` mutate/read module state under a witnessed lock; a
 snapshot copies each ring (``list(deque)`` is also GIL-atomic) so
 readers never observe a half-written event.
@@ -128,7 +127,7 @@ def ensure(cfg) -> None:
 
 
 def reset(cfg=None) -> None:
-    """Tests/bench: drop all state; re-arm when ``cfg`` is given."""
+    """Tests: drop all state; re-arm when ``cfg`` is given."""
     global _rings, _events_per_ring
     with _lock:
         _rings = None

@@ -24,8 +24,7 @@ over the same instrumentation points.
 Knobs (config.py ObsConfig, env ``LO_TPU_OBS_*``):
 
 - ``LO_TPU_OBS_ENABLED=0`` turns the layer off: every primitive
-  becomes a no-op and tracing stops minting spans — the bench's
-  overhead probe measures exactly this delta.
+  becomes a no-op and tracing stops minting spans.
 - ``LO_TPU_OBS_MAX_SERIES`` bounds label cardinality per metric: past
   the cap, new label combinations collapse into one ``_overflow``
   series instead of growing memory without bound (a client fuzzing
@@ -532,9 +531,8 @@ def get_registry() -> MetricsRegistry:
 
 
 def reset_registry(**overrides) -> MetricsRegistry:
-    """Replace the singleton (tests; the bench's on/off overhead
-    probe).  With overrides, builds directly from them; bare call
-    rebuilds from config."""
+    """Replace the singleton (tests).  With overrides, builds directly
+    from them; bare call rebuilds from config."""
     global _registry
     with _registry_lock:
         if overrides:

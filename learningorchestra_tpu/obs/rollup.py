@@ -620,9 +620,9 @@ def ensure_engine(cfg) -> RollupEngine:
 
 
 def reset_engine(cfg=None) -> RollupEngine:
-    """Replace the singleton (tests, the bench probe); stops any
-    running daemon thread first.  ``cfg=None`` rebuilds lazily from
-    the global config on next use."""
+    """Replace the singleton (tests); stops any running daemon thread
+    first.  ``cfg=None`` rebuilds lazily from the global config on next
+    use."""
     global _engine
     with _engine_lock:
         old, _engine = _engine, None

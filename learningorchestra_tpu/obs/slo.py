@@ -327,8 +327,7 @@ class SLOService:
     def evaluate(self, engine, now: float | None = None) -> list[dict]:
         """One pass over every (objective, instance) against the
         rollup windows; returns the delivered transition events.
-        Called from the rollup tick; public for tests and the bench
-        probe."""
+        Called from the rollup tick; public for tests."""
         if not self.cfg.enabled:
             return []
         now = time.monotonic() if now is None else float(now)
@@ -578,7 +577,7 @@ def ensure_service(cfg) -> SLOService:
 
 
 def reset_service(cfg=None) -> SLOService:
-    """Replace the singleton (tests, the bench probe)."""
+    """Replace the singleton (tests)."""
     global _service
     with _service_lock:
         _service = None if cfg is None else SLOService(cfg)
@@ -587,7 +586,7 @@ def reset_service(cfg=None) -> SLOService:
 
 def on_tick(engine, now: float | None = None) -> None:
     """Rollup-tick hook: evaluate the singleton IF one has been
-    configured (API server boot, a test, the bench).  A bare rollup
+    configured (API server boot, a test).  A bare rollup
     engine with no SLO service evaluates nothing — objective state
     must not mint itself as a side effect of unrelated ticks."""
     with _service_lock:

@@ -15,8 +15,8 @@ through the process-wide compile cache keyed on (architecture, bucket),
 so scaling 1→N adds zero compile-cache misses; only the parameter copy
 is per-device (``Replica.place``).  On CPU-only backends leases grant
 no devices and replicas share the registry's resident params — the
-fleet machinery is then pure routing, which is what the unit tests and
-the bench probe exercise.
+fleet machinery is then pure routing, which is what the unit tests
+exercise.
 
 Drain-before-unload: scale-down removes the victim from the routable
 list FIRST, then closes its batcher (``MicroBatcher.close`` flushes
@@ -225,8 +225,8 @@ class ReplicaSet:
 
     ``dispatch_factory(replica)`` returns the padded-bucket dispatch
     for one replica — the serving service binds the real registry +
-    compile-cache + device-placement dispatch; tests and the bench
-    probe inject stubs to exercise routing/scaling without a model.
+    compile-cache + device-placement dispatch; tests inject stubs to
+    exercise routing/scaling without a model.
     """
 
     def __init__(

@@ -52,23 +52,27 @@ def _save_args(state: dict):
     """orbax save arguments that keep every file under the size the
     volumes split artifacts at (store/volumes.py ``max_file_bytes``):
     orbax's own default packs a step into data files of up to 2 GB,
-    and a host's file-size limit fails that write with EFBIG.  A data
-    file closes once it reaches the target and a chunk is at most as
-    large again, so no file passes twice ``half``.  ``StandardSave``
-    cannot carry the target, hence the PyTree handler; what it writes
-    is what ``StandardCheckpointer`` restores."""
+    and a host's file-size limit fails that write with EFBIG.  The
+    target is no bound: a data file under it takes one more write,
+    which is a chunk or, at the commit, the root node that lists every
+    key and array header (one node whatever its size: orbax pins
+    OCDBT's ``max_decoded_node_bytes`` at 100 MB).  With target and
+    chunk at a quarter of the bound a data file stays under half of it
+    and the root node has three quarters.  ``StandardSave`` cannot
+    carry the target, hence the PyTree handler; what it writes is what
+    ``StandardCheckpointer`` restores."""
     import jax
     import orbax.checkpoint as ocp
 
     from learningorchestra_tpu.store.volumes import max_file_bytes
 
-    half = max_file_bytes() // 2
+    quarter = max_file_bytes() // 4
     return ocp.args.PyTreeSave(
         state,
         save_args=jax.tree.map(
-            lambda _: ocp.SaveArgs(chunk_byte_size=half), state
+            lambda _: ocp.SaveArgs(chunk_byte_size=quarter), state
         ),
-        ocdbt_target_data_file_size=half,
+        ocdbt_target_data_file_size=quarter,
     )
 
 
@@ -201,8 +205,20 @@ def save(directory: str | Path, step: int, state: dict,
                 slot.ckpt.wait_until_finished()
                 _publish(directory, p_step, p_history)
             if slot.ckpt is None:
+                # The temporary step directory is made before ``save``
+                # returns, not on the background thread: orbax tells a
+                # background write that its directory exists through
+                # signals keyed by ONE process-wide operation counter,
+                # which every save (sync or async, any directory)
+                # advances — saves that start together from two
+                # threads read each other's key, write into a
+                # directory not yet made and lose it.  Made up front,
+                # no save waits on a signal.
                 slot.ckpt = ocp.AsyncCheckpointer(
-                    ocp.PyTreeCheckpointHandler()
+                    ocp.PyTreeCheckpointHandler(),
+                    async_options=ocp.options.AsyncOptions(
+                        create_directories_asynchronously=False
+                    ),
                 )
             directory.mkdir(parents=True, exist_ok=True)
             path = directory / f"step_{step}"

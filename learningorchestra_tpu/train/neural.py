@@ -414,12 +414,19 @@ def _param_cast_for(dtype):
     return cast
 
 
-def _device_epoch_raw(
+def build_device_epoch(
     module, optimizer, loss_fn, dtype, *, n, batch_size, shuffle
 ):
-    """Unjitted whole-epoch function over a device-resident dataset —
-    shared by the per-epoch runner (jitted directly) and the fused
-    multi-epoch runner (scanned)."""
+    """Jitted whole-epoch step over a DEVICE-RESIDENT dataset.
+
+    The dataset is uploaded once; each epoch is one jitted call that
+    permutes indices on device (``jax.random.permutation``), gathers
+    batches in HBM and scans the train step — host traffic per epoch is
+    one PRNG key and the metrics scalars, vs. the host-side reshuffle +
+    full re-upload per epoch of the generic path (the reference pays
+    keras' per-batch Python dispatch on top, train_function.py:84-87).
+    (params, opt_state) are donated so updates happen in place.
+    """
     n_batches = max(1, -(-n // batch_size))
     pad = n_batches * batch_size - n
     _pcast = _param_cast_for(dtype)
@@ -463,61 +470,7 @@ def _device_epoch_raw(
         )
         return params, opt_state, _finalize_metrics(metrics)
 
-    return epoch
-
-
-def build_device_epoch(
-    module, optimizer, loss_fn, dtype, *, n, batch_size, shuffle
-):
-    """Jitted whole-epoch step over a DEVICE-RESIDENT dataset.
-
-    The dataset is uploaded once; each epoch is one jitted call that
-    permutes indices on device (``jax.random.permutation``), gathers
-    batches in HBM and scans the train step — host traffic per epoch is
-    one PRNG key and the metrics scalars, vs. the host-side reshuffle +
-    full re-upload per epoch of the generic path (the reference pays
-    keras' per-batch Python dispatch on top, train_function.py:84-87).
-    (params, opt_state) are donated so updates happen in place.
-    """
-    epoch = _device_epoch_raw(
-        module, optimizer, loss_fn, dtype,
-        n=n, batch_size=batch_size, shuffle=shuffle,
-    )
     return jax.jit(epoch, donate_argnums=(0, 1))
-
-
-def build_fused_epochs(
-    module, optimizer, loss_fn, dtype, *, n, batch_size, shuffle, epochs
-):
-    """ALL epochs in one jitted call: ``lax.scan`` over the device
-    epoch, per-epoch keys folded in on device, metrics stacked and read
-    back once at the end.
-
-    The per-epoch runner costs one dispatch + readback per epoch,
-    which dominates sub-100 ms epochs and corrupts throughput
-    measurements; here K epochs cost exactly one.
-    No per-epoch host work is possible inside (checkpointing/verbose
-    callbacks need the per-epoch runner).
-    """
-    epoch_raw = _device_epoch_raw(
-        module, optimizer, loss_fn, dtype,
-        n=n, batch_size=batch_size, shuffle=shuffle,
-    )
-
-    def fused(params, opt_state, x, y, key):
-        def body(carry, e):
-            params, opt_state = carry
-            params, opt_state, metrics = epoch_raw(
-                params, opt_state, x, y, jax.random.fold_in(key, e)
-            )
-            return (params, opt_state), metrics
-
-        (params, opt_state), metrics = jax.lax.scan(
-            body, (params, opt_state), jnp.arange(epochs)
-        )
-        return params, opt_state, metrics  # metrics: (epochs,) per key
-
-    return jax.jit(fused, donate_argnums=(0, 1))
 
 
 def _cast_for(dtype):
@@ -648,24 +601,6 @@ def _epoch_cost_attrs(est, epoch_s: float) -> dict:
     if util is not None:
         attrs["mfu"] = util
     return attrs
-
-
-def cached_fused_epochs(
-    est, loss_kind, *, n, batch_size, shuffle, epochs
-):
-    """Cache-fronted :func:`build_fused_epochs` — the bench's cold/warm
-    probe and any repeated fused-epoch caller share one trace per
-    (arch, optimizer, loss, dtype, shape, epochs) tuple."""
-    dtype = jnp.bfloat16 if est.compute_dtype == "bfloat16" else None
-    return _cached_program(
-        "fused_epochs", est, loss_kind,
-        shapes=(n, batch_size, bool(shuffle), int(epochs)),
-        builder=lambda: build_fused_epochs(
-            est.module, est.optimizer, est._loss_and_metrics(loss_kind),
-            dtype, n=n, batch_size=batch_size, shuffle=bool(shuffle),
-            epochs=int(epochs),
-        ),
-    )
 
 
 def _make_step(module, optimizer, loss_fn, _cast, _pcast):

@@ -178,3 +178,40 @@ def test_part_size_yields_to_the_file_size_limit():
         assert max_file_bytes() == PART_BYTES
     finally:
         resource.setrlimit(resource.RLIMIT_FSIZE, (soft, hard))
+
+
+@pytest.mark.parametrize("async_save", [False, True], ids=["sync", "async"])
+def test_checkpoint_files_stay_under_the_file_size_bound(
+    tmp_path, monkeypatch, async_save
+):
+    """orbax's data-file size is a target, not a bound: a file under it
+    takes one more chunk.  With both at a quarter of the bound a file
+    of arrays that do not compress stays under half of it (the rest is
+    the root node's, which no option bounds)."""
+    import numpy as np
+
+    from learningorchestra_tpu.store import volumes as volumes_mod
+    from learningorchestra_tpu.train import checkpoint
+
+    bound = 128 << 10
+    monkeypatch.setattr(volumes_mod, "max_file_bytes", lambda: bound)
+    rng = np.random.default_rng(0)
+    state = {
+        "params": {
+            f"w{i}": rng.integers(
+                0, 2**32, size=(rows, 128), dtype=np.uint32
+            )
+            for i, rows in enumerate((127, 127, 127, 120, 100, 64, 33, 200))
+        },
+        "opt_state": {"count": np.zeros((), np.int32)},
+    }
+    checkpoint.save(tmp_path, 1, state, async_save=async_save)
+    checkpoint.finalize_async(tmp_path)
+    largest = max(
+        p.stat().st_size for p in tmp_path.rglob("*") if p.is_file()
+    )
+    assert largest <= bound // 2 + 1024
+    back, step, _ = checkpoint.load_latest(tmp_path, state)
+    assert step == 1
+    for name, want in state["params"].items():
+        assert np.array_equal(back["params"][name], want)

@@ -8,7 +8,7 @@ surface at tiny shapes (VERDICT r1 next-round item 10):
   evaluated, then explored with a t-SNE scatter PNG;
 - config 4: BERT fine-tune driven by the Tune grid-search route.
 
-Config 2 (MNIST-style CNN flow) is covered by test_api.py and bench.py;
+Config 2 (MNIST-style CNN flow) is covered by test_api.py;
 config 5's multi-chip shape by test_multihost.py + the dryrun entries.
 """
 

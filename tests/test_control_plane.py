@@ -691,23 +691,3 @@ def test_partition_drill_peer_steals_and_resumes(tmp_path):
     assert len(result["epochs"]) < 6, result
     # The armed claim fault actually rode the drill's claims.
     assert result["claimTriggers"] >= 1, result
-
-
-# -- bench probe -------------------------------------------------------------
-
-
-class TestBenchProbe:
-    def test_claim_probe_smoke(self):
-        import bench
-
-        out = bench._claim_probe()
-        assert set(out) == {
-            "claim_us", "cycle_us", "heartbeat_us", "dispatch_us",
-            "claim_share_of_dispatch_pct",
-            "cycle_share_of_dispatch_pct",
-        }
-        assert out["claim_us"] > 0
-        assert out["dispatch_us"] > 0
-        # The acceptance bound is <=5% on a quiet box; a loaded CI
-        # worker gets headroom — the banked number lives in README.
-        assert out["claim_share_of_dispatch_pct"] < 25.0

@@ -164,11 +164,14 @@ class TestLocalClusterBringup:
         """Kill agent1, wait for the supervisor restart and the
         coordinator re-registration.  pgrep is scoped to THIS
         cluster's coordinator port so a retry's fresh cluster never
-        matches a half-torn-down predecessor's agents."""
+        matches a half-torn-down predecessor's agents, and anchored
+        at the interpreter: the supervising ``bash -c`` carries the
+        same words in its command line and has the lower pid."""
 
         def agent1_pid():
             out = subprocess.run(
                 ["pgrep", "-f",
+                 r"^\S*python\S* -m learningorchestra_tpu "
                  f"agent --coordinator 127.0.0.1:{coord_port} "
                  "--id agent1"],
                 capture_output=True, text=True,
@@ -200,12 +203,12 @@ class TestLocalClusterBringup:
         """Kill an agent process; the supervisor must restart it (the
         reference's restart_policy: on-failure).
 
-        Known load-flake (BASELINE notes, PR-13 git-stash A/B): under
-        heavy machine load the 90 s restart/re-register waits can
-        lapse on an UNCHANGED tree.  The drill retries once on a
-        FRESH cluster so tier-1 (now also witness-enabled) doesn't
-        inherit the noise — a genuine supervisor regression fails
-        both attempts."""
+        What read as a load flake until PR 31 was the drill killing
+        the SUPERVISOR (its ``bash -c`` matched the same pgrep): an
+        agent that then died registering before the coordinator
+        listened was never restarted.  The drill still retries once on
+        a FRESH cluster; a genuine supervisor regression fails both
+        attempts."""
         last = None
         for _attempt in range(2):
             _proc, _api_port, coord_port = launch_cluster()

@@ -1129,24 +1129,6 @@ class TestClusterChaos:
             thief.close()
 
 
-# -- bench probe -------------------------------------------------------------
-
-
-class TestBenchProbe:
-    def test_faults_probe_smoke(self):
-        """The banked subsystem number: disabled-path hit cost is a
-        measured sub-microsecond quantity, negligible against the
-        cheapest real operation carrying a probe."""
-        import bench
-
-        out = bench._faults_probe()
-        assert 0 < out["hit_disabled_ns"] < 10_000
-        assert out["wal_append_us"] > 0
-        assert out["disabled_share_of_wal_append_pct"] < 5.0
-        # The probe cleans up after itself.
-        assert not faults.status()["enabled"]
-
-
 # -- the gate: every fault point exercised -----------------------------------
 
 

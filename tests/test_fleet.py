@@ -43,9 +43,8 @@ def _stub_set(
     max_queue=64,
     flush_ms=1.0,
 ):
-    """ReplicaSet over an injected device pool with a stub dispatch —
-    the seam the bench probe uses too: real routing/scaling/leasing,
-    no model."""
+    """ReplicaSet over an injected device pool with a stub dispatch:
+    real routing/scaling/leasing, no model."""
     leaser = DeviceLeaser([f"tpu:{i}" for i in range(n_devices)])
     cfg = ServeConfig(
         max_batch=max_batch, max_queue=max_queue, flush_ms=flush_ms

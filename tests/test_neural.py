@@ -365,61 +365,6 @@ def test_decoder_lm_validation_and_pad_masking():
     assert est.history["accuracy"][-1] > 0.9
 
 
-def test_fused_epochs_match_per_epoch_runner():
-    """build_fused_epochs (one dispatch for K epochs — the bench's
-    timing path) must produce the same trajectory as K calls of the
-    per-epoch runner with the same folded keys."""
-    import jax
-    import jax.numpy as jnp
-
-    from learningorchestra_tpu.models.mlp import MLPClassifier
-    from learningorchestra_tpu.train.neural import (
-        build_device_epoch,
-        build_fused_epochs,
-    )
-
-    rng = np.random.default_rng(0)
-    x = rng.standard_normal((32, 4)).astype(np.float32)
-    y = (x.sum(1) > 0).astype(np.int32)
-
-    def fresh():
-        est = MLPClassifier(hidden_layer_sizes=[8], num_classes=2, seed=1)
-        est._init_params(jnp.asarray(x[:1]))
-        loss_fn = est._loss_and_metrics("softmax_ce")
-        return est, loss_fn
-
-    epochs, bs = 3, 8
-    key = jax.random.PRNGKey(7)
-
-    est1, loss_fn = fresh()
-    per_epoch = build_device_epoch(
-        est1.module, est1.optimizer, loss_fn, None,
-        n=len(x), batch_size=bs, shuffle=True,
-    )
-    p, o = est1.params, est1.opt_state
-    seq_losses = []
-    for e in range(epochs):
-        p, o, m = per_epoch(p, o, jnp.asarray(x), jnp.asarray(y),
-                            jax.random.fold_in(key, e))
-        seq_losses.append(float(m["loss"]))
-
-    est2, loss_fn2 = fresh()
-    fused = build_fused_epochs(
-        est2.module, est2.optimizer, loss_fn2, None,
-        n=len(x), batch_size=bs, shuffle=True, epochs=epochs,
-    )
-    p2, o2, metrics = fused(
-        est2.params, est2.opt_state, jnp.asarray(x), jnp.asarray(y), key
-    )
-    fused_losses = [float(v) for v in metrics["loss"]]
-    np.testing.assert_allclose(fused_losses, seq_losses, rtol=1e-5)
-    # Final params agree too (same updates in the same order).
-    for a, b in zip(jax.tree_util.tree_leaves(p),
-                    jax.tree_util.tree_leaves(p2)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-6)
-
-
 def test_kv_cache_generate_matches_full_forward():
     """The one-scan KV-cache decode must reproduce the naive
     full-re-forward greedy loop token for token."""

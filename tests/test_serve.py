@@ -414,6 +414,66 @@ class TestPredictCompileBuckets:
             atol=1e-6,
         )
 
+    def test_coalesced_serving_compiles_per_bucket_not_per_request(self):
+        """The serving dispatch shape — a MicroBatcher whose dispatch
+        resolves ``apply`` from the compile cache by padded bucket —
+        under 8 concurrent clients: however the 64 requests coalesce,
+        the programs built are at most the bucket set."""
+        import jax
+        import jax.numpy as jnp
+
+        from learningorchestra_tpu.models.mlp import MLPClassifier
+        from learningorchestra_tpu.train import compile_cache as cc
+
+        cc.reset_cache()
+        est = MLPClassifier(hidden_layer_sizes=[16], num_classes=8)
+        est.compute_dtype = "float32"
+        rng = np.random.default_rng(0)
+        row = rng.standard_normal((1, 8)).astype(np.float32)
+        est._init_params(jnp.asarray(row))
+        params, module = est.params, est.module
+        want = np.asarray(module.apply(params, jnp.asarray(row)))
+
+        def dispatch(padded):
+            apply = cc.get_cache().get_or_build(
+                cc.apply_program_key(module, rows=padded.shape[0]),
+                lambda: jax.jit(module.apply),
+                label=f"t-serve:b{padded.shape[0]}",
+            )
+            return apply(params, jnp.asarray(padded))
+
+        before = cc.counters_snapshot()
+        mb = MicroBatcher(
+            dispatch, max_batch=8, max_queue=256, flush_ms=2.0,
+            name="t-buckets",
+        )
+        errors = []
+
+        def client():
+            try:
+                for _ in range(8):
+                    np.testing.assert_allclose(
+                        mb.submit(row), want, rtol=1e-5, atol=1e-6
+                    )
+            except Exception as exc:  # noqa: BLE001 - re-raised below
+                errors.append(exc)
+
+        try:
+            threads = [threading.Thread(target=client) for _ in range(8)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            assert not any(t.is_alive() for t in threads)
+            assert not errors, errors[0]
+            stats = mb.stats()
+        finally:
+            mb.close()
+        buckets = bucket_sizes(8)
+        assert 1 <= cc.delta_since(before)["misses"] <= len(buckets) == 4
+        assert set(stats["bucketHistogram"]) <= {str(b) for b in buckets}
+        assert 0 < stats["batchOccupancy"] <= 1
+
 
 # -- REST surface ------------------------------------------------------------
 

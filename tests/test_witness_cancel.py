@@ -256,17 +256,26 @@ class TestCancelToken:
         on instrumented locks."""
         artifacts.metadata.create("coop", {"name": "coop"})
         engine = JobEngine(artifacts, max_workers=1, deadline_s=0.3)
+        saw_token = threading.Event()
+        failed = threading.Event()
         exited = threading.Event()
 
         def body():
             while not cancel_requested():
                 time.sleep(0.01)
+            saw_token.set()
+            # The watchdog flips the token BEFORE it fails the future
+            # (store writes in between): a body that returned here
+            # could resolve the future first, with None.
+            failed.wait(30)
             exited.set()
 
         future = engine.submit("coop", body)
         with pytest.raises(JobDeadlineExceeded):
             future.result(30)
-        assert exited.wait(5), "body never saw the cancel token"
+        assert saw_token.wait(5), "body never saw the cancel token"
+        failed.set()
+        assert exited.wait(5)
         t0 = time.monotonic()
         engine.shutdown(wait=True)  # legacy unbounded drain is fine:
         # the zombie already exited cooperatively.
