@@ -45,16 +45,14 @@ logger = get_logger("decode")
 #: Ceiling on a non-stream request's wait for its streams to finish.
 _NONSTREAM_TIMEOUT_S = 300.0
 
-#: Lazy (non-SSE) pools sync to host every this-many steps so async
-#: dispatch cannot run unboundedly ahead of the device.
-_SYNC_STRIDE = 32
-
 #: What one turn of the worker's loop is made of, in order: ``admit``
 #: (the loop top: pending streams, admission, the abort sweep),
 #: ``dispatch`` (the step's host arrays and the call that enqueues it),
-#: ``sync`` (reading the step's tokens, and finished rows, back),
+#: ``sync`` (reading a step's tokens, and finished rows, back),
 #: ``emit`` (tokens to their streams, histograms, the devtime ledger);
 #: ``wait`` is the worker parked with nothing live, outside any turn.
+#: A one-token pool's turn enqueues step k and only then reads and
+#: emits step k-1, so its ``sync`` and ``emit`` run beside the chip.
 _PHASES = ("admit", "dispatch", "sync", "emit", "wait")
 
 #: A turn longer than this leaves a ``slow_step`` flight event with its
@@ -146,6 +144,9 @@ class _ModelDecoder:
         # updated the pool's pages in place (jax leaves a donated
         # argument alive when XLA could not alias it).
         self.steps_in_place = 0
+        # Steps enqueued while the pool's step before them was still
+        # unread: the host's turn for that one ran beside the chip.
+        self.steps_ahead = 0
         # Written by the worker thread alone, read by stats().
         self.phases = obs_tracing.Phases("decode", _PHASES)
         self.prompt_steps = 0  # slot-steps that consumed a prompt token
@@ -156,7 +157,7 @@ class _ModelDecoder:
         # One turn's share of the three counters above and what it
         # stepped: the ``lo:decode.step`` annotation's metadata.
         self._turn = {"prompt": 0, "output": 0, "keys": 0, "slots": 0,
-                      "kv": 0, "inplace": 0}
+                      "kv": 0, "inplace": 0, "ahead": 0}
         # Generation by diffusion over blocks: slot-steps by phase
         # (whole prompt blocks prefilled, denoising forwards, commits),
         # positions processed, tokens the denoising forwards fixed, and
@@ -225,7 +226,13 @@ class _ModelDecoder:
     # -- worker --------------------------------------------------------------
 
     def _any_live(self) -> bool:
-        return any(p.live for p in self._pools.values())
+        """Whether a turn has anything to do: a stream seated, or a
+        step's result still unread (every stream of it aborted since).
+        The worker parks, idles out and clears its pools only from a
+        state with neither: no read is ever left pending."""
+        return any(
+            p.live or p.unread is not None for p in self._pools.values()
+        )
 
     def _park(self) -> bool:
         """Wait (phase ``wait``) until there is a stream to serve or
@@ -319,7 +326,19 @@ class _ModelDecoder:
                     model=self.name, turnS=round(turn_s, 4),
                     phaseS=split, step=self.steps,
                 )
-        # closed: fail whatever never got (or was mid) service.
+        # closed: what a pool's step in flight produced goes out first
+        # (a stream whose last step it was ends whole), then whatever
+        # never got, or was mid, service fails.
+        for pool in pools:
+            unread, pool.unread = pool.unread, None
+            try:
+                if unread is not None:
+                    self._read_step(pool, unread)
+            except Exception as exc:  # noqa: BLE001 — shutting down:
+                # its streams fail below either way.
+                logger.error("decode drain on close failed %s", kv(
+                    model=self.name, error=str(exc),
+                ))
         self._shut_down(pending, pools)
         with self._cv:
             self._streams.clear()
@@ -476,7 +495,7 @@ class _ModelDecoder:
                     if stream is not None and stream.token.cancelled():
                         pool.release(slot)
                         self._finish(stream, aborted=True)
-            if not pool.live:
+            if not pool.live and pool.unread is None:
                 continue
             try:
                 self._step_pool(pool)
@@ -492,115 +511,151 @@ class _ModelDecoder:
                     model=self.name, pool=f"{key}", error=str(exc),
                 )
                 # The step consumes the pool's cache and buffer, so
-                # after one that raised they may be gone: the pool
-                # forgets its device state whole (nothing here may
-                # touch the old buffer) and the next admission
-                # allocates afresh.
+                # after one that raised (when it was enqueued, or when
+                # its result was read with its successor already
+                # enqueued) they may be gone: the pool forgets its
+                # device state whole, the unread result with it
+                # (nothing here may touch the old buffer), and the next
+                # admission allocates afresh.
                 for stream in pool.drop():
                     self._finish(
                         stream, error=f"decode step failed: {exc}"
                     )
 
     def _step_pool(self, pool: PagePool) -> None:
+        """One turn of a pool.  A one-token pool keeps ONE step in
+        flight: the turn enqueues step k and only then reads step k-1,
+        so the host's whole turn runs while the chip runs step k, and
+        the chip finds step k+1 queued when step k ends.  Nothing in
+        step k needs step k-1's tokens on the host: its input token is
+        in the device's buffer, and positions, prompt lengths and who
+        is live follow from lengths alone (greedy, no EOS: a stream
+        ends at ``total``).  A token leaves one turn late and no later
+        than before: as soon as its own step has ended on the chip.
+        Reading step k-1 before step k+1 is enqueued is what bounds the
+        run-ahead at one step.  A block pool's next input is decided
+        from its last result, so its turn reads what it dispatched
+        (``_step_blocks``): ``pool.width``, the model's own, chooses."""
         from learningorchestra_tpu import faults
 
         if pool.width > 1:
             return self._step_blocks(pool)
-        phases = self.phases
-        with phases("dispatch"):
-            # The chaos probe stands where the step is dispatched: a
-            # delay armed on it reads as dispatch time.
-            faults.hit("serve.decode_step")
-            step, _ = self._step_for(pool.nslots, pool.kv)
-            live = np.array(
-                [s is not None for s in pool.streams], bool
-            )
-            t0s = np.array(
-                [s.t0 if s is not None else pool.kv + 1
-                 for s in pool.streams],
-                np.int32,
-            )
-            eager = any(
-                s is not None and s.eager for s in pool.streams
-            )
-            # ``pool.pos`` is host state mutated in place right after
-            # this dispatch; jax's CPU backend may alias numpy buffers
-            # zero-copy, so a lazily-executed step would read positions
-            # from the FUTURE once the host loop runs ahead of the
-            # device (e.g. behind a bucket-grow compile).  Snapshot per
-            # dispatch — ``t0s``/``live`` above are already fresh
-            # per-call arrays.
-            pos_now = pool.pos.copy()
-            # What this step does, slot by slot: a live slot whose next
-            # position is still inside its prompt consumes a prompt
-            # token (prefill, one token a step), any other live slot
-            # produces an output token; each attends over the keys up
-            # to and with its own position.
-            nxt = pos_now + 1
-            n_live = int(live.sum())
-            n_prompt = int((live & (nxt < t0s)).sum())
-            n_keys = int(nxt[live].sum())
-            self._count(pool, n_prompt, n_live - n_prompt, n_keys)
-            t_start = time.perf_counter()
-            col = self._call(pool, step, pos_now, t0s, live)
-        with phases("sync"):
-            col_host = None
-            if eager or pool.steps % _SYNC_STRIDE == 0:
-                # SSE wants the token NOW; lazy pools sync on a stride
-                # so async dispatch pipelines the loop like the solo
-                # scan.
-                col_host = np.asarray(col)
+        # Positions advance when a step is dispatched: a slot whose
+        # stream has had its last step dispatched sits this one out,
+        # seated until that step's result is read.
+        live = np.array(
+            [s is not None and pool.pos[i] < s.total - 1
+             for i, s in enumerate(pool.streams)], bool
+        )
+        before, pool.unread = pool.unread, None
+        if live.any():  # else the turn only drains: no step for nothing
+            with self.phases("dispatch"):
+                # The chaos probe stands where the step is dispatched:
+                # a delay armed on it reads as dispatch time.
+                faults.hit("serve.decode_step")
+                pool.unread = self._dispatch(
+                    pool, live, ahead=before is not None
+                )
+        if before is not None:
+            self._read_step(pool, before)
+
+    def _dispatch(self, pool: PagePool, live, ahead: bool) -> tuple:
+        """Enqueue a one-token pool's next step for the ``live`` slots
+        and return what reading it will need: its token column, the
+        streams it stepped, their positions after it, and the terminal
+        buffer rows of the lazy streams it ends.  ``ahead``: the step
+        before it is still unread."""
+        step, _ = self._step_for(pool.nslots, pool.kv)
+        t0s = np.array(
+            [s.t0 if s is not None else pool.kv + 1
+             for s in pool.streams],
+            np.int32,
+        )
+        # A fresh array a dispatch (``pool.pos`` is mutated right
+        # below, and jax's CPU backend may alias numpy buffers
+        # zero-copy, so a lazily-executed step would read positions
+        # from the FUTURE); a slot not live in this step goes in at
+        # position 0, like a free one.
+        pos_now = np.where(live, pool.pos, 0).astype(np.int32)
+        # What this step does, slot by slot: a live slot whose next
+        # position is still inside its prompt consumes a prompt token
+        # (prefill, one token a step), any other live slot produces an
+        # output token; each attends over the keys up to and with its
+        # own position.
+        nxt = pos_now + 1
+        n_live = int(live.sum())
+        n_prompt = int((live & (nxt < t0s)).sum())
+        n_keys = int(nxt[live].sum())
+        self._count(pool, n_prompt, n_live - n_prompt, n_keys)
+        if ahead:
+            self.steps_ahead += 1
+            self._turn["ahead"] += 1
+        else:
+            # From a drained pool: the chip's time starts here.
+            pool.read_at = time.perf_counter()
+        col = self._call(pool, step, pos_now, t0s, live)
+        # The column starts for the host the moment its step ends,
+        # whatever the worker is doing then.
+        col.copy_to_host_async()
+        pool.pos[live] = nxt[live]
+        stepped = [s if on else None for s, on in zip(pool.streams, live)]
+        # Terminal: the full row (prompt + continuation) is in the
+        # buffer this step returns; a lazy stream surfaces everything
+        # from it.  The slice is enqueued here, before the next step
+        # consumes that buffer, and read with the column.
+        rows = {
+            slot: pool.buf[slot]
+            for slot, stream in enumerate(stepped)
+            if stream is not None and not stream.eager
+            and nxt[slot] >= stream.total - 1
+        }
+        return col, stepped, nxt, rows
+
+    def _read_step(self, pool: PagePool, unread: tuple) -> None:
+        """Read a dispatched step's result back (what ``_dispatch``
+        returned for it), hand its tokens to the eager streams and
+        finish the streams whose last step it was."""
+        col, stepped, nxt, rows = unread
+        with self.phases("sync"):
+            col_host = np.asarray(col)
+            rows = {slot: np.asarray(row) for slot, row in rows.items()}
             now = time.perf_counter()
-            # Terminal: the full row (prompt + continuation) is in the
-            # buffer; lazy streams surface everything from it.
-            rows = {
-                slot: np.asarray(pool.buf[slot])
-                for slot, stream in enumerate(pool.streams)
-                if stream is not None
-                and nxt[slot] >= stream.total - 1
-            }
-        with phases("emit"):
-            for slot, stream in enumerate(pool.streams):
-                if stream is None:
+        with self.phases("emit"):
+            for slot, stream in enumerate(stepped):
+                # Not in that step, or aborted (and its slot perhaps
+                # seated anew) since it was dispatched.
+                if stream is None or pool.streams[slot] is not stream:
                     continue
                 nxt_pos = int(nxt[slot])
-                pool.pos[slot] = nxt_pos
-                if nxt_pos >= stream.t0 and col_host is not None \
-                        and stream.eager:
+                if stream.eager and nxt_pos >= stream.t0:
                     self._emit(stream, int(col_host[slot]), nxt_pos, now)
-                row = rows.get(slot)
-                if row is not None:
+                if nxt_pos >= stream.total - 1:
                     if not stream.eager:
-                        stream.tokens = [
-                            int(t) for t in row[stream.t0: stream.total]
-                        ]
-                        stream.first_at = stream.first_at or now
-                        _decode_hists.ttft(
-                            stream.first_at - stream.arrived, self.name
-                        )
-                        obs_flight.record(
-                            "decode", "ttft",
-                            model=self.name, stream=stream.stream_id,
-                            ttftS=round(
-                                stream.first_at - stream.arrived, 4
-                            ),
-                        )
-                        _decode_hists.tokens(
-                            len(stream.tokens), self.name
-                        )
+                        self._surface(stream, rows[slot], now)
                     pool.release(slot)
-                    self._finish(stream, row=row)
-            # Devtime attribution flushes at every host sync, whichever
-            # transport forced it — eager token read (per step), lazy
-            # stride boundary, or a terminal row read — so non-stream
-            # decode feeds the autoscaler's LO_TPU_FLEET_UP_DEVICE_FRAC
-            # signal too.  Between syncs the async backlog's device
-            # work is paid inside the syncing call, so measuring to
-            # HERE (past the row reads above) captures the stride's
-            # full cost as one amortized sample.
-            pool.pending_devtime += time.perf_counter() - t_start
-            if col_host is not None or rows:
-                self._flush_devtime(pool)
+                    self._finish(stream)
+            # The wall time between successive reads is the chip's time
+            # for a step while one is always in flight (measured to
+            # HERE, past the row reads), whichever transport the
+            # streams ride: non-stream decode feeds the autoscaler's
+            # LO_TPU_FLEET_UP_DEVICE_FRAC signal too.
+            done_at = time.perf_counter()
+            self._record_devtime(pool, done_at - pool.read_at)
+            pool.read_at = done_at
+
+    def _surface(self, stream: DecodeStream, row, now: float) -> None:
+        """A lazy stream's tokens, all at once from its terminal buffer
+        row."""
+        stream.tokens = [int(t) for t in row[stream.t0: stream.total]]
+        stream.first_at = stream.first_at or now
+        ttft_s = stream.first_at - stream.arrived
+        _decode_hists.ttft(ttft_s, self.name)
+        obs_flight.record(
+            "decode", "ttft",
+            model=self.name, stream=stream.stream_id,
+            ttftS=round(ttft_s, 4),
+        )
+        _decode_hists.tokens(len(stream.tokens), self.name)
 
     def _count(self, pool: PagePool, prompt: int, output: int,
                keys: int) -> None:
@@ -633,7 +688,7 @@ class _ModelDecoder:
             self._turn["inplace"] += 1
         return col
 
-    def _flush_devtime(self, pool: PagePool) -> None:
+    def _record_devtime(self, pool: PagePool, seconds: float) -> None:
         from learningorchestra_tpu.obs import costs as obs_costs
 
         if obs_costs.enabled():
@@ -641,10 +696,9 @@ class _ModelDecoder:
             weight = led.will_record(self.name)
             if weight:
                 led.record_model(
-                    weight, pool.pending_devtime, None, None,
+                    weight, seconds, None, None,
                     self.name, f"dec{pool.nslots}x{pool.kv}",
                 )
-            pool.pending_devtime = 0.0
 
     def _step_blocks(self, pool: PagePool) -> None:
         """One turn of a pool whose model generates by diffusion over
@@ -726,8 +780,7 @@ class _ModelDecoder:
                     self._finish(stream)
                 else:
                     pool.blocks[slot] = stream.block_at(start + q)
-            pool.pending_devtime += time.perf_counter() - t_start
-            self._flush_devtime(pool)
+            self._record_devtime(pool, time.perf_counter() - t_start)
 
     def _emit(self, stream: DecodeStream, tok: int, pos: int,
               now: float, step=None) -> None:
@@ -745,7 +798,7 @@ class _ModelDecoder:
         stream.push_token(tok, pos, step)
         _decode_hists.tokens(1, self.name)
 
-    def _finish(self, stream: DecodeStream, *, row=None,
+    def _finish(self, stream: DecodeStream, *,
                 error: str | None = None,
                 aborted: bool = False) -> None:
         if error is not None:
@@ -804,6 +857,7 @@ class _ModelDecoder:
             "pending": pending,
             "steps": self.steps,
             "stepsInPlace": self.steps_in_place,
+            "stepsAhead": self.steps_ahead,
             "pools": pools,
             # Cumulative, from the worker's own counts (each step, each
             # live slot is one slot-step: prompt while it consumes its

@@ -216,10 +216,16 @@ class PagePool:
     Only the owning model's decode worker thread touches a pool, so the
     pool itself is lock-free; the worker's condition variable is the
     synchronization point for admission and abort.
+
+    A one-token pool has a step in flight most of the time.  ``pos`` is
+    the host's: it advances when a step is dispatched, not when its
+    result is read.  ``admit``, ``release`` and ``_grow`` only enqueue
+    device updates on what the last dispatched step returned, which
+    jax orders behind it; nothing here reads the device.
     """
 
     __slots__ = ("kv", "nslots", "max_slots", "cache", "buf", "pos",
-                 "streams", "steps", "replica_idx", "pending_devtime",
+                 "streams", "steps", "replica_idx", "unread", "read_at",
                  "width", "blocks")
 
     def __init__(self, kv: int, max_slots: int,
@@ -234,11 +240,10 @@ class PagePool:
         self.drop()
         self.steps = 0
         self.replica_idx = replica_idx
-        # Step wall time not yet flushed to the devtime ledger: lazy
-        # pools dispatch async and only pay the device sync on the
-        # stride boundary, so per-step times are accumulated here and
-        # recorded as one amortized sample at each sync.
-        self.pending_devtime = 0.0
+        # When the worker last read a step of this pool back (or
+        # dispatched one into it drained): the devtime ledger is fed
+        # the wall time between successive reads.
+        self.read_at = 0.0
 
     # -- capacity ------------------------------------------------------------
 
@@ -269,6 +274,9 @@ class PagePool:
         seated = [s for s in self.streams if s is not None]
         self.cache = None  # device tree, allocated on first admit
         self.buf = None    # (S, Tk) int32 token buffer
+        # A one-token pool's step in flight: what the engine needs to
+        # read its result, kept from its dispatch to the turn after.
+        self.unread = None
         self.nslots = 0
         self.pos = np.zeros(0, np.int32)
         self.streams = []

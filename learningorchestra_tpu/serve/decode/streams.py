@@ -8,10 +8,10 @@ mid-body — the PR-14 :class:`~learningorchestra_tpu.jobs.cancel.
 CancelToken` carries that request into the decode worker, which frees
 the stream's KV pages and slot at the next step boundary.
 
-Non-stream requests ride the same object (``eager=False``): the engine
-skips the per-step device sync for them (jax's async dispatch pipelines
-the whole decode like the solo ``lax.scan`` does) and the HTTP thread
-blocks on :meth:`wait_done`.
+Non-stream requests ride the same object (``eager=False``) through the
+same steps: the engine hands them no token a step, surfaces all their
+tokens from the terminal buffer row when their last step is read, and
+the HTTP thread blocks on :meth:`wait_done`.
 """
 
 from __future__ import annotations
@@ -52,8 +52,8 @@ class DecodeStream:
         self.span = self.total if plan is None \
             else -(-self.total // plan.block) * plan.block
         # eager: the transport wants every token as it lands (SSE), so
-        # the worker syncs the step's token column to host each step.
-        # Lazy streams let dispatch run ahead; tokens surface at done.
+        # the worker hands it each step's token as that step is read.
+        # A lazy stream's tokens surface together at done.
         self.eager = bool(eager)
         self.token = CancelToken()
         self.events: queue.Queue = queue.Queue(maxsize=_QUEUE_CAP)

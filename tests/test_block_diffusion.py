@@ -67,6 +67,31 @@ def api(tmp_path_factory, est):
     server.shutdown()
 
 
+@pytest.fixture
+def annotations(monkeypatch):
+    """``(name, metadata)`` of every ``obs.tracing.annotation`` that
+    closes while the test runs."""
+    from learningorchestra_tpu.obs import tracing
+
+    seen = []
+
+    class Recorded:
+        def __init__(self, name, **metadata):
+            self.name, self.metadata = name, dict(metadata)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            seen.append((self.name, self.metadata))
+
+        def set_metadata(self, **metadata):
+            self.metadata.update(metadata)
+
+    monkeypatch.setattr(tracing, "annotation", Recorded)
+    return seen
+
+
 def _publish(server, name, estimator):
     server.ctx.volumes.save_object("train/tensorflow", name, estimator)
     server.ctx.artifacts.metadata.create(name, "train/tensorflow")
@@ -257,31 +282,13 @@ def test_slots_admitted_mid_flight(api, est):
     assert stats["stepsInPlace"] == stats["steps"]
 
 
-def test_step_annotation_carries_the_block_counters(api, monkeypatch):
+def test_step_annotation_carries_the_block_counters(api, annotations):
     """``lo:decode.step`` gains positions, fixed, the slot-steps by
     phase and the experts reached, beside what it had."""
-    from learningorchestra_tpu.obs import tracing
-
-    seen = []
-
-    class Recorded:
-        def __init__(self, name, **metadata):
-            self.name, self.metadata = name, dict(metadata)
-
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *exc):
-            seen.append((self.name, self.metadata))
-
-        def set_metadata(self, **metadata):
-            self.metadata.update(metadata)
-
-    monkeypatch.setattr(tracing, "annotation", Recorded)
     _, base = api
     _stream(base, "bd", [5, 6, 7, 8, 9, 10], maxNewTokens=6,
             denoisingSteps=2, remasking="low_confidence_static")
-    turns = [md for name, md in seen
+    turns = [md for name, md in annotations
              if name == "decode.step" and md.get("positions")]
     assert turns
     for md in turns:
@@ -293,6 +300,31 @@ def test_step_annotation_carries_the_block_counters(api, monkeypatch):
     assert sum(md["commit"] for md in turns) == 2
     assert sum(md["denoise"] for md in turns) == 1 + 2
     assert sum(md["fixed"] for md in turns) == 2 + 4
+
+
+def test_a_block_pool_never_steps_ahead(api, est, annotations):
+    """The strategy decides a block pool's next input from its last
+    result, so its turn reads the step it dispatched: no step is
+    enqueued with the one before it unread (``stepsAhead`` stays 0,
+    every turn's annotation says ``ahead`` 0), and tokens and fixing
+    order are the reference's as before."""
+    server, base = api
+    prompt = [7, 3, 9, 2, 6]
+    toks, got_steps = _stream(
+        base, "bd", prompt, maxNewTokens=7, denoisingSteps=2,
+        remasking="low_confidence_static",
+    )
+    want, want_steps = oracle.generate(
+        est, prompt, 7, 2, "low_confidence_static"
+    )
+    assert prompt + toks == want.tolist()
+    assert got_steps == {p: s for p, s in want_steps.items()
+                         if 5 <= p < 5 + 7}
+    stats = server.serving.decode.stats()["models"]["bd"]
+    assert stats["steps"] > 0 and stats["stepsAhead"] == 0
+    turns = [md for name, md in annotations
+             if name == "decode.step" and md.get("positions")]
+    assert turns and all(md["ahead"] == 0 for md in turns)
 
 
 def test_nonstream_and_defaults(api, est):

@@ -617,6 +617,270 @@ class TestStepOwnsItsState:
         )["tokens"][0] == solo
 
 
+class TestOneStepInFlight:
+    """A one-token pool's turn enqueues step k+1 before it reads step
+    k's tokens back: the host's turn runs beside the chip, one step
+    deep, for SSE and non-stream requests alike; tokens leave one turn
+    late and none is lost, whatever ends the pool."""
+
+    @staticmethod
+    def _spy(monkeypatch, decoder, *, step_wrap=None):
+        """``events``: ("step", id of its column) for every step the
+        decoder enqueues, ("read", id of the column) for every host
+        read, in the order they happened."""
+        events = []
+        real_read = decoder._read_step
+
+        def spied(step):
+            if step_wrap is not None:
+                step = step_wrap(step)
+
+            def stepped(*args, **kwargs):
+                out = step(*args, **kwargs)
+                events.append(("step", id(out[2])))
+                return out
+            return stepped
+
+        def read_step(pool, unread):
+            events.append(("read", id(unread[0])))
+            return real_read(pool, unread)
+
+        TestStepOwnsItsState._wrap_steps(monkeypatch, decoder, spied)
+        monkeypatch.setattr(decoder, "_read_step", read_step)
+        return events
+
+    @staticmethod
+    def _solo(est, prompt, new):
+        return np.asarray(est.generate(
+            np.asarray([prompt], np.int32), max_new_tokens=new
+        ))[0].tolist()
+
+    @pytest.mark.parametrize("stream", [True, False],
+                             ids=["sse", "nonstream"])
+    def test_next_step_is_enqueued_before_the_last_is_read(
+            self, decode_api, monkeypatch, stream):
+        server, _, est = decode_api
+        eng = server.serving.decode
+        decoder = eng._decoder_for("lm_srv")
+        events = self._spy(monkeypatch, decoder)
+        before = TestDecodeLoopAccounting._model_stats(eng)
+        prompt, new = [5, 3, 2], 6
+        out = eng.generate("lm_srv", [prompt], max_new_tokens=new,
+                           stream=stream)
+        if stream:
+            assert out.wait_done(60) and out.error is None
+            tokens = prompt + out.tokens
+        else:
+            tokens = out["tokens"][0]
+        assert tokens == self._solo(est, prompt, new)
+        after = eng.stats()["models"]["lm_srv"]
+        n = len(prompt) - 1 + new
+        assert after["steps"] - before["steps"] == n
+        steps = [col for kind, col in events if kind == "step"]
+        reads = [col for kind, col in events if kind == "read"]
+        # Every step is read once, in order, and step k+1 was on the
+        # chip's queue before step k's column was asked for: the pool
+        # started drained, so all but its first step were ahead.
+        assert reads == steps and len(steps) == n
+        for k in range(n - 1):
+            assert events.index(("step", steps[k + 1])) \
+                < events.index(("read", steps[k]))
+        assert after["stepsAhead"] - before.get("stepsAhead", 0) == n - 1
+        # The last token and ``done`` came from a turn that only read:
+        # no step was dispatched for them.
+        assert [kind for kind, _ in events[-2:]] == ["read", "read"]
+
+    def test_a_drained_pool_idles_out_with_every_token_delivered(
+            self, decode_api, monkeypatch):
+        """One stream, then nothing: the turn after its last step only
+        reads, the stream ends whole, and only then does the worker
+        park, idle past the knob and clear its pools; the next request
+        starts a new worker on a pool allocated afresh."""
+        server, _, est = decode_api
+        eng = server.serving.decode
+        decoder = eng._decoder_for("lm_srv")
+        monkeypatch.setattr(eng.cfg, "idle_timeout_s", 0.2)
+        prompt, new = [4, 4, 2, 1], 7
+        stream = eng.generate("lm_srv", prompt, max_new_tokens=new,
+                              stream=True)
+        events = list(stream.sse_events())
+        assert [e for e, _ in events][-1] == "done"
+        toks = [doc["t"] for e, doc in events if e == "token"]
+        solo = self._solo(est, prompt, new)
+        assert prompt + toks == solo
+        assert events[-1][1]["tokens"] == toks and len(toks) == new
+        deadline = time.monotonic() + 10
+        while decoder._thread is not None and time.monotonic() < deadline:
+            time.sleep(0.02)
+        assert decoder._thread is None and decoder._pools == {}
+        out = eng.generate("lm_srv", [prompt], max_new_tokens=new)
+        assert out["tokens"][0] == solo
+
+    def test_close_with_a_result_unread_loses_no_token(
+            self, decode_api, monkeypatch):
+        """A decoder that closes mid-stream reads its step in flight
+        back first: the stream holds a token for every output step that
+        was dispatched for it, then fails as shut down."""
+        server, _, est = decode_api
+        eng = server.serving.decode
+        decoder = eng._decoder_for("lm_srv")
+        events = self._spy(monkeypatch, decoder)
+        prompt, new = [7, 2, 4, 1], 12
+        try:
+            faults.arm("serve.decode_step", "delay", delay_ms=40,
+                       max_triggers=256)
+            stream = eng.generate("lm_srv", prompt, max_new_tokens=new,
+                                  stream=True)
+            deadline = time.monotonic() + 30
+            while len(stream.tokens) < 2 and time.monotonic() < deadline:
+                time.sleep(0.005)
+            assert 0 < len(stream.tokens) < new, "stream not mid-flight"
+            eng.drop_model("lm_srv")  # closes the decoder
+        finally:
+            faults.reset()
+        assert stream.done()
+        dispatched = sum(kind == "step" for kind, _ in events)
+        assert sum(kind == "read" for kind, _ in events) == dispatched
+        assert len(stream.tokens) == dispatched - (len(prompt) - 1)
+        assert len(stream.tokens) < new
+        assert stream.error == "decode engine shut down"
+        solo = self._solo(est, prompt, new)
+        assert prompt + stream.tokens == solo[: len(prompt) + len(
+            stream.tokens)]
+        # A new decoder serves the model from here on.
+        out = eng.generate("lm_srv", [prompt], max_new_tokens=new)
+        assert out["tokens"][0] == solo
+
+    @pytest.mark.parametrize("where", ["dispatch", "read"])
+    def test_a_step_that_raises_with_one_in_flight_costs_its_pool_only(
+            self, decode_api, monkeypatch, where):
+        """Two pools (kv 16 and kv 8) step side by side, each with a
+        step in flight; the kv-16 pool's step raises, when it is
+        enqueued or when its column is read with its successor already
+        enqueued: that pool's streams fail, the in-flight step's
+        included, the other pool's stream is whole, and the worker
+        serves the next request."""
+        import jax
+
+        server, _, est = decode_api
+        eng = server.serving.decode
+        decoder = eng._decoder_for("lm_srv")
+        broken = threading.Event()
+
+        class Unreadable:
+            def copy_to_host_async(self):
+                pass
+
+            def __array__(self, *args, **kwargs):
+                raise RuntimeError("chip fell over")
+
+        def breaking(step):
+            def stepped(variables, cache, buf, *rest):
+                if buf.shape[1] != 16 or not broken.is_set():
+                    return step(variables, cache, buf, *rest)
+                if where == "read":
+                    cache, buf, _col = step(variables, cache, buf, *rest)
+                    return cache, buf, Unreadable()
+                for leaf in jax.tree_util.tree_leaves((cache, buf)):
+                    leaf.delete()
+                raise RuntimeError("chip fell over")
+            return stepped
+
+        events = self._spy(monkeypatch, decoder, step_wrap=breaking)
+        try:
+            faults.arm("serve.decode_step", "delay", delay_ms=20,
+                       max_triggers=512)
+            doomed = [
+                eng.generate("lm_srv", prompt, max_new_tokens=12,
+                             stream=True)
+                for prompt in ([7, 2, 4, 1], [3, 9, 1, 5])
+            ]
+            spared = eng.generate("lm_srv", [6, 1, 3], max_new_tokens=5,
+                                  stream=True)
+            deadline = time.monotonic() + 30
+            while time.monotonic() < deadline and not all(
+                len(s.tokens) >= 2 for s in doomed
+            ):
+                time.sleep(0.005)
+            assert all(0 < len(s.tokens) < 12 for s in doomed)
+            pool = decoder._pools[(None, 16)]
+            assert pool.unread is not None or pool.live == 2
+            broken.set()
+            for s in (*doomed, spared):
+                assert s.wait_done(30)
+        finally:
+            faults.reset()
+        for s in doomed:
+            assert "chip fell over" in (s.error or ""), s.error
+        assert spared.error is None
+        assert [6, 1, 3] + spared.tokens == self._solo(est, [6, 1, 3], 5)
+        st = eng.stats()["models"]["lm_srv"]
+        assert st["activeStreams"] == 0
+        assert [(p["pageBytes"], p["slots"], p["live"])
+                for p in st["pools"] if p["kv"] == 16] == [(0, 0, 0)]
+        assert decoder._pools[(None, 16)].unread is None
+        assert decoder._thread is not None and decoder._thread.is_alive()
+        # More steps were enqueued than read: one was in flight when
+        # the pool went.
+        assert sum(kind == "step" for kind, _ in events) \
+            > sum(kind == "read" for kind, _ in events) - (where == "read")
+        broken.clear()
+        prompt = [3, 9, 1, 5, 2, 8, 4, 6]
+        out = eng.generate("lm_srv", [prompt], max_new_tokens=8)
+        assert out["tokens"][0] == self._solo(est, prompt, 8)
+
+    def test_a_slot_freed_under_a_step_in_flight_is_seated_anew(
+            self, decode_api, monkeypatch):
+        """A ends while C is mid-flight; B is then admitted into the
+        slot A left, its prompt row enqueued behind C's step in flight:
+        B (non-stream) and C decode exactly their solo results."""
+        server, _, est = decode_api
+        eng = server.serving.decode
+        decoder = eng._decoder_for("lm_srv")
+        in_flight_at_admit = {}
+        real_admit = decoder._admit
+
+        def admit(stream):
+            in_flight_at_admit[stream.stream_id] = any(
+                p.unread is not None for p in decoder._pools.values()
+            )
+            return real_admit(stream)
+
+        monkeypatch.setattr(decoder, "_admit", admit)
+        started_at = time.monotonic()
+        prompt_c, prompt_a = [7, 2, 4, 1], [9, 2, 5, 5, 1, 3, 8]
+        prompt_b = [3, 9, 1, 5, 2, 8, 4, 6]
+        try:
+            faults.arm("serve.decode_step", "delay", delay_ms=30,
+                       max_triggers=256)
+            # First, so seated in slot 0; 7 + 2 tokens: the kv-16 pool.
+            a = eng.generate("lm_srv", prompt_a, max_new_tokens=2,
+                             stream=True)
+            deadline = time.monotonic() + 30
+            while not in_flight_at_admit and time.monotonic() < deadline:
+                time.sleep(0.002)
+            c = eng.generate("lm_srv", prompt_c, max_new_tokens=12,
+                             stream=True)
+            assert a.wait_done(30) and a.error is None
+            assert not c.done(), "stream C not mid-flight"
+            out = eng.generate("lm_srv", [prompt_b], max_new_tokens=8)
+            assert c.wait_done(30) and c.error is None
+        finally:
+            faults.reset()
+        assert out["tokens"][0] == self._solo(est, prompt_b, 8)
+        assert prompt_c + c.tokens == self._solo(est, prompt_c, 12)
+        assert prompt_a + a.tokens == self._solo(est, prompt_a, 2)
+        b_id = out["streams"][0]["stream"]
+        assert in_flight_at_admit[b_id], "no step in flight at B's admit"
+        slots = {
+            e["stream"]: e["slot"]
+            for e in obs_flight.snapshot(["decode"])["events"]["decode"]
+            if e["kind"] == "admit" and e["t"] >= started_at
+        }
+        assert slots[b_id] == slots[a.stream_id]
+        assert slots[c.stream_id] != slots[a.stream_id]
+
+
 class TestDecodeSLO:
     def test_ttft_objective_fires_on_slow_decode(self):
         """The decode-TTFT objective drives the same burn-rate
