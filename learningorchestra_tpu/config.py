@@ -224,9 +224,13 @@ class DecodeConfig:
     # through the solo jitted scan; stream=true is refused (406).
     # Env: LO_TPU_DECODE_ENABLED.
     enabled: bool = True
-    # Largest slot bucket per KV page pool (power-of-two growth up to
-    # this): bounds concurrent in-flight sequences per (model, kv
-    # bucket) AND the slot dimension of every step executable.
+    # Ceiling of the slot buckets of a KV page pool: a pool grows
+    # through the powers of two up to this (1, 2, 4, ... max_slots; the
+    # ceiling itself is a bucket even where it is no power of two), so
+    # it bounds the sequences in flight per (model, kv bucket) AND the
+    # slot dimension of every step executable, and a KV bucket costs
+    # floor(log2(max_slots - 1)) + 2 step programs to compile and keep:
+    # 4 at the default 8, 7 at 64 (``bucketing.bucket_sizes``).
     # Env: LO_TPU_DECODE_MAX_SLOTS.
     max_slots: int = 8
     # Largest KV-length bucket (pages per slot); also caps prompt+

@@ -17,6 +17,7 @@ cannot.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -26,7 +27,12 @@ from learningorchestra_tpu.models.text import (
     cls_head,
     embed_tokens,
 )
-from learningorchestra_tpu.ops.layers import MultiHeadSelfAttention, RMSNorm
+from learningorchestra_tpu.ops.latent_attention import LatentAttention
+from learningorchestra_tpu.ops.layers import (
+    GatedMlp,
+    MultiHeadSelfAttention,
+    RMSNorm,
+)
 from learningorchestra_tpu.ops.moe import MoEMlp, RoutedExperts
 from learningorchestra_tpu.toolkit.registry import register
 from learningorchestra_tpu.train.neural import NeuralEstimator
@@ -501,3 +507,218 @@ class BlockDiffusionMoELM(NeuralEstimator):
                     state.denoise(x0, conf)
                 row[start: start + b] = state.tokens
         return out[:, : min(total, t0 + max_new_tokens)]
+
+
+class LatentExpertBlock(nn.Module):
+    """Pre-RMSNorm block of a DeepSeek-V3-shaped model: latent
+    attention (``ops/latent_attention.py``), then either a dense gated
+    FFN of ``mlp_dim`` (``routed=False``: a leading dense layer) or
+    sigmoid-routed SwiGLU experts with a shared expert of
+    ``shared_dim`` that every token passes beside them."""
+
+    attention: tuple  # LatentAttention's fields, as sorted items
+    routed: bool
+    mlp_dim: int
+    expert_dim: int
+    num_experts: int
+    top_k: int
+    shared_dim: int
+    routed_scale: float = 1.0
+    experts_held: tuple | None = None
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype | None = None
+    param_dtype: jnp.dtype = jnp.float32
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, key_mask=None):
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+
+        def norm(name):
+            return RMSNorm(self.norm_eps, name=name, **kw)
+
+        x = x + LatentAttention(
+            **dict(self.attention), norm_eps=self.norm_eps,
+            decode=self.decode, **kw,
+        )(norm("attn_norm")(x), key_mask=key_mask)
+        y = norm("ffn_norm")(x)
+        if not self.routed:
+            return x + GatedMlp(self.mlp_dim, **kw)(y)
+        out = RoutedExperts(
+            num_experts=self.num_experts,
+            expert_dim=self.expert_dim,
+            top_k=self.top_k,
+            held=self.experts_held,
+            scoring="sigmoid",
+            score_bias=True,
+            routed_scale=self.routed_scale,
+            **kw,
+        )(y)
+        if self.shared_dim:
+            with jax.named_scope("moe_shared"):
+                out = out + GatedMlp(
+                    self.shared_dim, name="shared_expert", **kw
+                )(y)
+        return x + out
+
+
+class _LatentMoE(nn.Module):
+    """Token embedding, ``first_dense`` dense :class:`LatentExpertBlock`
+    layers and routed ones after them, final RMSNorm and an untied
+    bias-free head: a causal LM."""
+
+    vocab_size: int
+    hidden_dim: int
+    num_layers: int
+    first_dense: int
+    attention: tuple  # LatentAttention's fields, as sorted items
+    mlp_dim: int
+    expert_dim: int
+    num_experts: int
+    top_k: int
+    shared_dim: int
+    routed_scale: float = 1.0
+    experts_held: tuple | None = None
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype | None = None
+    param_dtype: jnp.dtype = jnp.float32
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, tokens, positions=None, key_mask=None):
+        # ``positions`` is the decode step's; attention takes them from
+        # its own cache index.
+        del positions
+        tokens = tokens.astype(jnp.int32)
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        x = nn.Embed(self.vocab_size, self.hidden_dim, **kw)(tokens)
+        if key_mask is None:
+            key_mask = tokens != 0  # (B, T), pad id 0
+        for i in range(self.num_layers):
+            x = LatentExpertBlock(
+                attention=self.attention,
+                routed=i >= self.first_dense,
+                mlp_dim=self.mlp_dim,
+                expert_dim=self.expert_dim,
+                num_experts=self.num_experts,
+                top_k=self.top_k,
+                shared_dim=self.shared_dim,
+                routed_scale=self.routed_scale,
+                experts_held=self.experts_held,
+                norm_eps=self.norm_eps,
+                decode=self.decode,
+                name=f"LatentExpertBlock_{i}",
+                **kw,
+            )(x, key_mask=key_mask)
+        x = RMSNorm(self.norm_eps, name="final_norm", **kw)(x)
+        return nn.Dense(
+            self.vocab_size, use_bias=False, name="head", **kw
+        )(x)  # (B, T, V)
+
+
+@register(_MODULE)
+class LatentMoELM(GreedyDecodeMixin, NeuralEstimator):
+    """Causal LM of the DeepSeek-V3 / Kimi-K2 shape: multi-head latent
+    attention (a ``kv_lora_rank + qk_rope_head_dim`` cache row a token,
+    YaRN rotary frequencies from ``rope_scaling``, the published
+    group), ``first_dense_layers`` dense SwiGLU layers of ``mlp_dim``,
+    then layers of ``num_experts`` sigmoid-routed SwiGLU experts
+    (chosen by score + bias, weighed by the score over the chosen's sum
+    times ``routed_scale``) beside ``shared_experts`` shared ones.
+
+    ``experts_held`` = (first, count) is this chip's share of an
+    expert-parallel deployment: the router scores all ``num_experts``
+    and the layer adds what its own experts give.  ``param_dtype`` is
+    the dtype the parameters are held in, in the artifact, in the
+    serving registry and on the device alike; the latent cache follows
+    it.  Router, norm statistics and softmax are float32 whatever it
+    says.  Served through the decode engine like any next-token model.
+    """
+
+    def __init__(
+        self,
+        vocab_size: int = 32000,
+        hidden_dim: int = 256,
+        num_layers: int = 4,
+        num_heads: int = 8,
+        q_lora_rank: int = 96,
+        kv_lora_rank: int = 64,
+        qk_nope_head_dim: int = 32,
+        qk_rope_head_dim: int = 16,
+        v_head_dim: int = 32,
+        mlp_dim: int = 1024,
+        first_dense_layers: int = 1,
+        expert_dim: int = 128,
+        num_experts: int = 16,
+        experts_per_token: int = 4,
+        shared_experts: int = 1,
+        routed_scale: float = 1.0,
+        experts_held: tuple | None = None,
+        rope_theta: float = 10000.0,
+        rope_scaling: dict | None = None,
+        norm_eps: float = 1e-6,
+        max_len: int = 1024,
+        param_dtype: str = "bfloat16",
+        learning_rate: float = 3e-4,
+        seed: int = 0,
+    ):
+        self.vocab_size = vocab_size
+        self.hidden_dim = hidden_dim
+        self.num_layers = num_layers
+        self.num_heads = num_heads
+        self.q_lora_rank = q_lora_rank
+        self.kv_lora_rank = kv_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+        self.mlp_dim = mlp_dim
+        self.first_dense_layers = first_dense_layers
+        self.expert_dim = expert_dim
+        self.num_experts = num_experts
+        self.experts_per_token = experts_per_token
+        self.shared_experts = shared_experts
+        self.routed_scale = routed_scale
+        self.experts_held = None if experts_held is None \
+            else tuple(experts_held)
+        self.rope_theta = rope_theta
+        self.rope_scaling = None if rope_scaling is None \
+            else dict(rope_scaling)
+        self.norm_eps = norm_eps
+        self.max_len = max_len
+        self.param_dtype = param_dtype
+        dtype = jnp.dtype(param_dtype)
+        attention = dict(
+            num_heads=num_heads, q_lora_rank=q_lora_rank,
+            kv_lora_rank=kv_lora_rank,
+            qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rope_theta=float(rope_theta),
+            # hashable, for the module's fingerprint and jit
+            rope_scaling=None if rope_scaling is None else tuple(sorted(
+                (k, v) for k, v in rope_scaling.items()
+                if not isinstance(v, str)
+            )),
+        )
+        super().__init__(
+            _LatentMoE(
+                vocab_size=vocab_size,
+                hidden_dim=hidden_dim,
+                num_layers=num_layers,
+                first_dense=first_dense_layers,
+                attention=tuple(sorted(attention.items())),
+                mlp_dim=mlp_dim,
+                expert_dim=expert_dim,
+                num_experts=num_experts,
+                top_k=experts_per_token,
+                shared_dim=shared_experts * expert_dim,
+                routed_scale=float(routed_scale),
+                experts_held=self.experts_held,
+                norm_eps=norm_eps,
+                dtype=dtype,
+                param_dtype=dtype,
+            ),
+            loss="softmax_ce",
+            learning_rate=learning_rate,
+            seed=seed,
+            compute_dtype=param_dtype,
+        )

@@ -96,6 +96,26 @@ class RMSNorm(nn.Module):
         return (y * scale.astype(jnp.float32)).astype(dt)
 
 
+class GatedMlp(nn.Module):
+    """Bias-free gated feed-forward (SwiGLU): ``(silu(x W_gate) * x
+    W_up) W_down``.  A dense layer's FFN, and the shared expert every
+    token passes beside its routed ones."""
+
+    mlp_dim: int
+    dtype: jnp.dtype | None = None
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            param_dtype=self.param_dtype, name=name)
+
+        hid = nn.silu(dense(self.mlp_dim, "gate")(x)) \
+            * dense(self.mlp_dim, "up")(x)
+        return dense(x.shape[-1], "down")(hid)
+
+
 class MultiHeadSelfAttention(nn.Module):
     """Self-attention with a key-side padding mask (B, T).
 

@@ -135,13 +135,35 @@ class MoEMlp(nn.Module):
         return jnp.einsum("btec,ebch->bth", combine.astype(dt), h2)
 
 
-def route_top_k(logits, top_k: int):
-    """Token-choice routing in float32: softmax over every expert, the
-    ``top_k`` largest, their gates renormalised to sum to 1.  Returns
-    (gates (N, k) float32, expert ids (N, k) int32)."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gates, ids = jax.lax.top_k(probs, top_k)
-    return gates / jnp.sum(gates, axis=-1, keepdims=True), ids
+def route_top_k(logits, top_k: int, scoring: str = "softmax",
+                bias=None, scale: float = 1.0):
+    """Token-choice routing in float32.  Returns (gates (N, k) float32,
+    expert ids (N, k) int32).
+
+    ``scoring="softmax"``: softmax over every expert, the ``top_k``
+    largest, their gates renormalised to sum to 1.  ``"sigmoid"``: each
+    expert's score is its own sigmoid; the ``top_k`` are those with the
+    largest score PLUS ``bias`` (a per-expert correction that balances
+    load and never weighs a result: DeepSeek-V3's ``noaux_tc`` with one
+    group), and the gates are the chosen scores WITHOUT the bias, over
+    their sum, times ``scale``."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    if bias is None:
+        gates, ids = jax.lax.top_k(scores, top_k)
+    else:
+        _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        gates = jnp.take_along_axis(scores, ids, axis=-1)
+    total = jnp.sum(gates, axis=-1, keepdims=True)
+    if scoring == "sigmoid":
+        total = total + 1e-20
+    gates = gates / total
+    return (gates if scale == 1.0 else gates * scale), ids
 
 
 def grouped_matmul(rows, weights, sizes):
@@ -150,24 +172,37 @@ def grouped_matmul(rows, weights, sizes):
     N): (M, N).  Rows behind the last group come out undefined.
 
     On the TPU this is jax's shipped megablox kernel where its tiles
-    divide the shapes, with whole-K tiles: with a few rows a group the
-    kernel is bound by the expert matrices it streams, and at the
-    routed layer's shapes (256 rows over 128 experts of 2048 x 768) it
-    ran the three matmuls in 1.52 ms against ``jax.lax.ragged_dot``'s
-    3.08 (88% against 43% of the HBM roofline; PERF.md, PR 29).
-    Anywhere else, and for shapes the tiles do not divide, it is
-    ``ragged_dot``, which XLA lowers on every backend."""
+    divide the shapes, with K tiles as large as 2,048 allows (the
+    largest whole number of 128-lane rows that divides K: 2,048 of
+    2,048, 1,792 of 7,168): with a few rows a group the kernel is bound
+    by the expert matrices it streams, and at the routed layer's shapes
+    (256 rows over 128 experts of 2048 x 768) it ran the three matmuls
+    in 1.52 ms against ``jax.lax.ragged_dot``'s 3.08 (88% against 43%
+    of the HBM roofline; PERF.md, PR 29).  Anywhere else, and for
+    shapes the tiles do not divide, it is ``ragged_dot``, which XLA
+    lowers on every backend."""
     m, k = rows.shape
     n = weights.shape[-1]
-    tiling = (min(m, 128), min(k, 2048), min(n, 1024))
-    if jax.default_backend() == "tpu" and m % 16 == 0 and all(
-        size % tile == 0 and tile % 128 == 0
-        for size, tile in ((k, tiling[1]), (n, tiling[2]))
-    ) and m % tiling[0] == 0:
+    tiling = (min(m, 128), _tile(k, 2048), _tile(n, 1024))
+    if jax.default_backend() == "tpu" and m % 16 == 0 \
+            and tiling[1] % 128 == 0 and tiling[2] % 128 == 0 \
+            and m % tiling[0] == 0:
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
         return gmm(rows, weights, sizes, rows.dtype, tiling)
     return jax.lax.ragged_dot(rows, weights, sizes)
+
+
+def _tile(size: int, cap: int) -> int:
+    """The largest divisor of ``size`` that is at most ``cap`` and a
+    whole number of 128-lane rows; ``size`` itself where it is under
+    the cap or has no such divisor."""
+    if size <= cap:
+        return size
+    for parts in range(-(-size // cap), size // 128 + 1):
+        if size % parts == 0 and (size // parts) % 128 == 0:
+            return size // parts
+    return size
 
 
 class RoutedExperts(nn.Module):
@@ -186,9 +221,16 @@ class RoutedExperts(nn.Module):
     part of the result its own experts give; a choice that lands on an
     absent expert adds nothing here (its chip adds it).
 
-    The router's matmul and softmax run in float32; the experts in
-    ``dtype``.  Sows ``moe_stats`` (``experts_hit``: experts with at
-    least one row; ``load_max``: rows of the busiest) when that
+    ``scoring``, ``score_bias`` and ``routed_scale`` are the router's
+    variant as a model's configuration states it
+    (:func:`route_top_k`): softmax scores renormalised over the chosen,
+    or sigmoid scores chosen by score + a ``score_bias`` parameter and
+    weighed by the score alone, times ``routed_scale``.
+
+    The router's matmul and scores run in float32; the experts in
+    ``dtype``.  Sows ``moe_stats`` (``experts_hit``: held experts with
+    at least one row; ``load_max``: rows of the busiest; ``rows``:
+    (token, choice) pairs that reached a held expert) when that
     collection is mutable.
     """
 
@@ -196,6 +238,9 @@ class RoutedExperts(nn.Module):
     expert_dim: int
     top_k: int = 2
     held: tuple | None = None
+    scoring: str = "softmax"
+    score_bias: bool = False
+    routed_scale: float = 1.0
     dtype: jnp.dtype | None = None
     param_dtype: jnp.dtype = jnp.float32
 
@@ -206,7 +251,17 @@ class RoutedExperts(nn.Module):
         x = x.reshape(-1, h)
         n = x.shape[0]
         first, count = self.held or (0, self.num_experts)
-        k = min(self.top_k, self.num_experts)
+        if first < 0 or count < 1 or first + count > self.num_experts:
+            raise ValueError(
+                f"held={(first, count)} names experts beyond the "
+                f"num_experts={self.num_experts} the router scores"
+            )
+        if self.top_k > self.num_experts:
+            raise ValueError(
+                f"top_k={self.top_k} passes num_experts="
+                f"{self.num_experts}"
+            )
+        k = self.top_k
         dt = self.dtype if self.dtype is not None else x.dtype
         init = nn.initializers.lecun_normal(batch_axis=(0,))
 
@@ -219,7 +274,13 @@ class RoutedExperts(nn.Module):
                 x.astype(jnp.float32), router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST,
             )
-            gates, ids = route_top_k(logits, k)  # (N, k)
+            bias = self.param(
+                "score_bias", nn.initializers.zeros, (self.num_experts,),
+                self.param_dtype,
+            ) if self.score_bias else None
+            gates, ids = route_top_k(
+                logits, k, self.scoring, bias, self.routed_scale
+            )  # (N, k)
 
         w_gate = self.param("w_gate", init, (count, h, self.expert_dim),
                             self.param_dtype)
@@ -253,7 +314,8 @@ class RoutedExperts(nn.Module):
         if self.is_mutable_collection("moe_stats") \
                 and not self.is_initializing():
             for name, value in (("experts_hit", jnp.sum(sizes > 0)),
-                                ("load_max", jnp.max(sizes))):
+                                ("load_max", jnp.max(sizes)),
+                                ("rows", jnp.sum(sizes))):
                 self.sow("moe_stats", name, value.astype(jnp.int32),
                          reduce_fn=lambda _, new: new,
                          init_fn=lambda: jnp.int32(0))

@@ -34,7 +34,7 @@ from learningorchestra_tpu.serve.decode.blocks import BlockPlan
 from learningorchestra_tpu.serve.decode.pages import (
     PagePool,
     build_step,
-    key_pages,
+    first_pages,
     step_width,
 )
 from learningorchestra_tpu.serve.decode.streams import DecodeStream
@@ -157,20 +157,27 @@ class _ModelDecoder:
         # One turn's share of the three counters above and what it
         # stepped: the ``lo:decode.step`` annotation's metadata.
         self._turn = {"prompt": 0, "output": 0, "keys": 0, "slots": 0,
-                      "kv": 0, "inplace": 0, "ahead": 0}
+                      "kv": 0, "inplace": 0, "ahead": 0,
+                      "kv_bytes_per_token": 0}
         # Generation by diffusion over blocks: slot-steps by phase
         # (whole prompt blocks prefilled, denoising forwards, commits),
-        # positions processed, tokens the denoising forwards fixed, and
-        # what the step program counted of its routed experts
-        # (distinct experts a layer, summed over layers and steps; the
-        # busiest expert's rows in any one step).
+        # positions processed, tokens the denoising forwards fixed.
         self.block_steps = {"prefill": 0, "denoise": 0, "commit": 0}
         self.positions = 0
         self.tokens_fixed = 0
+        self._block_turn = {"positions": 0, "fixed": 0, "denoise": 0,
+                            "commit": 0, "prefill": 0}
+        # What the step program counted of its routed experts, of
+        # either kind of pool (``pages._moe_stats``): distinct held
+        # experts a layer, summed over layers and steps; the busiest
+        # expert's rows in any one step; (token, choice) pairs that
+        # reached a held expert.  A one-token pool's are of the step a
+        # turn READ, which it dispatched the turn before.
         self.experts_hit = 0
         self.expert_load_max = 0
-        self._block_turn = {"positions": 0, "fixed": 0, "denoise": 0,
-                            "commit": 0, "prefill": 0, "experts_hit": 0}
+        self.expert_rows = 0
+        self._moe_turn = {"experts_hit": 0, "load_max": 0,
+                          "expert_rows": 0}
 
     # -- submission (any thread) --------------------------------------------
 
@@ -180,7 +187,9 @@ class _ModelDecoder:
                 raise ServeError(
                     f"decode for {self.name!r} is shut down"
                 )
-            active = len(self._streams) + len(self._pending)
+            # every stream not yet finished, seated or still pending
+            # (a pending one is in ``_streams`` too: counted once)
+            active = len(self._streams)
             if active >= self.cfg.max_streams:
                 obs_flight.record(
                     "decode", "queue_full",
@@ -268,7 +277,7 @@ class _ModelDecoder:
             t_turn = time.perf_counter()
             before = dict(phases.total)
             turn = self._turn
-            for counts in (turn, self._block_turn):
+            for counts in (turn, self._block_turn, self._moe_turn):
                 for key in counts:
                     counts[key] = 0
             with obs_tracing.annotation("decode.step") as step_ann:
@@ -312,6 +321,8 @@ class _ModelDecoder:
                 step_ann.set_metadata(**turn)
                 if self._block_turn["positions"]:
                     step_ann.set_metadata(**self._block_turn)
+                if self._moe_turn["load_max"]:
+                    step_ann.set_metadata(**self._moe_turn)
             turn_s = time.perf_counter() - t_turn
             if turn_s > _SLOW_STEP_S:
                 split = {
@@ -621,6 +632,7 @@ class _ModelDecoder:
             rows = {slot: np.asarray(row) for slot, row in rows.items()}
             now = time.perf_counter()
         with self.phases("emit"):
+            self._count_experts(col_host[len(stepped):])
             for slot, stream in enumerate(stepped):
                 # Not in that step, or aborted (and its slot perhaps
                 # seated anew) since it was dispatched.
@@ -672,12 +684,27 @@ class _ModelDecoder:
         turn["keys"] += keys
         turn["slots"] += pool.nslots
         turn["kv"] = max(turn["kv"], pool.kv)
+        turn["kv_bytes_per_token"] = pool.token_bytes()
+
+    def _count_experts(self, counted) -> None:
+        """A step's routed-expert counts as its program returned them
+        (``pages._moe_stats``; nothing from a dense model)."""
+        if len(counted) < 3:
+            return
+        hit, busiest, rows = (int(v) for v in counted[:3])
+        self.experts_hit += hit
+        self.expert_load_max = max(self.expert_load_max, busiest)
+        self.expert_rows += rows
+        turn = self._moe_turn
+        turn["experts_hit"] += hit
+        turn["load_max"] = max(turn["load_max"], busiest)
+        turn["expert_rows"] += rows
 
     def _call(self, pool: PagePool, step, *slots, **block):
         """Enqueue the pool's step.  The step consumes the cache and
         the buffer: from here on the pool holds only what it
         returned."""
-        went_in = key_pages(pool.cache)
+        went_in = first_pages(pool.cache)
         pool.cache, pool.buf, col = step(
             self._params_for(pool), pool.cache, pool.buf, *slots, **block
         )
@@ -753,10 +780,7 @@ class _ModelDecoder:
         with phases("emit"):
             x0 = col_host[:-1, :q]
             conf = col_host[:-1, q:].view(np.float32)
-            hit, busiest = int(col_host[-1, 0]), int(col_host[-1, 1])
-            self.experts_hit += hit
-            self.expert_load_max = max(self.expert_load_max, busiest)
-            bturn["experts_hit"] += hit
+            self._count_experts(col_host[-1])
             for slot, stream in enumerate(pool.streams):
                 if stream is None:
                     continue
@@ -801,14 +825,17 @@ class _ModelDecoder:
     def _finish(self, stream: DecodeStream, *,
                 error: str | None = None,
                 aborted: bool = False) -> None:
+        # Out of the active set BEFORE its caller hears of the end: a
+        # closed loop of ``max_streams`` callers sends its next request
+        # the moment the last one ends, and must find its place free.
+        with self._cv:
+            self._streams.pop(stream.stream_id, None)
         if error is not None:
             stream.fail(error)
         elif aborted:
             stream.mark_aborted()
         else:
             stream.finish()
-        with self._cv:
-            self._streams.pop(stream.stream_id, None)
 
     # -- lifecycle / observability -------------------------------------------
 
@@ -848,6 +875,7 @@ class _ModelDecoder:
                 "live": pool.live,
                 "steps": pool.steps,
                 "pageBytes": pool.page_bytes(),
+                "kvBytesPerToken": pool.token_bytes(),
                 "replica": pool.replica_idx,
             }
             for pool in pools_snap
@@ -871,14 +899,16 @@ class _ModelDecoder:
             "admitWaitS": self.admit_wait_s,
             # Generation by diffusion over blocks (all 0 for a
             # next-token model): slot-steps by phase, positions
-            # processed, tokens the denoising forwards fixed, experts
-            # the steps' rows reached (distinct a layer, summed over
-            # layers and steps), the busiest expert's rows in a step.
+            # processed, tokens the denoising forwards fixed.
             "blockSteps": dict(self.block_steps),
             "positions": self.positions,
             "tokensFixed": self.tokens_fixed,
+            # Routed experts, of a block model or a next-token one:
+            # held experts the steps' rows reached, the busiest one's
+            # rows in a step, (token, choice) pairs that reached one.
             "expertsHit": self.experts_hit,
             "expertLoadMax": self.expert_load_max,
+            "expertRows": self.expert_rows,
         }
 
     def close(self) -> None:

@@ -31,18 +31,24 @@ from __future__ import annotations
 
 import numpy as np
 
+#: The leaf an attention layer's cache is found by: a layer of
+#: ``MultiHeadSelfAttention`` keeps ``cached_key`` (with
+#: ``cached_value`` beside it), a layer of ``LatentAttention`` its one
+#: ``cached_latent``.
+PAGE_LEAVES = ("cached_key", "cached_latent")
+
 
 def set_index(pages, pos):
-    """The decode cache tree the module applies on: ``pages`` (the K/V
-    leaves a pool carries) with a ``cache_index`` beside every
-    ``cached_key``, each the per-slot position vector ``pos`` (S,) —
-    the step's single source of truth for where each slot writes and
-    how far it may attend."""
+    """The decode cache tree the module applies on: ``pages`` (the
+    leaves a pool carries) with a ``cache_index`` beside every layer's
+    page leaf (:data:`PAGE_LEAVES`), each the per-slot position vector
+    ``pos`` (S,) — the step's single source of truth for where each
+    slot writes and how far it may attend."""
     out = {
         key: set_index(val, pos) if isinstance(val, dict) else val
         for key, val in pages.items()
     }
-    if "cached_key" in pages:
+    if any(name in pages for name in PAGE_LEAVES):
         out["cache_index"] = pos
     return out
 
@@ -58,18 +64,34 @@ def strip_index(cache):
     }
 
 
-def key_pages(cache):
-    """The first K leaf of a decode cache tree: the one the engine asks
+def first_pages(cache):
+    """The first page leaf of a decode cache tree, a layer's K pages or
+    its latent pages (:data:`PAGE_LEAVES`): the one the engine asks
     ``is_deleted()`` after a step, to count the steps that updated the
     pages in place."""
     for key, val in cache.items():
-        if key == "cached_key":
+        if key in PAGE_LEAVES:
             return val
         if isinstance(val, dict):
-            found = key_pages(val)
+            found = first_pages(val)
             if found is not None:
                 return found
     return None
+
+
+def _moe_stats(mut) -> list:
+    """What the step's routed layers sowed, summed over layers:
+    [distinct held experts reached a layer, the busiest expert's rows,
+    (token, choice) pairs that reached a held expert]; empty for a
+    model without routed experts."""
+    import jax.numpy as jnp
+
+    sown = mut.get("moe_stats", {})
+    hit = _named(sown, "experts_hit")
+    if not hit:
+        return []
+    return [sum(hit), jnp.max(jnp.stack(_named(sown, "load_max"))),
+            sum(_named(sown, "rows"))]
 
 
 def _named(tree, name: str) -> list:
@@ -121,7 +143,10 @@ def build_step(module, nslots: int, kv: int):
     ``(S + 1, 2q)`` int32 array: per slot the ``q`` proposals ``x0``
     and the bits of their float32 confidences, and in the last row the
     experts the step's rows reached (distinct experts a layer, summed
-    over layers) and the busiest expert's rows.
+    over layers), the busiest expert's rows and the rows that reached a
+    held expert (:func:`_moe_stats`).  A next-token model with routed
+    experts returns the same three behind its token column: ``(S + 3,)``
+    where a dense model's is ``(S,)``.
     """
     import jax
     import jax.numpy as jnp
@@ -155,11 +180,8 @@ def build_step(module, nslots: int, kv: int):
         # softmax(logits)[x0], in float32
         conf = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), -1)
         stats = jnp.zeros(2 * q, jnp.int32)
-        hit = _named(mut.get("moe_stats", {}), "experts_hit")
-        if hit:
-            busiest = _named(mut["moe_stats"], "load_max")
-            stats = stats.at[0].set(sum(hit)) \
-                .at[1].set(jnp.max(jnp.stack(busiest)))
+        for i, value in enumerate(_moe_stats(mut)):
+            stats = stats.at[i].set(value)
         col = jnp.concatenate([
             jnp.concatenate(
                 [x0, jax.lax.bitcast_convert_type(conf, jnp.int32)], 1
@@ -176,7 +198,7 @@ def build_step(module, nslots: int, kv: int):
         logits, mut = decode_mod.apply(
             {**variables, "cache": cache}, tok,
             positions=pos[:, None], key_mask=kmask,
-            mutable=["cache"],
+            mutable=["cache", "moe_stats"],
         )
         step_logits = logits[:, 0].astype(jnp.float32)
         nxt = jnp.argmax(step_logits, -1).astype(jnp.int32)
@@ -189,6 +211,11 @@ def build_step(module, nslots: int, kv: int):
         # ``i + 1 >= t0`` select.
         col = jnp.where(live & (nxt_pos >= t0s), nxt, prev)
         buf = buf.at[jnp.arange(nslots), nxt_pos].set(col)
+        stats = _moe_stats(mut)
+        if stats:  # one result, one transfer: the counts ride the column
+            col = jnp.concatenate(
+                [col, jnp.stack(stats).astype(jnp.int32)]
+            )
         return strip_index(mut["cache"]), buf, col
 
     def step(variables, cache, buf, slots):
@@ -226,7 +253,7 @@ class PagePool:
 
     __slots__ = ("kv", "nslots", "max_slots", "cache", "buf", "pos",
                  "streams", "steps", "replica_idx", "unread", "read_at",
-                 "width", "blocks")
+                 "width", "blocks", "_token_bytes")
 
     def __init__(self, kv: int, max_slots: int,
                  replica_idx: int | None = None, width: int = 1):
@@ -252,7 +279,9 @@ class PagePool:
         return sum(1 for s in self.streams if s is not None)
 
     def page_bytes(self) -> int:
-        """Resident KV bytes — observability for the freeing tests.
+        """Resident KV bytes (per-head K and V pages, or a latent
+        layer's one ``kv_lora_rank + qk_rope_head_dim`` row a position)
+        — observability for the freeing tests.
         Shapes only (``nbytes`` is the aval's): the REST thread may
         call this while the worker is inside a step, when the tree it
         finds here has just been donated."""
@@ -264,6 +293,12 @@ class PagePool:
         return sum(
             leaf.nbytes for leaf in jax.tree_util.tree_leaves(cache)
         )
+
+    def token_bytes(self) -> float:
+        """Resident KV bytes a cached position (``page_bytes`` over
+        slots x pages): what a token costs the pool, all layers.  The
+        same at every slot bucket: worked out when the pool allocates."""
+        return self._token_bytes
 
     def drop(self) -> list:
         """Forget the device state, back to unallocated (the next admit
@@ -278,6 +313,7 @@ class PagePool:
         # read its result, kept from its dispatch to the turn after.
         self.unread = None
         self.nslots = 0
+        self._token_bytes = 0.0
         self.pos = np.zeros(0, np.int32)
         self.streams = []
         self.blocks: list = []
@@ -295,6 +331,7 @@ class PagePool:
         self.streams = [None] * nslots
         self.blocks = [None] * nslots
         self.nslots = nslots
+        self._token_bytes = self.page_bytes() / (nslots * self.kv)
 
     def _grow(self, cache_shapes, nslots: int) -> None:
         """Pad every per-slot axis up to the next slot bucket; existing

@@ -4,6 +4,7 @@ its jnp reference — the on-chip half of tests/test_tpu_lowering.py
 compile itself happens in libtpu, only here).
 
     chiprun -- python scripts/chip_kernel_check.py            # one chip
+    chiprun -- python scripts/chip_kernel_check.py latent     # those cases
     chiprun --chips 4 -- python scripts/chip_kernel_check.py  # + ring flash
 
 Fails without a TPU.  Every case runs; the exit code is non-zero if any
@@ -32,7 +33,7 @@ from learningorchestra_tpu.ops.attention import (
     flash_attention,
     mha_reference,
 )
-from learningorchestra_tpu.ops import decode_attention
+from learningorchestra_tpu.ops import decode_attention, latent_attention
 from learningorchestra_tpu.ops.quant import (
     dequantize_rowwise,
     quantize_rowwise,
@@ -156,7 +157,51 @@ def _decode_case(h: int, kvh: int, t: int, d: int, dtype) -> dict:
             "ok": same and err < (1e-4 if dtype == jnp.float32 else TOL)}
 
 
+def _latent_case(h: int, rank: int, rope: int, tk: int) -> dict:
+    """The absorbed latent attend over 8 slots of packed bfloat16
+    pages (two positions a row) against the plain form: slots at
+    different lengths, one on the bucket's last position, one free."""
+    rng = np.random.default_rng(h * 7 + rank)
+    b = 8
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+    q = draw(b, h, 1, rank + rope)
+    pages = latent_attention.insert_rows(
+        jnp.zeros(latent_attention.page_shape(b, tk, rank, rope),
+                  jnp.bfloat16),
+        draw(b, tk, rank), draw(b, tk, rope), jnp.zeros(b, jnp.int32),
+    )
+    idx = jnp.asarray(
+        [0, 300, tk - 1, 511, 512, 0, 100, tk // 2 + 3], jnp.int32)
+    buf = jnp.asarray(rng.integers(0, 5, (b, tk)) != 0).at[5].set(False)
+    mask = buf & (jnp.arange(tk)[None, :] <= idx[:, None])
+    scale = 0.1
+    row = draw(b, 1, rank + rope)
+
+    def plain(q, latent, key_pe, pages, idx, mask):
+        pages = latent_attention.insert_rows(pages, latent, key_pe, idx)
+        return latent_attention.plain_latent_attend(
+            q, pages, mask, rank, scale), pages
+
+    def kernel(q, latent, key_pe, pages, idx, mask):
+        return latent_attention.latent_attend_kernel(
+            q, latent, key_pe, pages, idx, mask, rank=rank, scale=scale)
+
+    # the kernel's pages are donated, as the engine's step donates them
+    ref, got = (
+        jax.jit(fn, donate_argnums=3)(
+            q, row[..., :rank], row[..., rank:], pages + 0, idx, mask)
+        for fn in (plain, kernel)
+    )
+    err = _max_err(ref[0], got[0])
+    same = bool(jnp.array_equal(ref[1], got[1]))
+    return {"out_err": err, "pages_equal": same, "ok": same and err < TOL}
+
+
 def main() -> int:
+    only = sys.argv[1] if len(sys.argv) > 1 else ""
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(f"no TPU: jax found {dev.platform}", file=sys.stderr)
@@ -182,6 +227,10 @@ def main() -> int:
         25, 25, 1, 64, jnp.float32)))
     cases.append(("decode_attend:32over4:t4:D128:bf16", lambda: _decode_case(
         32, 4, 4, 128, jnp.bfloat16)))
+    cases.append(("latent_attend:64h:512+64:Tk2048:bf16",
+                  lambda: _latent_case(64, 512, 64, 2048)))
+    cases.append(("latent_attend:64h:512+64:Tk256:bf16",
+                  lambda: _latent_case(64, 512, 64, 256)))
     if jax.device_count() >= 4:
         # chip_smoke.py's multi-chip phase owns the ring-flash check
         # (it raises on a miss).
@@ -192,6 +241,8 @@ def main() -> int:
         }))
     results = []
     for name, fn in cases:
+        if only not in name:  # a substring picks the cases to run
+            continue
         try:
             rec = {"case": name, **fn()}
         except Exception as exc:  # noqa: BLE001 — every case reports

@@ -482,7 +482,7 @@ class TestStepOwnsItsState:
 
     def test_every_step_updates_the_pages_in_place(
             self, decode_api, monkeypatch):
-        from learningorchestra_tpu.serve.decode.pages import key_pages
+        from learningorchestra_tpu.serve.decode.pages import first_pages
 
         server, _, est = decode_api
         eng = server.serving.decode
@@ -491,7 +491,7 @@ class TestStepOwnsItsState:
 
         def spy(step):
             def spied(variables, cache, buf, *rest):
-                went_in.append((key_pages(cache), buf))
+                went_in.append((first_pages(cache), buf))
                 return step(variables, cache, buf, *rest)
             return spied
 
@@ -513,7 +513,7 @@ class TestStepOwnsItsState:
         # What the pool holds now is what the last step returned, and
         # stats() reads its size from shapes alone.
         pool = decoder._pools[(None, 16)]
-        assert not key_pages(pool.cache).is_deleted()
+        assert not first_pages(pool.cache).is_deleted()
         assert not pool.buf.is_deleted()
         assert pool.page_bytes() > 0
         solo = np.asarray(est.generate(

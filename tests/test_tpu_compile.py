@@ -151,3 +151,57 @@ def test_block_step_of_an_sdar_attention_layer_is_one_pass(
         arg((8, 512), jnp.bool_),
     ).compile().as_text()
     _assert_one_pass_over_the_pages(text, pages, layers=1)
+
+
+@pytest.mark.parametrize("slots", [1, 8, 64])
+def test_latent_step_reads_the_packed_pages_once_at_kimi_k2_widths(
+        slots, one_chip, monkeypatch):
+    """The engine's step program of ``LatentMoELM`` at Kimi-K2's widths
+    (a dense and a routed layer, 12 of 384 experts held, bfloat16) over
+    a 2,048 bucket: one ``latent_attend`` kernel a layer over ONE page
+    leaf of 576 values a position, two positions a row; the leaf
+    updated in place by the kernel itself and never copied, relaid or
+    made anew; the experts' three grouped matmuls on the megablox kernel
+    where the rows fill a tile."""
+    import re
+
+    from learningorchestra_tpu.models.moe import LatentMoELM
+    from learningorchestra_tpu.serve.decode.pages import build_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    est = LatentMoELM(
+        vocab_size=20480, hidden_dim=7168, num_layers=2, num_heads=64,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, mlp_dim=18432,
+        first_dense_layers=1, expert_dim=2048, num_experts=384,
+        experts_per_token=8, routed_scale=2.827, experts_held=(0, 12),
+        rope_theta=50000.0, rope_scaling={
+            "beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1,
+            "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
+        }, norm_eps=1e-5, max_len=262144,
+    )
+    step, pages = build_step(est.module, slots, 2048)
+    leaves = jax.tree_util.tree_leaves(pages)
+    assert [leaf.shape for leaf in leaves] == [(slots, 1024, 1152)] * 2
+    variables = jax.eval_shape(
+        est.module.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )
+    text = step.program.lower(
+        _on(one_chip, variables), _on(one_chip, pages),
+        jax.ShapeDtypeStruct((slots, 2048), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((3, slots), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+    calls = re.findall(r"%(\S+) = [^=]* custom-call\(", text)
+    assert sum(c.startswith("latent_attend") for c in calls) == 2
+    assert text.split("\n", 1)[0].count("-alias)") >= 3  # pages, buffer
+    makers = {
+        op for shape, op in re.findall(
+            r"= (\w+\[[\d,]*\])\{[^ ]*\} ([\w-]+)\(", text
+        ) if shape == f"bf16[{slots},1024,1152]"
+    }
+    # in place: the kernel sends the step's row back into the aliased
+    # leaf; no copy, no relayout, no scatter, no fusion makes a leaf
+    assert makers <= {"parameter", "get-tuple-element"}, makers
+    assert f"bf16[{slots},1024,1152]{{2,1,0" in text  # row-major
+    if slots * 8 % 16 == 0:
+        assert "ragged-dot" not in text
