@@ -835,7 +835,8 @@ class _Serve:
         ``denoising_steps``, ``remasking`` and ``confidence_threshold``
         go to a model that generates by diffusion over blocks (its
         token events carry ``s``, the denoising step each token was
-        fixed at, and a block's tokens arrive together on its commit);
+        fixed at, and a block's tokens arrive together, once its
+        commit forward has ended on the chip);
         any other model refuses them."""
         body: dict = {
             "prompts": prompts,

@@ -472,10 +472,7 @@ class BlockDiffusionMoELM(NeuralEstimator):
         import jax
         import numpy as np
 
-        from learningorchestra_tpu.serve.decode.blocks import (
-            BlockPlan,
-            BlockState,
-        )
+        from learningorchestra_tpu.serve.decode import blocks
 
         if temperature is not None or top_k is not None \
                 or top_p is not None:
@@ -483,8 +480,8 @@ class BlockDiffusionMoELM(NeuralEstimator):
                 "block-diffusion generation is greedy: no temperature, "
                 "top_k or top_p"
             )
-        plan = BlockPlan(self, denoising_steps, remasking,
-                         confidence_threshold)
+        plan = blocks.BlockPlan(self, denoising_steps, remasking,
+                                confidence_threshold)
         prompts = np.asarray(prompts, np.int32)
         bsz, t0 = prompts.shape
         b = plan.block
@@ -494,18 +491,22 @@ class BlockDiffusionMoELM(NeuralEstimator):
         out[:, :t0] = prompts
         for row in out:
             for start in range(t0 // b * b, total, b):
-                state = BlockState(plan, row[start: min(max(t0, start), start + b)])
-                while not state.final:
-                    row[start: start + b] = state.tokens
-                    logits = np.asarray(
+                # the block as it begins: mask ids from t0 on
+                state = (row[start: start + b],
+                         np.arange(start, start + b) >= t0,
+                         np.full(b, -1, np.int32), np.int32(0))
+                while not blocks.final(state[1]):
+                    logits = jnp.asarray(
                         apply(self.params, row[None])[0, start: start + b],
-                        np.float32,
+                        jnp.float32,
                     )
-                    x0 = logits.argmax(-1)
                     top = logits.max(-1, keepdims=True)
-                    conf = 1.0 / np.exp(logits - top).sum(-1)
-                    state.denoise(x0, conf)
-                row[start: start + b] = state.tokens
+                    *state, _ = blocks.denoise(
+                        *state, logits.argmax(-1),
+                        1.0 / jnp.exp(logits - top).sum(-1),
+                        plan.steps, plan.dynamic, plan.threshold,
+                    )
+                    row[start: start + b] = state[0]
         return out[:, : min(total, t0 + max_new_tokens)]
 
 

@@ -1,4 +1,4 @@
-"""Generation by diffusion over blocks: the host's part.
+"""Generation by diffusion over blocks: the procedure, once.
 
 A block-diffusion LM generates ``B`` positions at a time.  A block
 starts as mask tokens (a prompt's remainder sits in it already fixed)
@@ -15,6 +15,18 @@ When no mask is left, one *commit* forward over the block's final
 tokens makes its K/V final and the next block begins.  What the
 forward is belongs to the caller: the decode engine's step program over
 the page pool, or an estimator's plain full forward.
+
+The strategy (:func:`choose`, :func:`denoise`) is written on arrays,
+any leading axes, and is traced: the engine's step program applies it
+to every slot's block on the device (``pages.build_step``), where the
+proposals and confidences are, so no host stands between one step and
+the next; the estimator's plain path calls the same function on its one
+row.  A block's state is four things: its tokens (the mask id where not
+yet fixed), which positions are still masked, the denoising step each
+was fixed at (-1: never, a prompt's token or one still masked), and how
+many denoising forwards it has had.  ``masked`` is its own vector and
+not ``tokens == mask id``: a model may well propose the mask id itself,
+and a position fixed to it is fixed.
 """
 
 from __future__ import annotations
@@ -23,27 +35,89 @@ import numpy as np
 
 REMASKING = ("low_confidence_static", "low_confidence_dynamic")
 
+#: What a slot's forward was, as the step program reports it: nothing
+#: (the slot sat the step out), a whole prompt block's prefill, a
+#: denoising forward, a generated block's commit.
+IDLE, PREFILL, DENOISE, COMMIT = range(4)
 
-def transfer_count(block: int, steps: int, step: int) -> int:
+#: The rows of the one host array a block step is called with, a column
+#: a slot: who is seated; who was seated since the pool's last step (the
+#: step then begins that slot's blocks anew, from its buffer row); and
+#: what only the request knows: prompt length, where it ends, and its
+#: plan (:attr:`BlockPlan.packed`: the mask id with it, which is the
+#: estimator's and not the module's).
+SLOT_ROWS = ("live", "seat", "t0", "total", "mask", "steps", "dynamic",
+             "threshold")
+
+#: A slot's row of the state a block pool carries on the device, int32:
+#: where its current block starts and how many denoising forwards the
+#: block has had (-1: not begun, the step reads it from the buffer row
+#: first), then the block's B tokens, B masked flags and B ``fixed_at``.
+STATE_HEAD = ("pos", "step")
+
+#: A slot's row of a block step's result, int32: what its forward was
+#: (:data:`IDLE` .. :data:`COMMIT`), where the block starts, how many
+#: positions the forward fixed, then the block's B tokens and their B
+#: ``fixed_at`` as the forward left them (final after a commit).
+RESULT_HEAD = ("kind", "start", "fixed")
+
+
+def transfer_count(block, steps, step):
     """How many positions denoising step ``step`` of ``steps`` fixes at
     the least: ``block / steps``, the remainder going to the first
-    steps, so that ``steps`` steps fix a whole block."""
-    return block // steps + (1 if step < block % steps else 0)
+    steps, so that ``steps`` steps fix a whole block.  Ints or int
+    arrays."""
+    return block // steps + (step < block % steps)
 
 
-def choose(conf, masked, count: int, remasking: str,
-           threshold: float):
-    """Which masked positions to fix now: a bool vector.  Ties go to
-    the earlier position."""
-    conf = np.where(masked, np.asarray(conf, np.float32), -np.inf)
-    count = min(int(count), int(masked.sum()))
-    if remasking == "low_confidence_dynamic":
-        high = masked & (conf > threshold)
-        if high.sum() >= count:
-            return high
-    pick = np.zeros(len(conf), bool)
-    pick[np.argsort(-conf, kind="stable")[:count]] = True
-    return pick
+def final(masked):
+    """No mask left (..., B) -> (...): the forward over these tokens is
+    the block's commit, or a prompt block's prefill."""
+    return ~masked.any(-1)
+
+
+def choose(conf, masked, count, dynamic, threshold):
+    """Which masked positions to fix now, a bool array like ``masked``
+    (..., B): the ``count`` (...) of highest float32 confidence
+    ``conf``, ties to the earlier position, or where ``dynamic`` (...)
+    every one over ``threshold`` (...) if at least ``count`` are."""
+    import jax.numpy as jnp
+
+    conf = jnp.where(masked, conf, -jnp.inf)
+    count = jnp.minimum(jnp.asarray(count), masked.sum(-1))
+    threshold = jnp.asarray(threshold, jnp.float32)
+    # The stable descending order without a sort: a position's rank is
+    # how many come before it, the higher and, among equals, the earlier.
+    mine, other = conf[..., :, None], conf[..., None, :]
+    idx = jnp.arange(conf.shape[-1])
+    before = (other > mine) | (
+        (other == mine) & (idx[None, :] < idx[:, None])
+    )
+    pick = before.sum(-1) < count[..., None]
+    high = masked & (conf > threshold[..., None])
+    return jnp.where(
+        (jnp.asarray(dynamic) & (high.sum(-1) >= count))[..., None],
+        high, pick,
+    )
+
+
+def denoise(tokens, masked, fixed_at, step, x0, conf, steps, dynamic,
+            threshold):
+    """One denoising forward's proposals ``x0`` and confidences applied
+    to a block's state by the request's plan (``steps``, ``dynamic``,
+    ``threshold``): ``(tokens, masked, fixed_at, step)`` after it and
+    the bool array of the positions it fixed."""
+    import jax.numpy as jnp
+
+    step = jnp.asarray(step)
+    pick = choose(
+        conf, masked, transfer_count(masked.shape[-1], steps, step),
+        dynamic, threshold,
+    )
+    return (
+        jnp.where(pick, x0, tokens), masked & ~pick,
+        jnp.where(pick, step[..., None], fixed_at), step + 1, pick,
+    )
 
 
 class BlockPlan:
@@ -82,44 +156,17 @@ class BlockPlan:
                 f"{self.threshold}"
             )
 
-
-class BlockState:
-    """One sequence's current block: its tokens (the mask id where not
-    yet fixed), which positions are still masked, the denoising step
-    each was fixed at (-1: never, a prompt's token or one still
-    masked), and how many denoising forwards it has had.  ``masked`` is
-    its own vector and not ``tokens == mask id``: a model may well
-    propose the mask id itself, and a position fixed to it is fixed."""
-
-    __slots__ = ("plan", "tokens", "masked", "fixed_at", "step")
-
-    def __init__(self, plan: BlockPlan, given):
-        """``given``: the block's tokens that are known already (a
-        prompt's), at most ``block`` of them."""
-        self.plan = plan
-        self.tokens = np.full(plan.block, plan.mask_id, np.int32)
-        self.tokens[: len(given)] = given
-        self.masked = np.arange(plan.block) >= len(given)
-        self.fixed_at = np.full(plan.block, -1, np.int32)
-        self.step = 0
+    @property
+    def dynamic(self) -> bool:
+        """Whether the rule is ``low_confidence_dynamic``."""
+        return self.remasking == "low_confidence_dynamic"
 
     @property
-    def final(self) -> bool:
-        """No mask left: the forward over these tokens is the block's
-        commit (or a prompt block's prefill)."""
-        return not self.masked.any()
-
-    def denoise(self, x0, conf) -> int:
-        """Apply the strategy to one denoising forward's proposals;
-        returns how many positions it fixed."""
-        plan = self.plan
-        pick = choose(
-            conf, self.masked,
-            transfer_count(plan.block, plan.steps, self.step),
-            plan.remasking, plan.threshold,
+    def packed(self) -> tuple:
+        """The plan as the step program takes it, four int32: the mask
+        id, steps, whether the rule is the dynamic one, the float32
+        threshold's bits (the last four of :data:`SLOT_ROWS`)."""
+        return (
+            self.mask_id, self.steps, int(self.dynamic),
+            int(np.float32(self.threshold).view(np.int32)),
         )
-        self.tokens[pick] = np.asarray(x0, np.int32)[pick]
-        self.fixed_at[pick] = self.step
-        self.masked = self.masked & ~pick
-        self.step += 1
-        return int(pick.sum())

@@ -30,11 +30,13 @@ from learningorchestra_tpu.obs import tracing as obs_tracing
 from learningorchestra_tpu.obs.metrics import get_registry
 from learningorchestra_tpu.serve.batcher import QueueFull
 from learningorchestra_tpu.serve.bucketing import bucket_for
+from learningorchestra_tpu.serve.decode import blocks
 from learningorchestra_tpu.serve.decode.blocks import BlockPlan
 from learningorchestra_tpu.serve.decode.pages import (
     PagePool,
     build_step,
     first_pages,
+    step_donates,
     step_width,
 )
 from learningorchestra_tpu.serve.decode.streams import DecodeStream
@@ -51,8 +53,8 @@ _NONSTREAM_TIMEOUT_S = 300.0
 #: ``sync`` (reading a step's tokens, and finished rows, back),
 #: ``emit`` (tokens to their streams, histograms, the devtime ledger);
 #: ``wait`` is the worker parked with nothing live, outside any turn.
-#: A one-token pool's turn enqueues step k and only then reads and
-#: emits step k-1, so its ``sync`` and ``emit`` run beside the chip.
+#: A turn enqueues step k and only then reads and emits step k-1, so
+#: its ``sync`` and ``emit`` run beside the chip.
 _PHASES = ("admit", "dispatch", "sync", "emit", "wait")
 
 #: A turn longer than this leaves a ``slow_step`` flight event with its
@@ -171,8 +173,8 @@ class _ModelDecoder:
         # either kind of pool (``pages._moe_stats``): distinct held
         # experts a layer, summed over layers and steps; the busiest
         # expert's rows in any one step; (token, choice) pairs that
-        # reached a held expert.  A one-token pool's are of the step a
-        # turn READ, which it dispatched the turn before.
+        # reached a held expert.  They are of the step a turn READ,
+        # which it dispatched the turn before.
         self.experts_hit = 0
         self.expert_load_max = 0
         self.expert_rows = 0
@@ -460,9 +462,10 @@ class _ModelDecoder:
                 loss="-",
                 dtype="-",
                 shapes=("decode_step", nslots, kvlen),
-                # The step consumes its cache and buffer: a store keyed
-                # without this must not hand back one that copies.
-                donate=(1, 2),
+                # The step consumes its cache and buffer (a block
+                # pool's, the blocks' state too): a store keyed without
+                # this must not hand back one that copies.
+                donate=step_donates(module),
             )
             label = (
                 f"decode:{type(module).__name__}"
@@ -534,28 +537,32 @@ class _ModelDecoder:
                     )
 
     def _step_pool(self, pool: PagePool) -> None:
-        """One turn of a pool.  A one-token pool keeps ONE step in
-        flight: the turn enqueues step k and only then reads step k-1,
-        so the host's whole turn runs while the chip runs step k, and
-        the chip finds step k+1 queued when step k ends.  Nothing in
-        step k needs step k-1's tokens on the host: its input token is
-        in the device's buffer, and positions, prompt lengths and who
-        is live follow from lengths alone (greedy, no EOS: a stream
-        ends at ``total``).  A token leaves one turn late and no later
-        than before: as soon as its own step has ended on the chip.
-        Reading step k-1 before step k+1 is enqueued is what bounds the
-        run-ahead at one step.  A block pool's next input is decided
-        from its last result, so its turn reads what it dispatched
-        (``_step_blocks``): ``pool.width``, the model's own, chooses."""
+        """One turn of a pool, which keeps ONE step in flight: the turn
+        enqueues step k and only then reads step k-1, so the host's
+        whole turn runs while the chip runs step k, and the chip finds
+        step k+1 queued when step k ends.  Nothing in step k needs step
+        k-1's result on the host.  A one-token pool's input token is in
+        the device's buffer, and positions, prompt lengths and who is
+        live follow from lengths alone (greedy, no EOS: a stream ends
+        at ``total``).  A block pool's step program keeps every slot's
+        block on the device and applies the strategy to its own
+        proposals (``pages.build_step``), so where a slot stands is the
+        device's to know and the host believes what it reads.  A token
+        leaves one turn late and no later than before: as soon as its
+        own step has ended on the chip.  Reading step k-1 before step
+        k+1 is enqueued is what bounds the run-ahead at one step.
+        ``pool.width``, the model's own, chooses the step program and
+        the reader of its result, nothing else."""
         from learningorchestra_tpu import faults
 
-        if pool.width > 1:
-            return self._step_blocks(pool)
-        # Positions advance when a step is dispatched: a slot whose
-        # stream has had its last step dispatched sits this one out,
-        # seated until that step's result is read.
+        # A one-token pool's positions advance when a step is
+        # dispatched: a slot whose stream has had its last step
+        # dispatched sits this one out, seated until that step's result
+        # is read.  A block pool's slot is stepped while it is seated:
+        # the step program lets it sit out once its blocks are done.
         live = np.array(
-            [s is not None and pool.pos[i] < s.total - 1
+            [s is not None
+             and (pool.width > 1 or pool.pos[i] < s.total - 1)
              for i, s in enumerate(pool.streams)], bool
         )
         before, pool.unread = pool.unread, None
@@ -571,81 +578,88 @@ class _ModelDecoder:
             self._read_step(pool, before)
 
     def _dispatch(self, pool: PagePool, live, ahead: bool) -> tuple:
-        """Enqueue a one-token pool's next step for the ``live`` slots
-        and return what reading it will need: its token column, the
-        streams it stepped, their positions after it, and the terminal
-        buffer rows of the lazy streams it ends.  ``ahead``: the step
-        before it is still unread."""
+        """Enqueue the pool's next step for the ``live`` slots and
+        return what reading it will need: its result, the streams it
+        stepped and, of a one-token pool, their positions after it and
+        the terminal buffer rows of the lazy streams it ends.
+        ``ahead``: the step before it is still unread."""
         step, _ = self._step_for(pool.nslots, pool.kv)
-        t0s = np.array(
-            [s.t0 if s is not None else pool.kv + 1
-             for s in pool.streams],
-            np.int32,
-        )
-        # A fresh array a dispatch (``pool.pos`` is mutated right
-        # below, and jax's CPU backend may alias numpy buffers
-        # zero-copy, so a lazily-executed step would read positions
-        # from the FUTURE); a slot not live in this step goes in at
-        # position 0, like a free one.
-        pos_now = np.where(live, pool.pos, 0).astype(np.int32)
-        # What this step does, slot by slot: a live slot whose next
-        # position is still inside its prompt consumes a prompt token
-        # (prefill, one token a step), any other live slot produces an
-        # output token; each attends over the keys up to and with its
-        # own position.
-        nxt = pos_now + 1
-        n_live = int(live.sum())
-        n_prompt = int((live & (nxt < t0s)).sum())
-        n_keys = int(nxt[live].sum())
-        self._count(pool, n_prompt, n_live - n_prompt, n_keys)
+        turn = self._turn
+        turn["slots"] += pool.nslots
+        turn["kv"] = max(turn["kv"], pool.kv)
+        turn["kv_bytes_per_token"] = pool.token_bytes()
         if ahead:
             self.steps_ahead += 1
-            self._turn["ahead"] += 1
+            turn["ahead"] += 1
         else:
             # From a drained pool: the chip's time starts here.
             pool.read_at = time.perf_counter()
-        col = self._call(pool, step, pos_now, t0s, live)
-        # The column starts for the host the moment its step ends,
+        stepped = [s if on else None for s, on in zip(pool.streams, live)]
+        if pool.width > 1:
+            slots = np.zeros((len(blocks.SLOT_ROWS), pool.nslots), np.int32)
+            for slot, stream in enumerate(stepped):
+                if stream is not None:
+                    slots[:, slot] = (
+                        1, pool.fresh[slot], stream.t0, stream.total,
+                        *stream.plan.packed,
+                    )
+            pool.fresh[:] = False
+            nxt, rows = None, {}
+            col = self._call(pool, step, slots)
+        else:
+            t0s = np.array(
+                [s.t0 if s is not None else pool.kv + 1
+                 for s in pool.streams],
+                np.int32,
+            )
+            # A fresh array a dispatch (``pool.pos`` is mutated right
+            # below, and jax's CPU backend may alias numpy buffers
+            # zero-copy, so a lazily-executed step would read positions
+            # from the FUTURE); a slot not live in this step goes in at
+            # position 0, like a free one.
+            pos_now = np.where(live, pool.pos, 0).astype(np.int32)
+            # What this step does, slot by slot: a live slot whose next
+            # position is still inside its prompt consumes a prompt
+            # token (prefill, one token a step), any other live slot
+            # produces an output token; each attends over the keys up
+            # to and with its own position.
+            nxt = pos_now + 1
+            n_prompt = int((live & (nxt < t0s)).sum())
+            self._count(
+                n_prompt, int(live.sum()) - n_prompt, int(nxt[live].sum())
+            )
+            col = self._call(pool, step, pos_now, t0s, live)
+            pool.pos[live] = nxt[live]
+            # Terminal: the full row (prompt + continuation) is in the
+            # buffer this step returns; a lazy stream surfaces
+            # everything from it.  The slice is enqueued here, before
+            # the next step consumes that buffer, and read with the
+            # column.
+            rows = {
+                slot: pool.buf[slot]
+                for slot, stream in enumerate(stepped)
+                if stream is not None and not stream.eager
+                and nxt[slot] >= stream.total - 1
+            }
+        # The result starts for the host the moment its step ends,
         # whatever the worker is doing then.
         col.copy_to_host_async()
-        pool.pos[live] = nxt[live]
-        stepped = [s if on else None for s, on in zip(pool.streams, live)]
-        # Terminal: the full row (prompt + continuation) is in the
-        # buffer this step returns; a lazy stream surfaces everything
-        # from it.  The slice is enqueued here, before the next step
-        # consumes that buffer, and read with the column.
-        rows = {
-            slot: pool.buf[slot]
-            for slot, stream in enumerate(stepped)
-            if stream is not None and not stream.eager
-            and nxt[slot] >= stream.total - 1
-        }
         return col, stepped, nxt, rows
 
     def _read_step(self, pool: PagePool, unread: tuple) -> None:
         """Read a dispatched step's result back (what ``_dispatch``
-        returned for it), hand its tokens to the eager streams and
-        finish the streams whose last step it was."""
+        returned for it), hand its tokens to their streams and finish
+        the streams whose last step it was."""
         col, stepped, nxt, rows = unread
         with self.phases("sync"):
             col_host = np.asarray(col)
             rows = {slot: np.asarray(row) for slot, row in rows.items()}
             now = time.perf_counter()
         with self.phases("emit"):
-            self._count_experts(col_host[len(stepped):])
-            for slot, stream in enumerate(stepped):
-                # Not in that step, or aborted (and its slot perhaps
-                # seated anew) since it was dispatched.
-                if stream is None or pool.streams[slot] is not stream:
-                    continue
-                nxt_pos = int(nxt[slot])
-                if stream.eager and nxt_pos >= stream.t0:
-                    self._emit(stream, int(col_host[slot]), nxt_pos, now)
-                if nxt_pos >= stream.total - 1:
-                    if not stream.eager:
-                        self._surface(stream, rows[slot], now)
-                    pool.release(slot)
-                    self._finish(stream)
+            if pool.width == 1:
+                self._read_tokens(pool, col_host, stepped, now, nxt, rows)
+            else:
+                self._read_blocks(pool, col_host, stepped, now)
             # The wall time between successive reads is the chip's time
             # for a step while one is always in flight (measured to
             # HERE, past the row reads), whichever transport the
@@ -654,6 +668,72 @@ class _ModelDecoder:
             done_at = time.perf_counter()
             self._record_devtime(pool, done_at - pool.read_at)
             pool.read_at = done_at
+
+    def _read_tokens(self, pool: PagePool, col, stepped, now: float,
+                     nxt, rows) -> None:
+        """A one-token step's column: its tokens to the eager streams,
+        the terminal rows to the lazy ones it ends."""
+        self._count_experts(col[len(stepped):])
+        for slot, stream in enumerate(stepped):
+            # Not in that step, or aborted (and its slot perhaps seated
+            # anew) since it was dispatched.
+            if stream is None or pool.streams[slot] is not stream:
+                continue
+            nxt_pos = int(nxt[slot])
+            if stream.eager and nxt_pos >= stream.t0:
+                self._emit(stream, int(col[slot]), nxt_pos, now)
+            if nxt_pos >= stream.total - 1:
+                if not stream.eager:
+                    self._surface(stream, rows[slot], now)
+                pool.release(slot)
+                self._finish(stream)
+
+    def _read_blocks(self, pool: PagePool, col, stepped,
+                     now: float) -> None:
+        """A block step's result (``blocks.RESULT_HEAD``): what each
+        slot's forward was is the step program's word, and the counters
+        describe the step read.  A denoising forward's tokens stay on
+        the device; a forward that made a block's K/V final (a whole
+        prompt block's prefill, or a generated block's commit, whose
+        tokens go out in order with the step each was fixed at) is the
+        stream's last where the block reaches ``total``."""
+        q, head = pool.width, len(blocks.RESULT_HEAD)
+        self._count_experts(col[-1])
+        kinds, starts, fixed = col[:-1, :head].T
+        counts = np.bincount(kinds, minlength=4)
+        by_kind = {"prefill": int(counts[blocks.PREFILL]),
+                   "denoise": int(counts[blocks.DENOISE]),
+                   "commit": int(counts[blocks.COMMIT])}
+        stepped_n = sum(by_kind.values())
+        self._count(
+            by_kind["prefill"], stepped_n - by_kind["prefill"],
+            q * int((starts + q)[kinds != blocks.IDLE].sum()),
+        )
+        bturn = self._block_turn
+        for name, n in by_kind.items():
+            self.block_steps[name] += n
+            bturn[name] += n
+        self.positions += q * stepped_n
+        bturn["positions"] += q * stepped_n
+        self.tokens_fixed += int(fixed.sum())
+        bturn["fixed"] += int(fixed.sum())
+        for slot, stream in enumerate(stepped):
+            # Not in that step, or aborted (and its slot perhaps seated
+            # anew) since it was dispatched.
+            if stream is None or pool.streams[slot] is not stream \
+                    or kinds[slot] in (blocks.IDLE, blocks.DENOISE):
+                continue
+            start = int(starts[slot])
+            if kinds[slot] == blocks.COMMIT:
+                tokens = col[slot, head: head + q]
+                fixed_at = col[slot, head + q:]
+                for j in range(q):
+                    if stream.t0 <= start + j < stream.total:
+                        self._emit(stream, int(tokens[j]), start + j,
+                                   now, step=int(fixed_at[j]))
+            if start + q >= stream.total:
+                pool.release(slot)
+                self._finish(stream)
 
     def _surface(self, stream: DecodeStream, row, now: float) -> None:
         """A lazy stream's tokens, all at once from its terminal buffer
@@ -669,11 +749,12 @@ class _ModelDecoder:
         )
         _decode_hists.tokens(len(stream.tokens), self.name)
 
-    def _count(self, pool: PagePool, prompt: int, output: int,
-               keys: int) -> None:
+    def _count(self, prompt: int, output: int, keys: int) -> None:
         """A step's slot-steps and attended keys, into the cumulative
-        counters and the turn's annotation.  Counted at dispatch and
-        not at the turn's end: a stream this step finishes may read
+        counters and the turn's annotation.  A one-token pool's are
+        counted at dispatch (they follow from lengths), a block pool's
+        when its result is read (they are the step program's word), and
+        never at the turn's end: a stream this step finishes may read
         stats() before the turn is over."""
         self.prompt_steps += prompt
         self.output_steps += output
@@ -682,9 +763,6 @@ class _ModelDecoder:
         turn["prompt"] += prompt
         turn["output"] += output
         turn["keys"] += keys
-        turn["slots"] += pool.nslots
-        turn["kv"] = max(turn["kv"], pool.kv)
-        turn["kv_bytes_per_token"] = pool.token_bytes()
 
     def _count_experts(self, counted) -> None:
         """A step's routed-expert counts as its program returned them
@@ -700,13 +778,13 @@ class _ModelDecoder:
         turn["load_max"] = max(turn["load_max"], busiest)
         turn["expert_rows"] += rows
 
-    def _call(self, pool: PagePool, step, *slots, **block):
-        """Enqueue the pool's step.  The step consumes the cache and
-        the buffer: from here on the pool holds only what it
-        returned."""
+    def _call(self, pool: PagePool, step, *slots):
+        """Enqueue the pool's step.  The step consumes what the pool
+        carries on the device (``PagePool.device``): from here on the
+        pool holds only what it returned."""
         went_in = first_pages(pool.cache)
-        pool.cache, pool.buf, col = step(
-            self._params_for(pool), pool.cache, pool.buf, *slots, **block
+        *pool.device, col = step(
+            self._params_for(pool), *pool.device, *slots
         )
         pool.steps += 1
         self.steps += 1
@@ -726,85 +804,6 @@ class _ModelDecoder:
                     weight, seconds, None, None,
                     self.name, f"dec{pool.nslots}x{pool.kv}",
                 )
-
-    def _step_blocks(self, pool: PagePool) -> None:
-        """One turn of a pool whose model generates by diffusion over
-        blocks (``blocks.py``): every live slot forwards its current
-        block, ``pool.width`` positions.  What the forward was depends
-        on the block's state when it was dispatched: with a mask left
-        it was a denoising forward and the strategy now fixes tokens
-        from its proposals; with none it made the block's K/V final (a
-        whole prompt block's prefill, or a generated block's commit),
-        so the block's tokens go out in order and the slot moves on."""
-        from learningorchestra_tpu import faults
-
-        phases = self.phases
-        q = pool.width
-        with phases("dispatch"):
-            faults.hit("serve.decode_step")
-            step, _ = self._step_for(pool.nslots, pool.kv)
-            live = np.array([s is not None for s in pool.streams], bool)
-            pos_now = pool.pos.copy()
-            block = np.zeros((pool.nslots, q), np.int32)
-            kinds: list = [None] * pool.nslots
-            for slot, state in enumerate(pool.blocks):
-                if state is None:
-                    continue
-                block[slot] = state.tokens
-                kinds[slot] = (
-                    "denoise" if not state.final
-                    else "prefill"
-                    if pos_now[slot] + q <= pool.streams[slot].t0
-                    else "commit"
-                )
-            n_live = int(live.sum())
-            n_keys = q * int((pos_now[live] + q).sum())
-            bturn = self._block_turn
-            by_kind = {kind: kinds.count(kind) for kind in self.block_steps}
-            for kind, n in by_kind.items():
-                self.block_steps[kind] += n
-                bturn[kind] += n
-            self._count(pool, by_kind["prefill"],
-                        n_live - by_kind["prefill"], n_keys)
-            self.positions += q * n_live
-            bturn["positions"] += q * n_live
-            t_start = time.perf_counter()
-            col = self._call(
-                pool, step, pos_now, np.zeros(pool.nslots, np.int32),
-                live, block=block,
-            )
-        with phases("sync"):
-            # The strategy is the host's: every turn reads its step.
-            col_host = np.asarray(col)
-            now = time.perf_counter()
-        with phases("emit"):
-            x0 = col_host[:-1, :q]
-            conf = col_host[:-1, q:].view(np.float32)
-            self._count_experts(col_host[-1])
-            for slot, stream in enumerate(pool.streams):
-                if stream is None:
-                    continue
-                state = pool.blocks[slot]
-                if kinds[slot] == "denoise":
-                    fixed = state.denoise(x0[slot], conf[slot])
-                    self.tokens_fixed += fixed
-                    bturn["fixed"] += fixed
-                    continue
-                start = int(pos_now[slot])
-                if kinds[slot] == "commit":
-                    for j in range(q):
-                        if stream.t0 <= start + j < stream.total:
-                            self._emit(
-                                stream, int(state.tokens[j]), start + j,
-                                now, step=int(state.fixed_at[j]),
-                            )
-                pool.pos[slot] = start + q
-                if start + q >= stream.total:
-                    pool.release(slot)
-                    self._finish(stream)
-                else:
-                    pool.blocks[slot] = stream.block_at(start + q)
-            self._record_devtime(pool, time.perf_counter() - t_start)
 
     def _emit(self, stream: DecodeStream, tok: int, pos: int,
               now: float, step=None) -> None:
@@ -844,22 +843,24 @@ class _ModelDecoder:
         replica's placed params — pays the per-device executable
         load/compile before the router may pick the replica (the
         decode leg of PR-16 replica pre-warm)."""
+        width = step_width(entry.estimator.module)
         for (nslots, kvlen) in sorted(entry.decode_warm):
             step, cache_shapes = self._step_for(nslots, kvlen)
-            pool = PagePool(kvlen, nslots, replica_idx=replica.idx)
+            pool = PagePool(kvlen, nslots, replica_idx=replica.idx,
+                            width=width)
             pool._alloc(cache_shapes, nslots)
             params, _ = replica.place(
                 entry, np.zeros((1, 1), np.int32)
             )
-            width = step_width(entry.estimator.module)
-            step(
-                params, pool.cache, pool.buf,
+            # no slot live: a step that changes nothing
+            slots = (
                 np.zeros(nslots, np.int32),
                 np.full(nslots, kvlen + 1, np.int32),
                 np.zeros(nslots, bool),
-                block=None if width == 1
-                else np.zeros((nslots, width), np.int32),
+            ) if width == 1 else (
+                np.zeros((len(blocks.SLOT_ROWS), nslots), np.int32),
             )
+            step(params, *pool.device, *slots)
 
     def stats(self) -> dict:
         with self._cv:

@@ -31,6 +31,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from learningorchestra_tpu.serve.decode import blocks
+
 #: The leaf an attention layer's cache is found by: a layer of
 #: ``MultiHeadSelfAttention`` keeps ``cached_key`` (with
 #: ``cached_value`` beside it), a layer of ``LatentAttention`` its one
@@ -112,6 +114,12 @@ def step_width(module) -> int:
     return int(getattr(module, "block_length", None) or 1)
 
 
+def step_donates(module) -> tuple:
+    """The arguments a step of ``module`` consumes: cache and token
+    buffer, and a block pool's state."""
+    return (1, 2, 3) if step_width(module) > 1 else (1, 2)
+
+
 def build_step(module, nslots: int, kv: int):
     """(step fn, shape tree of the K/V pages a pool carries) for one
     (arch, S, Tk) cell.  ``step(variables, cache, buf, pos, t0s, live)``
@@ -134,43 +142,83 @@ def build_step(module, nslots: int, kv: int):
 
     A slot-step is ``q`` positions wide: 1 for a next-token model, the
     module's ``block_length`` for one that generates by diffusion over
-    blocks (:func:`step_width`).  Such a step takes the block's current
-    tokens from the host, ``q`` more rows of the packed array (the
-    host's turn keeps the block's state), writes them to the buffer at
+    blocks (:func:`step_width`).  Such a pool carries, beside pages and
+    buffer and donated like them, the ``(S, 2 + 3q)`` int32 state of
+    every slot's current block (``blocks.STATE_HEAD``), and its step is
+    ``step(variables, cache, buf, state, slots)`` -> ``(cache, buf,
+    state, col)``: the step program runs the procedure of
+    ``blocks.py`` itself.  ``slots`` is the one host array a call
+    (``blocks.SLOT_ROWS``, a column a slot): who is seated, who was
+    seated since the last step (that slot begins anew at position 0),
+    and each request's prompt length, end and plan; nothing ``q`` wide
+    comes from the host.  A seated slot whose block starts before its
+    request's end forwards the block: its tokens go to the buffer at
     ``pos .. pos+q-1`` and their K/V to the pages (every forward of a
-    block rewrites them; the commit's stay), attends with ``slot <=
-    pos+q-1`` and full sight inside the block, and returns ONE
-    ``(S + 1, 2q)`` int32 array: per slot the ``q`` proposals ``x0``
-    and the bits of their float32 confidences, and in the last row the
-    experts the step's rows reached (distinct experts a layer, summed
-    over layers), the busiest expert's rows and the rows that reached a
-    held expert (:func:`_moe_stats`).  A next-token model with routed
-    experts returns the same three behind its token column: ``(S + 3,)``
-    where a dense model's is ``(S,)``.
+    block rewrites them; the commit's stay), attending with ``slot <=
+    pos+q-1`` and full sight inside the block.  A block with a mask
+    left then takes the strategy (``blocks.denoise``) on the forward's
+    float32 proposals and confidences; one with none (a prompt block's
+    prefill, or a commit) moves on, the next block read from the
+    buffer row's prompt with the mask id from ``t0`` on.  The result
+    is ONE ``(S + 1, 3 + 2q)`` int32 array: per slot what its forward
+    was, where the block starts, how many positions it fixed and the
+    block's tokens with the step each was fixed at
+    (``blocks.RESULT_HEAD``), and in the last row the experts the
+    step's rows reached (distinct experts a layer, summed over layers),
+    the busiest expert's rows and the rows that reached a held expert
+    (:func:`_moe_stats`).  A next-token model with routed experts
+    returns the same three behind its token column: ``(S + 3,)`` where
+    a dense model's is ``(S,)``.
     """
     import jax
     import jax.numpy as jnp
 
-    q = step_width(module)
+    q, head = step_width(module), len(blocks.STATE_HEAD)
     decode_mod = module.clone(decode=True)
     cache_shapes = strip_index(jax.eval_shape(
         decode_mod.init, jax.random.PRNGKey(0),
         jnp.zeros((nslots, kv), jnp.int32),
     )["cache"])
 
-    def block_step(variables, cache, buf, slots):
-        pos, live = slots[0], slots[2] != 0
-        tok = slots[3:].T  # (S, q): the block as the host has it
-        lanes = pos[:, None] + jnp.arange(q)[None, :]
-        rows = jnp.arange(nslots)[:, None]
-        # A free slot's row stays all-pad: its mask stays empty.
-        buf = buf.at[rows, lanes].set(
-            jnp.where(live[:, None], tok, buf[rows, lanes])
+    def block_step(variables, cache, buf, state, slots):
+        told = dict(zip(blocks.SLOT_ROWS, slots))
+        live, seat = told["live"] != 0, told["seat"] != 0
+        t0, total, mask_id = told["t0"], told["total"], told["mask"]
+        # a free slot's plan is all 0: no division by its steps
+        steps, dynamic = jnp.maximum(told["steps"], 1), told["dynamic"] != 0
+        threshold = jax.lax.bitcast_convert_type(
+            told["threshold"], jnp.float32
         )
-        kmask = (jnp.arange(kv)[None, :] <= pos[:, None] + (q - 1)) \
+        pos = jnp.where(seat, 0, state[:, 0])
+        nth = jnp.where(seat, -1, state[:, 1])
+        tokens, masked, fixed_at = jnp.split(state[:, head:], 3, axis=1)
+        masked = masked != 0
+        # A slot that sits the step out (free, or past its request's
+        # end and not yet released) goes in at position 0 with pad
+        # tokens; its buffer row and its state stay as they are.
+        active = live & (pos < total)
+        at = jnp.where(active, pos, 0)
+        lanes = at[:, None] + jnp.arange(q)[None, :]
+        rows = jnp.arange(nslots)[:, None]
+        held = buf[rows, lanes]
+        # A block not begun: the prompt's tokens that fall in it, the
+        # mask id from t0 on.
+        begin = (active & (nth < 0))[:, None]
+        given = lanes < t0[:, None]
+        tokens = jnp.where(
+            begin, jnp.where(given, held, mask_id[:, None]), tokens
+        )
+        masked = jnp.where(begin, ~given, masked)
+        fixed_at = jnp.where(begin, -1, fixed_at)
+        nth = jnp.where(begin[:, 0], 0, nth)
+        tok = jnp.where(active[:, None], tokens, 0)
+        buf = buf.at[rows, lanes].set(
+            jnp.where(active[:, None], tok, held)
+        )
+        kmask = (jnp.arange(kv)[None, :] <= at[:, None] + (q - 1)) \
             & (buf != 0)
         logits, mut = decode_mod.apply(
-            {**variables, "cache": set_index(cache, pos)}, tok,
+            {**variables, "cache": set_index(cache, at)}, tok,
             positions=lanes, key_mask=kmask,
             mutable=["cache", "moe_stats"],
         )
@@ -179,16 +227,40 @@ def build_step(module, nslots: int, kv: int):
         x0 = jnp.argmax(logits, -1).astype(jnp.int32)
         # softmax(logits)[x0], in float32
         conf = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), -1)
-        stats = jnp.zeros(2 * q, jnp.int32)
+        final = blocks.final(masked)
+        denoising = active & ~final
+        moving = active & final
+        *after, nth_after, pick = blocks.denoise(
+            tokens, masked, fixed_at, nth, x0, conf, steps, dynamic,
+            threshold,
+        )
+        tokens, masked, fixed_at = (
+            jnp.where(denoising[:, None], new, old)
+            for new, old in zip(after, (tokens, masked, fixed_at))
+        )
+        nth = jnp.where(denoising, nth_after, nth)
+        kind = jnp.where(
+            ~active, blocks.IDLE, jnp.where(
+                ~final, blocks.DENOISE, jnp.where(
+                    at + q <= t0, blocks.PREFILL, blocks.COMMIT)))
+        fixed = jnp.sum(pick & denoising[:, None], -1)
+        stats = jnp.zeros(len(blocks.RESULT_HEAD) + 2 * q, jnp.int32)
         for i, value in enumerate(_moe_stats(mut)):
             stats = stats.at[i].set(value)
         col = jnp.concatenate([
             jnp.concatenate(
-                [x0, jax.lax.bitcast_convert_type(conf, jnp.int32)], 1
-            ),
+                [jnp.stack([kind, at, fixed], 1), tokens, fixed_at], 1
+            ).astype(jnp.int32),
             stats[None],
         ])
-        return strip_index(mut["cache"]), buf, col
+        state = jnp.concatenate([
+            jnp.stack([
+                jnp.where(moving, pos + q, pos),
+                jnp.where(moving, -1, nth),
+            ], 1),
+            tokens, masked.astype(jnp.int32), fixed_at,
+        ], 1).astype(jnp.int32)
+        return strip_index(mut["cache"]), buf, state, col
 
     def token_step(variables, cache, buf, slots):
         pos, t0s, live = slots[0], slots[1], slots[2] != 0
@@ -218,19 +290,19 @@ def build_step(module, nslots: int, kv: int):
             )
         return strip_index(mut["cache"]), buf, col
 
-    def step(variables, cache, buf, slots):
+    def step(variables, cache, buf, *rest):
         # One name for the program whatever its width: the trace's
         # readers find a pool's steps as ``jit_step``.
         return (token_step if q == 1 else block_step)(
-            variables, cache, buf, slots
+            variables, cache, buf, *rest
         )
 
-    jitted = jax.jit(step, donate_argnums=(1, 2))
+    jitted = jax.jit(step, donate_argnums=step_donates(module))
 
-    def call(variables, cache, buf, pos, t0s, live, block=None):
-        packed = (pos, t0s, live) if block is None \
-            else (pos, t0s, live, *block.T)
-        return jitted(variables, cache, buf, np.stack(packed))
+    def call(variables, cache, buf, *rest):
+        if q == 1:  # pos, t0s, live: one transfer
+            rest = (np.stack(rest),)
+        return jitted(variables, cache, buf, *rest)
 
     # What ``call`` runs, for whoever compiles it without running it.
     call.program = jitted
@@ -244,24 +316,27 @@ class PagePool:
     pool itself is lock-free; the worker's condition variable is the
     synchronization point for admission and abort.
 
-    A one-token pool has a step in flight most of the time.  ``pos`` is
-    the host's: it advances when a step is dispatched, not when its
-    result is read.  ``admit``, ``release`` and ``_grow`` only enqueue
-    device updates on what the last dispatched step returned, which
-    jax orders behind it; nothing here reads the device.
+    A pool has a step in flight most of the time.  ``pos`` is the
+    host's: it advances when a step is dispatched, not when its result
+    is read.  ``admit``, ``release`` and ``_grow`` only enqueue device
+    updates on what the last dispatched step returned, which jax orders
+    behind it; nothing here reads the device.
     """
 
-    __slots__ = ("kv", "nslots", "max_slots", "cache", "buf", "pos",
-                 "streams", "steps", "replica_idx", "unread", "read_at",
-                 "width", "blocks", "_token_bytes")
+    __slots__ = ("kv", "nslots", "max_slots", "cache", "buf", "state",
+                 "pos", "fresh", "streams", "steps", "replica_idx",
+                 "unread", "read_at", "width", "_token_bytes")
 
     def __init__(self, kv: int, max_slots: int,
                  replica_idx: int | None = None, width: int = 1):
         self.kv = int(kv)
         self.max_slots = int(max_slots)
-        # Positions a slot-step processes (``step_width``); ``pos`` is
-        # then the start of each slot's current block, and ``blocks``
-        # holds that block's state beside each stream.
+        # Positions a slot-step processes (``step_width``).  Over 1,
+        # the pool carries ``state``, every slot's current block on the
+        # device (``blocks.STATE_HEAD``): where a slot stands is the
+        # step program's to know, ``pos`` is not kept, and ``fresh``
+        # marks the slots seated since the last dispatch, whose state
+        # the next step begins anew.
         self.width = int(width)
         self.streams: list = []
         self.drop()
@@ -300,6 +375,19 @@ class PagePool:
         same at every slot bucket: worked out when the pool allocates."""
         return self._token_bytes
 
+    @property
+    def device(self) -> tuple:
+        """What the pool's step consumes and hands back: pages, token
+        buffer and, in a block pool, the blocks' state."""
+        carried = (self.cache, self.buf)
+        return carried if self.state is None else (*carried, self.state)
+
+    @device.setter
+    def device(self, carried) -> None:
+        self.cache, self.buf, *state = carried
+        if state:
+            self.state, = state
+
     def drop(self) -> list:
         """Forget the device state, back to unallocated (the next admit
         allocates afresh), and return the streams that were seated.
@@ -309,14 +397,15 @@ class PagePool:
         seated = [s for s in self.streams if s is not None]
         self.cache = None  # device tree, allocated on first admit
         self.buf = None    # (S, Tk) int32 token buffer
-        # A one-token pool's step in flight: what the engine needs to
-        # read its result, kept from its dispatch to the turn after.
+        self.state = None  # (S, 2 + 3 width) int32, a block pool's
+        # The step in flight: what the engine needs to read its result,
+        # kept from its dispatch to the turn after.
         self.unread = None
         self.nslots = 0
         self._token_bytes = 0.0
         self.pos = np.zeros(0, np.int32)
+        self.fresh = np.zeros(0, bool)
         self.streams = []
-        self.blocks: list = []
         return seated
 
     def _alloc(self, cache_shapes, nslots: int) -> None:
@@ -327,9 +416,14 @@ class PagePool:
             lambda s: jnp.zeros(s.shape, s.dtype), cache_shapes
         )
         self.buf = jnp.zeros((nslots, self.kv), jnp.int32)
+        if self.width > 1:
+            self.state = jnp.zeros(
+                (nslots, len(blocks.STATE_HEAD) + 3 * self.width),
+                jnp.int32,
+            )
         self.pos = np.zeros(nslots, np.int32)
+        self.fresh = np.zeros(nslots, bool)
         self.streams = [None] * nslots
-        self.blocks = [None] * nslots
         self.nslots = nslots
         self._token_bytes = self.page_bytes() / (nslots * self.kv)
 
@@ -347,12 +441,14 @@ class PagePool:
 
         del cache_shapes  # same tree structure; pad in place
         self.cache = jax.tree_util.tree_map(pad, self.cache)
-        self.buf = jnp.pad(self.buf, [(0, extra), (0, 0)])
+        self.buf = pad(self.buf)
+        if self.state is not None:
+            self.state = pad(self.state)
         self.pos = np.concatenate(
             [self.pos, np.zeros(extra, np.int32)]
         )
+        self.fresh = np.concatenate([self.fresh, np.zeros(extra, bool)])
         self.streams.extend([None] * extra)
-        self.blocks.extend([None] * extra)
         self.nslots = nslots
 
     # -- slot lifecycle ------------------------------------------------------
@@ -362,7 +458,7 @@ class PagePool:
         bucket if needed, up to ``max_slots``); None when full.  The
         slot's buffer row gets the prompt, position 0 — prefill runs
         through the shared step one token at a time, exactly like the
-        solo scan."""
+        solo scan (a block pool's, a whole prompt block a step)."""
         from learningorchestra_tpu.serve.bucketing import bucket_for
 
         slot = None
@@ -385,8 +481,8 @@ class PagePool:
         row[: stream.t0] = stream.prompt
         self.buf = self.buf.at[slot].set(row)
         self.pos[slot] = 0
+        self.fresh[slot] = True
         self.streams[slot] = stream
-        self.blocks[slot] = stream.block_at(0)
         return slot
 
     def release(self, slot: int) -> None:
@@ -395,7 +491,6 @@ class PagePool:
         still hold is unreachable — the pages are free for the next
         admit without a scrub pass."""
         self.streams[slot] = None
-        self.blocks[slot] = None
         self.pos[slot] = 0
         if self.buf is not None:
             self.buf = self.buf.at[slot].set(0)

@@ -22,7 +22,6 @@ import time
 import uuid
 
 from learningorchestra_tpu.jobs.cancel import CancelToken
-from learningorchestra_tpu.serve.decode.blocks import BlockState
 
 #: Event-queue bound: ``total`` tokens plus lifecycle events always
 #: fit, but a reader that stopped draining must not grow memory.
@@ -72,16 +71,6 @@ class DecodeStream:
         except queue.Full:
             pass  # reader stopped draining; terminal state still lands
         # via _done / token, which the transports consult.
-
-    def block_at(self, start: int):
-        """The state of the block that starts at ``start``, with the
-        prompt's tokens that fall in it already fixed; None for a
-        next-token model."""
-        if self.plan is None:
-            return None
-        return BlockState(
-            self.plan, self.prompt[start: start + self.plan.block]
-        )
 
     def push_token(self, tok: int, pos: int, step=None) -> None:
         """``step``: the denoising step the token was fixed at, where

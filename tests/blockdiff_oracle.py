@@ -42,10 +42,11 @@ def block_mask(t: int, block):
     return j <= i if block is None else (j // block) <= (i // block)
 
 
-def forward(est, tokens, mask=None):
+def forward(est, tokens, mask=None, pos=None):
     """(T, V) logits of one sequence ``tokens`` (T,) under ``mask``
-    (T, T), default the model's own block mask.  A key whose token is
-    the pad id 0 is never seen."""
+    (T, T), default the model's own block mask, at position ids ``pos``
+    (T,), default 0 .. T-1.  A key whose token is the pad id 0 is never
+    seen."""
     p = est.params["params"]
     tokens = jnp.asarray(tokens, jnp.int32)
     t = tokens.shape[0]
@@ -54,7 +55,7 @@ def forward(est, tokens, mask=None):
     mask = mask & (tokens != 0)[None, :]
     heads, kvh, hd = est.num_heads, est.num_kv_heads, est.head_dim
     x = _f32(p["Embed_0"]["embedding"])[tokens]
-    pos = jnp.arange(t)
+    pos = jnp.arange(t) if pos is None else jnp.asarray(pos)
     for layer in range(est.num_layers):
         lp = p[f"RoutedExpertBlock_{layer}"]
         ap = lp["MultiHeadSelfAttention_0"]
@@ -97,14 +98,29 @@ def forward(est, tokens, mask=None):
     return jnp.matmul(x, _f32(p["head"]["kernel"]), precision=HI)
 
 
+def choose(conf, masked, block: int, steps: int, step: int,
+           remasking: str, threshold: float):
+    """Which of a block's positions denoising step ``step`` of ``steps``
+    fixes, a bool vector: of the ``masked`` ones the ``B // T`` (+1 for
+    the first ``B % T`` steps) of highest confidence, ties to the
+    earlier; ``low_confidence_dynamic`` fixes every one above the
+    threshold instead where at least that many are."""
+    conf = np.where(masked, conf, -np.inf)
+    count = min(block // steps + (step < block % steps), masked.sum())
+    pick = np.zeros(block, bool)
+    pick[np.argsort(-conf, kind="stable")[:count]] = True
+    if remasking == "low_confidence_dynamic":
+        high = conf > threshold
+        if high.sum() >= count:
+            pick = high
+    return pick
+
+
 def generate(est, prompt, max_new: int, steps: int, remasking: str,
              threshold: float = 0.9):
     """(tokens (t0 + max_new,), {position: denoising step it was fixed
     at}) by the published procedure, the whole buffer forwarded anew at
-    every denoising step: per step ``B // T`` (+1 for the first ``B %
-    T`` steps) masked positions of highest confidence are fixed;
-    ``low_confidence_dynamic`` fixes every one above the threshold
-    instead where at least that many are."""
+    every denoising step, :func:`choose` fixing positions."""
     b, m = est.block_length, est.mask_token_id
     t0 = len(prompt)
     total = -(-(t0 + max_new) // b) * b
@@ -122,14 +138,8 @@ def generate(est, prompt, max_new: int, steps: int, remasking: str,
             conf = np.asarray(jax.nn.softmax(jnp.asarray(logits), -1))[
                 np.arange(b), x0
             ]
-            conf = np.where(masked, conf, -np.inf)
-            count = min(b // steps + (step < b % steps), masked.sum())
-            pick = np.zeros(b, bool)
-            pick[np.argsort(-conf, kind="stable")[:count]] = True
-            if remasking == "low_confidence_dynamic":
-                high = conf > threshold
-                if high.sum() >= count:
-                    pick = high
+            pick = choose(conf, masked, b, steps, step, remasking,
+                          threshold)
             for j in np.flatnonzero(pick):
                 buf[start + j] = x0[j]
                 open_[start + j] = False

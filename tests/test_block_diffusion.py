@@ -8,6 +8,7 @@ store and ``serve.load``."""
 import copy
 import json
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -73,7 +74,21 @@ def annotations(monkeypatch):
     closes while the test runs."""
     from learningorchestra_tpu.obs import tracing
 
-    seen = []
+    class Seen(list):
+        def settle(self, timeout=10.0):
+            """Wait for the turn that drains the pool: a turn's
+            annotation closes after the turn, which may be after the
+            stream's end has reached its client."""
+            deadline = time.monotonic() + timeout
+            while time.monotonic() < deadline:
+                steps = [md for name, md in list(self)
+                         if name == "decode.step"]
+                if steps and not steps[-1].get("slots"):
+                    return
+                time.sleep(0.01)
+            raise AssertionError("the decode worker never drained")
+
+    seen = Seen()
 
     class Recorded:
         def __init__(self, name, **metadata):
@@ -288,6 +303,7 @@ def test_step_annotation_carries_the_block_counters(api, annotations):
     _, base = api
     _stream(base, "bd", [5, 6, 7, 8, 9, 10], maxNewTokens=6,
             denoisingSteps=2, remasking="low_confidence_static")
+    annotations.settle()
     turns = [md for name, md in annotations
              if name == "decode.step" and md.get("positions")]
     assert turns
@@ -302,13 +318,14 @@ def test_step_annotation_carries_the_block_counters(api, annotations):
     assert sum(md["fixed"] for md in turns) == 2 + 4
 
 
-def test_a_block_pool_never_steps_ahead(api, est, annotations):
-    """The strategy decides a block pool's next input from its last
-    result, so its turn reads the step it dispatched: no step is
-    enqueued with the one before it unread (``stepsAhead`` stays 0,
-    every turn's annotation says ``ahead`` 0), and tokens and fixing
-    order are the reference's as before."""
+def test_a_block_pool_keeps_one_step_in_flight(api, est, annotations):
+    """The step program applies the strategy itself, so a block pool's
+    turn enqueues step k before it reads step k-1: every step but the
+    first after a drained pool is ahead (``stepsAhead`` counts them,
+    the turn's annotation says ``ahead`` 1), a token leaves a turn
+    late, and tokens and fixing order are the reference's as before."""
     server, base = api
+    before = server.serving.decode.stats()["models"]["bd"]
     prompt = [7, 3, 9, 2, 6]
     toks, got_steps = _stream(
         base, "bd", prompt, maxNewTokens=7, denoisingSteps=2,
@@ -320,11 +337,271 @@ def test_a_block_pool_never_steps_ahead(api, est, annotations):
     assert prompt + toks == want.tolist()
     assert got_steps == {p: s for p, s in want_steps.items()
                          if 5 <= p < 5 + 7}
+    annotations.settle()
     stats = server.serving.decode.stats()["models"]["bd"]
-    assert stats["steps"] > 0 and stats["stepsAhead"] == 0
+    # a prefill, then 2 generated blocks of 2 denoising forwards and a
+    # commit, then the one step the slot sat out before its end was read
+    n = stats["steps"] - before["steps"]
+    assert n == 1 + 2 * 3 + 1
+    assert stats["stepsAhead"] - before["stepsAhead"] == n - 1
     turns = [md for name, md in annotations
-             if name == "decode.step" and md.get("positions")]
-    assert turns and all(md["ahead"] == 0 for md in turns)
+             if name == "decode.step" and md["slots"]]
+    assert [md["ahead"] for md in turns] == [0] + [1] * (n - 1)
+    # what a turn counts of blocks is the step it READ: the first turn
+    # read none, the last step's (all sat out) counts no position
+    assert [md.get("positions", 0) for md in turns] == [0] + [4] * (n - 1)
+
+
+ORACLE_RULES = [(r, t) for r in ("low_confidence_static",
+                                 "low_confidence_dynamic")
+                for t in (1, 2, 3, 4)]
+
+
+@pytest.mark.parametrize("remasking,steps", ORACLE_RULES)
+def test_traced_strategy_is_the_oracles_choice(remasking, steps):
+    """``blocks.choose`` under jit against the reference's choice on
+    random float32 confidences WITH ties (drawn from five values), at
+    every step of the plan, with a prompt's remainder already fixed and
+    with positions fixed by earlier steps, thresholds among the values
+    drawn: the same positions, bit for bit."""
+    from learningorchestra_tpu.serve.decode import blocks
+
+    rng = np.random.default_rng(steps * 7 + len(remasking))
+    n, b = 512, 4
+    levels = np.array([0.2, 0.5, 0.5000001, 0.9, 1.0], np.float32)
+    conf = levels[rng.integers(0, 5, (n, b))]
+    conf[: n // 4] = rng.random((n // 4, b), np.float32)  # and without
+    given = rng.integers(0, b, n)  # a prompt's remainder: 0..3 fixed
+    masked = (np.arange(b)[None] >= given[:, None]) \
+        & (rng.random((n, b)) < 0.8)
+    masked[np.arange(n), b - 1] |= ~masked.any(1)  # a mask is left
+    step = rng.integers(0, steps, n)
+    threshold = levels[rng.integers(0, 5, n)]
+    dynamic = remasking == "low_confidence_dynamic"
+    got = np.asarray(jax.jit(blocks.choose)(
+        conf, masked, blocks.transfer_count(b, steps, step),
+        np.full(n, dynamic), threshold,
+    ))
+    for i in range(n):
+        want = oracle.choose(conf[i], masked[i], b, steps, int(step[i]),
+                             remasking, threshold[i])
+        assert got[i].tolist() == want.tolist(), (
+            conf[i], masked[i], step[i], threshold[i])
+    assert (got & ~masked).sum() == 0
+    if dynamic and steps > 1:  # the threshold fired here, and not there
+        counts = np.minimum(blocks.transfer_count(b, steps, step),
+                            masked.sum(1))
+        assert (got.sum(1) > counts).any() and (got.sum(1) == counts).any()
+
+
+def _decoder(server, name="bd"):
+    return server.serving.decode._decoder_for(name)
+
+
+def test_abort_and_reseat_with_a_step_in_flight(api, est, monkeypatch):
+    """A is aborted with a step of it in flight while C keeps the pool
+    stepping, and B is seated in the slot A left, behind a step in
+    flight: the result that A's last step brings is dropped, never
+    emitted, B begins anew at position 0 whatever state A left in the
+    slot, and B and C get the reference's tokens."""
+    from learningorchestra_tpu import faults
+
+    server, _ = api
+    eng = server.serving.decode
+    decoder = _decoder(server)
+    dropped, in_flight_at_admit, slots = [], {}, {}
+    real_read, real_admit = decoder._read_blocks, decoder._admit
+
+    def read_blocks(pool, col, stepped, now):
+        for slot, stream in enumerate(stepped):
+            if stream is not None and pool.streams[slot] is not stream \
+                    and col[slot, 0]:
+                dropped.append((stream, slot))
+        return real_read(pool, col, stepped, now)
+
+    def admit(stream):
+        in_flight_at_admit[stream.stream_id] = any(
+            p.unread is not None for p in decoder._pools.values()
+        )
+        seated = real_admit(stream)
+        slots[stream.stream_id] = next(
+            (slot for p in decoder._pools.values()
+             for slot, s in enumerate(p.streams) if s is stream), None)
+        return seated
+
+    monkeypatch.setattr(decoder, "_read_blocks", read_blocks)
+    monkeypatch.setattr(decoder, "_admit", admit)
+    prompts = {"a": [7, 3, 9, 2, 6, 4], "b": [5, 8, 1],
+               "c": [2, 2, 6, 1, 9]}
+    kw = dict(denoising_steps=2, remasking="low_confidence_static")
+    try:
+        faults.arm("serve.decode_step", "delay", delay_ms=30,
+                   max_triggers=256)
+        a = eng.generate("bd", prompts["a"], max_new_tokens=9,
+                         stream=True, **kw)
+        c = eng.generate("bd", prompts["c"], max_new_tokens=11,
+                         stream=True, **kw)
+        deadline = time.monotonic() + 30
+        while len(a.tokens) < 2 and time.monotonic() < deadline:
+            time.sleep(0.002)
+        assert 0 < len(a.tokens) < 9, "A not mid-flight"
+        a.abort("client went away")
+        assert a.wait_done(30)
+        sent_a = len(a.tokens)
+        b = eng.generate("bd", prompts["b"], max_new_tokens=9,
+                         stream=True, **kw)
+        assert b.wait_done(30) and c.wait_done(30)
+    finally:
+        faults.reset()
+    want = {k: oracle.generate(est, p, {"a": 9, "b": 9, "c": 11}[k], 2,
+                               "low_confidence_static")
+            for k, p in prompts.items()}
+    assert prompts["a"] + a.tokens == want["a"][0].tolist()[: 6 + sent_a]
+    assert [e for e, _ in a.sse_events()][-1] == "aborted"
+    # a step that forwarded A's block was read after A had gone
+    assert any(stream is a for stream, _ in dropped)
+    assert len(a.tokens) == sent_a < 9
+    # B took A's slot with C's step in flight
+    assert slots[b.stream_id] == slots[a.stream_id]
+    assert in_flight_at_admit[b.stream_id]
+    for stream, key, t0 in ((b, "b", 3), (c, "c", 5)):
+        tokens, steps = want[key]
+        assert stream.error is None
+        assert prompts[key] + stream.tokens == tokens.tolist()
+        got = {doc["i"]: doc["s"] for e, doc in stream.sse_events()
+               if e == "token"}
+        assert got == {p: s for p, s in steps.items() if p >= t0}
+
+
+def test_a_failed_step_with_one_unread_drops_the_pool(api, est,
+                                                      monkeypatch):
+    """A block pool's step raises when it is enqueued, with the step
+    before it unread: the pool forgets its device state whole, the
+    unread result with it, both its streams fail, and the next request
+    is served from a pool allocated afresh."""
+    from learningorchestra_tpu import faults
+
+    server, _ = api
+    eng = server.serving.decode
+    decoder = _decoder(server)
+    broken = threading.Event()
+    real = decoder._step_for
+
+    def step_for(nslots, kvlen):
+        step, shapes = real(nslots, kvlen)
+
+        def stepped(variables, *carried_and_slots):
+            if not broken.is_set():
+                return step(variables, *carried_and_slots)
+            for leaf in jax.tree_util.tree_leaves(carried_and_slots[:3]):
+                leaf.delete()
+            raise RuntimeError("chip fell over")
+        return stepped, shapes
+
+    aheads = []
+    real_dispatch = decoder._dispatch
+
+    def dispatch(pool, live, ahead):
+        aheads.append(ahead)
+        return real_dispatch(pool, live, ahead)
+
+    monkeypatch.setattr(decoder, "_step_for", step_for)
+    monkeypatch.setattr(decoder, "_dispatch", dispatch)
+    kw = dict(denoising_steps=2, remasking="low_confidence_static")
+    try:
+        faults.arm("serve.decode_step", "delay", delay_ms=20,
+                   max_triggers=256)
+        doomed = [
+            eng.generate("bd", prompt, max_new_tokens=12, stream=True,
+                         **kw)
+            for prompt in ([7, 2, 4, 1], [3, 9, 1, 5, 2])
+        ]
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline and not all(
+            len(s.tokens) >= 2 for s in doomed
+        ):
+            time.sleep(0.002)
+        assert all(0 < len(s.tokens) < 12 for s in doomed)
+        pool = decoder._pools[(None, 16)]
+        broken.set()
+        for s in doomed:
+            assert s.wait_done(30)
+    finally:
+        faults.reset()
+    assert aheads[-1] is True  # the step that raised had one unread
+    for s, prompt in zip(doomed, ([7, 2, 4, 1], [3, 9, 1, 5, 2])):
+        assert "chip fell over" in (s.error or ""), s.error
+        want, _ = oracle.generate(est, prompt, 12, 2,
+                                  "low_confidence_static")
+        assert prompt + s.tokens == want.tolist()[: len(prompt)
+                                                  + len(s.tokens)]
+    assert pool.unread is None and pool.state is None \
+        and pool.cache is None and pool.page_bytes() == 0
+    assert decoder._thread is not None and decoder._thread.is_alive()
+    broken.clear()
+    prompt = [3, 1, 4, 1, 5]
+    out = eng.generate("bd", [prompt], max_new_tokens=7, **kw)
+    want, _ = oracle.generate(est, prompt, 7, 2, "low_confidence_static")
+    assert out["tokens"][0] == want.tolist()
+    assert pool.state is not None
+
+
+def test_dynamic_rule_read_a_step_late_commits_on_the_devices_word(
+        api, est):
+    """Under ``low_confidence_dynamic`` a block may be done after fewer
+    denoising forwards than its plan has; the host reads that a step
+    late and plans nothing itself: the commit comes when the step
+    program says so, the forwards are as many as the reference's and
+    no more, each token with the reference's step."""
+    server, base = api
+    prompt = np.random.default_rng(5).integers(1, 96, 6).tolist()
+    want, want_steps = oracle.generate(
+        est, prompt, 14, 4, "low_confidence_dynamic", 0.02
+    )
+    before = server.serving.decode.stats()["models"]["bd"]
+    toks, got_steps = _stream(
+        base, "bd", prompt, maxNewTokens=14, denoisingSteps=4,
+        remasking="low_confidence_dynamic", confidenceThreshold=0.02,
+    )
+    assert prompt + toks == want.tolist()
+    assert got_steps == {p: s for p, s in want_steps.items()
+                         if 6 <= p < 6 + 14}
+    # the reference's denoising forwards: the steps a block's positions
+    # were fixed at are 0 .. its last, fewer than 4 where the threshold
+    # fired
+    last = {}
+    for p, s in want_steps.items():
+        last[p // 4] = max(last.get(p // 4, 0), s)
+    assert min(last.values()) < 3
+    after = server.serving.decode.stats()["models"]["bd"]
+    grown = {k: after["blockSteps"][k] - before["blockSteps"][k]
+             for k in ("prefill", "denoise", "commit")}
+    assert grown == {"prefill": 1, "commit": len(last),
+                     "denoise": sum(s + 1 for s in last.values())}
+    assert after["tokensFixed"] - before["tokensFixed"] == len(want_steps)
+
+
+def test_warming_a_replica_steps_a_throwaway_block_pool(api, est):
+    """``warm_replica`` runs a block model's recorded (S, Tk) steps on
+    a pool of its own, its state leaf with it, no slot seated: the
+    pools that serve, and the counters, are as they were."""
+    from types import SimpleNamespace
+
+    server, _ = api
+    eng = server.serving.decode
+    prompt = [5, 6, 7, 8, 9]
+    kw = dict(max_new_tokens=7, denoising_steps=2,
+              remasking="low_confidence_static")
+    want, _ = oracle.generate(est, prompt, 7, 2, "low_confidence_static")
+    assert eng.generate("bd", [prompt], **kw)["tokens"] == [want.tolist()]
+    assert server.serving.registry.get("bd").decode_warm
+    before = eng.stats()["models"]["bd"]
+    eng.warm_replica("bd", SimpleNamespace(
+        idx=0, place=lambda entry, _x: (entry.params, None)))
+    after = eng.stats()["models"]["bd"]
+    assert (after["steps"], after["pools"], after["blockSteps"]) == (
+        before["steps"], before["pools"], before["blockSteps"])
+    assert eng.generate("bd", [prompt], **kw)["tokens"] == [want.tolist()]
 
 
 def test_nonstream_and_defaults(api, est):

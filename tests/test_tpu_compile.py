@@ -153,6 +153,53 @@ def test_block_step_of_an_sdar_attention_layer_is_one_pass(
     _assert_one_pass_over_the_pages(text, pages, layers=1)
 
 
+@pytest.mark.parametrize("slots", [1, 2, 4, 8])
+def test_block_step_carries_its_state_in_place_at_sdar_widths(
+        slots, one_chip, monkeypatch):
+    """The engine's step program of ``BlockDiffusionMoELM``, two layers
+    at SDAR-30B-A3B's widths (128 experts top 8, the whole vocabulary,
+    bfloat16) over a 512 bucket, at every slot bucket: the blocks'
+    state rides the step donated like pages and buffer (all three
+    updated in place), nothing but the kernel makes an array of a page
+    leaf's shape, and the strategy adds no loop over slots."""
+    from learningorchestra_tpu.models.moe import _BlockDiffusionMoE
+    from learningorchestra_tpu.serve.decode import blocks
+    from learningorchestra_tpu.serve.decode.pages import build_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    module = _BlockDiffusionMoE(
+        vocab_size=151936, hidden_dim=2048, num_layers=2, num_heads=32,
+        num_kv_heads=4, head_dim=128, expert_dim=768, num_experts=128,
+        top_k=8, max_len=4096, block_length=4, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16,
+    )
+    step, pages = build_step(module, slots, 512)
+    variables = jax.eval_shape(
+        module.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )
+    width = len(blocks.STATE_HEAD) + 3 * 4
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    text = step.program.lower(
+        _on(one_chip, variables), _on(one_chip, pages), ints(slots, 512),
+        ints(slots, width), ints(len(blocks.SLOT_ROWS), slots),
+    ).compile().as_text()
+    _assert_one_pass_over_the_pages(text, pages, layers=2)
+    header = text.split("\n", 1)[0]
+    n_params = len(jax.tree_util.tree_leaves(variables))
+    n_pages = len(jax.tree_util.tree_leaves(pages))
+    # pages, then the token buffer, then the state: each its own alias
+    for index in range(n_params, n_params + n_pages + 2):
+        assert f"({index}, {{}}, may-alias)" in header, (index, header[:400])
+    assert f"s32[{slots},{width}]" in header
+    # the state machine is array operations: the only loops are the
+    # experts' kernel's own search over group sizes
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert all("moe_experts" in line for line in loops), loops
+
+
 @pytest.mark.parametrize("slots", [1, 8, 64])
 def test_latent_step_reads_the_packed_pages_once_at_kimi_k2_widths(
         slots, one_chip, monkeypatch):
