@@ -30,6 +30,7 @@ from learningorchestra_tpu.models.moe import (
     MoEDecoderLM,
     MoETransformerClassifier,
 )
+from learningorchestra_tpu.models.retention import RetentionLM
 
 __all__ = [
     "MLPClassifier",
@@ -47,4 +48,5 @@ __all__ = [
     "MoEDecoderLM",
     "BlockDiffusionMoELM",
     "MoETransformerClassifier",
+    "RetentionLM",
 ]

@@ -36,6 +36,7 @@ from learningorchestra_tpu.serve.decode.pages import (
     PagePool,
     build_step,
     first_pages,
+    keyed_by_length,
     step_donates,
     step_width,
 )
@@ -136,7 +137,10 @@ class _ModelDecoder:
         self.cfg = engine.cfg
         self._cv = make_condition("_ModelDecoder._cv")
         self._pending: deque = deque()
-        self._pools: dict = {}  # (replica_idx | None, kv) → PagePool
+        # (replica_idx | None, kv | None) → PagePool; kv None where the
+        # model's cache has no length axis: one pool for every length
+        self._pools: dict = {}
+        self._by_length: bool | None = None  # asked of the model once
         self._streams: dict = {}  # stream_id → DecodeStream (active)
         self._step_state: dict = {}  # (S, kv) → (step fn, cache shapes)
         self._thread: threading.Thread | None = None
@@ -160,7 +164,11 @@ class _ModelDecoder:
         # stepped: the ``lo:decode.step`` annotation's metadata.
         self._turn = {"prompt": 0, "output": 0, "keys": 0, "slots": 0,
                       "kv": 0, "inplace": 0, "ahead": 0,
-                      "kv_bytes_per_token": 0}
+                      "kv_bytes_per_token": 0, "pools": 0,
+                      "state_bytes_per_slot": 0, "state_resets": 0}
+        # Slot-steps that began a recurrent state from zero (a request's
+        # first, in a pool of states).
+        self.state_resets = 0
         # Generation by diffusion over blocks: slot-steps by phase
         # (whole prompt blocks prefilled, denoising forwards, commits),
         # positions processed, tokens the denoising forwards fixed.
@@ -389,26 +397,28 @@ class _ModelDecoder:
         try:
             replica = self._route_replica()
             ridx = None if replica is None else replica.idx
-            kvlen = bucket_for(
-                stream.span,
-                min(self.cfg.max_kv, self._max_len()),
-            )
+            # A cache of pages is a pool a length bucket; one of states
+            # takes every length, its token buffer as long as a request
+            # may be.
+            cap = min(self.cfg.max_kv, self._max_len())
+            kvlen = bucket_for(stream.span, cap) \
+                if self._keyed_by_length() else None
             pool = self._pools.get((ridx, kvlen))
             if pool is None:
                 pool = self._pools[(ridx, kvlen)] = PagePool(
-                    kvlen, self.cfg.max_slots, replica_idx=ridx,
+                    kvlen or cap, self.cfg.max_slots, replica_idx=ridx,
                     width=1 if stream.plan is None
                     else stream.plan.block,
                 )
                 obs_flight.record(
                     "decode", "pool_grow",
-                    model=self.name, kv=kvlen,
+                    model=self.name, kv=kvlen, buffer=pool.kv,
                     slots=self.cfg.max_slots,
                     replica=-1 if ridx is None else ridx,
                 )
             slot = pool.admit(
                 stream,
-                lambda want: self._step_for(want, kvlen)[1],
+                lambda want: self._step_for(want, pool.kv)[1],
             )
         except Exception as exc:  # noqa: BLE001 — fail THIS stream
             logger.error("decode admit failed %s", kv(
@@ -439,6 +449,12 @@ class _ModelDecoder:
     def _max_len(self) -> int:
         entry = self.engine.service.registry.get(self.name)
         return int(getattr(entry.estimator, "max_len", self.cfg.max_kv))
+
+    def _keyed_by_length(self) -> bool:
+        if self._by_length is None:
+            entry = self.engine.service.registry.get(self.name)
+            self._by_length = keyed_by_length(entry.estimator.module)
+        return self._by_length
 
     # -- stepping ------------------------------------------------------------
 
@@ -586,8 +602,12 @@ class _ModelDecoder:
         step, _ = self._step_for(pool.nslots, pool.kv)
         turn = self._turn
         turn["slots"] += pool.nslots
-        turn["kv"] = max(turn["kv"], pool.kv)
-        turn["kv_bytes_per_token"] = pool.token_bytes()
+        turn["pools"] += 1
+        if pool.holds_pages:
+            turn["kv"] = max(turn["kv"], pool.kv)
+            turn["kv_bytes_per_token"] = pool.token_bytes()
+        else:
+            turn["state_bytes_per_slot"] = pool.slot_bytes()
         if ahead:
             self.steps_ahead += 1
             turn["ahead"] += 1
@@ -628,6 +648,10 @@ class _ModelDecoder:
             self._count(
                 n_prompt, int(live.sum()) - n_prompt, int(nxt[live].sum())
             )
+            if not pool.holds_pages:
+                resets = int((live & (pos_now == 0)).sum())
+                self.state_resets += resets
+                turn["state_resets"] += resets
             col = self._call(pool, step, pos_now, t0s, live)
             pool.pos[live] = nxt[live]
             # Terminal: the full row (prompt + continuation) is in the
@@ -871,12 +895,16 @@ class _ModelDecoder:
             pools_snap = list(self._pools.values())
         pools = [
             {
-                "kv": pool.kv,
+                # the bucket the pool is keyed by; none where its cache
+                # has no length axis (``buffer``: its token buffer's)
+                "kv": pool.kv if pool.holds_pages else None,
+                "buffer": pool.kv,
                 "slots": pool.nslots,
                 "live": pool.live,
                 "steps": pool.steps,
                 "pageBytes": pool.page_bytes(),
                 "kvBytesPerToken": pool.token_bytes(),
+                "stateBytesPerSlot": pool.slot_bytes(),
                 "replica": pool.replica_idx,
             }
             for pool in pools_snap
@@ -888,6 +916,9 @@ class _ModelDecoder:
             "stepsInPlace": self.steps_in_place,
             "stepsAhead": self.steps_ahead,
             "pools": pools,
+            "poolsLive": sum(1 for pool in pools if pool["live"]),
+            # Slots a step began from a zero state (pools of states).
+            "stateResets": self.state_resets,
             # Cumulative, from the worker's own counts (each step, each
             # live slot is one slot-step: prompt while it consumes its
             # prompt, output once it produces tokens).

@@ -1,4 +1,4 @@
-"""KV page pools — resident decode state, bucketed on both axes.
+"""Decode pools — resident decode state, bucketed on both axes.
 
 One pool per (model, routed replica, KV-length bucket): a batch of S
 decode *slots* over a KV cache of Tk *pages* per slot.  Both S and Tk
@@ -19,6 +19,17 @@ zeroed in the token buffer: an all-pad row masks to an exact-zero
 attention output (the masked-softmax double-where), so stale KV pages
 cost nothing and need no scrubbing.
 
+A model whose layers keep a recurrent STATE a slot instead of pages
+(:data:`STATE_LEAVES`: no length axis) has ONE pool a replica whatever
+its requests' lengths, and one step program a slot bucket; the pool's
+``kv`` is then only the length of its token buffer.  A stale state is
+not harmless as a stale page is: whatever the slot's last request left
+would decay into the next one's output.  So the layer itself begins a
+slot that stands at position 0 from a zero state, inside the step
+program, from the ``cache_index`` it is handed (``ops/retention.py``):
+no scrub on ``release``, no further host array.  The layer takes a slot
+whose key mask is empty (a free one) to sit the step out.
+
 The step program OWNS the pool's device state: it consumes the cache
 and the token buffer it is called with (both are donated, XLA updates
 them in place) and hands back the ones to use from then on.  After a
@@ -38,19 +49,24 @@ from learningorchestra_tpu.serve.decode import blocks
 #: ``cached_value`` beside it), a layer of ``LatentAttention`` its one
 #: ``cached_latent``.
 PAGE_LEAVES = ("cached_key", "cached_latent")
+#: The leaf a layer's recurrent state is found by: a layer of
+#: ``PowerRetention`` keeps ``retained_state`` (with ``retained_norm``
+#: beside it).  No axis of it is a sequence length.
+STATE_LEAVES = ("retained_state",)
 
 
 def set_index(pages, pos):
     """The decode cache tree the module applies on: ``pages`` (the
     leaves a pool carries) with a ``cache_index`` beside every layer's
-    page leaf (:data:`PAGE_LEAVES`), each the per-slot position vector
-    ``pos`` (S,) — the step's single source of truth for where each
-    slot writes and how far it may attend."""
+    page or state leaf (:data:`PAGE_LEAVES`, :data:`STATE_LEAVES`),
+    each the per-slot position vector ``pos`` (S,) — the step's single
+    source of truth for where each slot writes, how far it may attend
+    and whether its state begins anew."""
     out = {
         key: set_index(val, pos) if isinstance(val, dict) else val
         for key, val in pages.items()
     }
-    if any(name in pages for name in PAGE_LEAVES):
+    if any(name in pages for name in PAGE_LEAVES + STATE_LEAVES):
         out["cache_index"] = pos
     return out
 
@@ -66,19 +82,46 @@ def strip_index(cache):
     }
 
 
-def first_pages(cache):
-    """The first page leaf of a decode cache tree, a layer's K pages or
-    its latent pages (:data:`PAGE_LEAVES`): the one the engine asks
-    ``is_deleted()`` after a step, to count the steps that updated the
-    pages in place."""
+def first_pages(cache, names=PAGE_LEAVES + STATE_LEAVES):
+    """The first leaf of a decode cache tree called one of ``names``:
+    by default a layer's K pages, its latent pages or its retained
+    state, the one the engine asks ``is_deleted()`` after a step, to
+    count the steps that updated the cache in place."""
     for key, val in cache.items():
-        if key in PAGE_LEAVES:
+        if key in names:
             return val
         if isinstance(val, dict):
-            found = first_pages(val)
+            found = first_pages(val, names)
             if found is not None:
                 return found
     return None
+
+
+def holds_pages(cache) -> bool:
+    """Whether a decode cache tree (arrays or shapes) holds pages, rows
+    that grow with the sequence; one of recurrent states alone does
+    not, and its pool serves every length."""
+    return first_pages(cache, PAGE_LEAVES) is not None \
+        or first_pages(cache, STATE_LEAVES) is None
+
+
+def cache_shapes(module, nslots: int, kv: int):
+    """Shape tree of the decode cache a pool of ``module`` carries for
+    ``nslots`` slots over ``kv`` positions."""
+    import jax
+    import jax.numpy as jnp
+
+    return strip_index(jax.eval_shape(
+        module.clone(decode=True).init, jax.random.PRNGKey(0),
+        jnp.zeros((nslots, kv), jnp.int32),
+    )["cache"])
+
+
+def keyed_by_length(module) -> bool:
+    """Whether pools of ``module`` are keyed by a KV-length bucket: the
+    model's own cache says, as its ``block_length`` says the step's
+    width."""
+    return holds_pages(cache_shapes(module, 1, 8))
 
 
 def _moe_stats(mut) -> list:
@@ -175,10 +218,7 @@ def build_step(module, nslots: int, kv: int):
 
     q, head = step_width(module), len(blocks.STATE_HEAD)
     decode_mod = module.clone(decode=True)
-    cache_shapes = strip_index(jax.eval_shape(
-        decode_mod.init, jax.random.PRNGKey(0),
-        jnp.zeros((nslots, kv), jnp.int32),
-    )["cache"])
+    shapes = cache_shapes(module, nslots, kv)
 
     def block_step(variables, cache, buf, state, slots):
         told = dict(zip(blocks.SLOT_ROWS, slots))
@@ -277,7 +317,10 @@ def build_step(module, nslots: int, kv: int):
         nxt_pos = pos + 1
         prev = jnp.take_along_axis(buf, nxt_pos[:, None], axis=1)[:, 0]
         # ``live`` gates the write: a free slot's buffer row stays
-        # all-pad (its attention mask stays empty), and a slot still
+        # all-pad (its attention mask stays empty: nothing attends,
+        # and a layer that keeps a state leaves a slot with no key to
+        # see untouched; one at position 0 it begins from zero, so a
+        # reused slot needs no scrub), and a slot still
         # prefilling copies the NEXT prompt token instead of the
         # model's prediction — identical to the solo scan's
         # ``i + 1 >= t0`` select.
@@ -306,11 +349,13 @@ def build_step(module, nslots: int, kv: int):
 
     # What ``call`` runs, for whoever compiles it without running it.
     call.program = jitted
-    return call, cache_shapes
+    return call, shapes
 
 
 class PagePool:
-    """S slots × Tk KV pages of resident decode state for one model.
+    """S slots × Tk KV pages of resident decode state for one model; or
+    S slots' recurrent states, where the model keeps no pages
+    (``holds_pages``): ``kv`` is then the token buffer's length alone.
 
     Only the owning model's decode worker thread touches a pool, so the
     pool itself is lock-free; the worker's condition variable is the
@@ -325,7 +370,8 @@ class PagePool:
 
     __slots__ = ("kv", "nslots", "max_slots", "cache", "buf", "state",
                  "pos", "fresh", "streams", "steps", "replica_idx",
-                 "unread", "read_at", "width", "_token_bytes")
+                 "unread", "read_at", "width", "holds_pages",
+                 "_token_bytes", "_slot_bytes")
 
     def __init__(self, kv: int, max_slots: int,
                  replica_idx: int | None = None, width: int = 1):
@@ -354,9 +400,10 @@ class PagePool:
         return sum(1 for s in self.streams if s is not None)
 
     def page_bytes(self) -> int:
-        """Resident KV bytes (per-head K and V pages, or a latent
-        layer's one ``kv_lora_rank + qk_rope_head_dim`` row a position)
-        — observability for the freeing tests.
+        """Resident cache bytes (per-head K and V pages, a latent
+        layer's one ``kv_lora_rank + qk_rope_head_dim`` row a position,
+        or a retention layer's state a slot) — observability for the
+        freeing tests.
         Shapes only (``nbytes`` is the aval's): the REST thread may
         call this while the worker is inside a step, when the tree it
         finds here has just been donated."""
@@ -369,11 +416,19 @@ class PagePool:
             leaf.nbytes for leaf in jax.tree_util.tree_leaves(cache)
         )
 
-    def token_bytes(self) -> float:
+    def token_bytes(self) -> float | None:
         """Resident KV bytes a cached position (``page_bytes`` over
         slots x pages): what a token costs the pool, all layers.  The
-        same at every slot bucket: worked out when the pool allocates."""
+        same at every slot bucket: worked out when the pool allocates.
+        None for a pool of states: a token costs it nothing."""
         return self._token_bytes
+
+    def slot_bytes(self) -> float | None:
+        """Resident state bytes a slot (``page_bytes`` over slots), all
+        layers, of a pool whose cache has no length axis: what a
+        request costs it, whatever its length.  None for a pool of
+        pages, which a request fills by the token."""
+        return self._slot_bytes
 
     @property
     def device(self) -> tuple:
@@ -402,7 +457,9 @@ class PagePool:
         # kept from its dispatch to the turn after.
         self.unread = None
         self.nslots = 0
-        self._token_bytes = 0.0
+        # Pages or states: the allocated tree says (``_alloc``).
+        self.holds_pages = True
+        self._token_bytes = self._slot_bytes = None
         self.pos = np.zeros(0, np.int32)
         self.fresh = np.zeros(0, bool)
         self.streams = []
@@ -425,7 +482,11 @@ class PagePool:
         self.fresh = np.zeros(nslots, bool)
         self.streams = [None] * nslots
         self.nslots = nslots
-        self._token_bytes = self.page_bytes() / (nslots * self.kv)
+        self.holds_pages = holds_pages(cache_shapes)
+        if self.holds_pages:
+            self._token_bytes = self.page_bytes() / (nslots * self.kv)
+        else:
+            self._slot_bytes = self.page_bytes() / nslots
 
     def _grow(self, cache_shapes, nslots: int) -> None:
         """Pad every per-slot axis up to the next slot bucket; existing
@@ -489,7 +550,9 @@ class PagePool:
         """Free the slot and its KV pages: zeroing the buffer row
         empties the slot's attention mask, so whatever K/V the pages
         still hold is unreachable — the pages are free for the next
-        admit without a scrub pass."""
+        admit without a scrub pass.  A state the slot leaves behind is
+        dropped by the step that seats the next request there: it
+        stands at position 0, where the layer begins from zero."""
         self.streams[slot] = None
         self.pos[slot] = 0
         if self.buf is not None:

@@ -33,7 +33,11 @@ from learningorchestra_tpu.ops.attention import (
     flash_attention,
     mha_reference,
 )
-from learningorchestra_tpu.ops import decode_attention, latent_attention
+from learningorchestra_tpu.ops import (
+    decode_attention,
+    latent_attention,
+    retention,
+)
 from learningorchestra_tpu.ops.quant import (
     dequantize_rowwise,
     quantize_rowwise,
@@ -200,6 +204,70 @@ def _latent_case(h: int, rank: int, rope: int, tk: int) -> dict:
     return {"out_err": err, "pages_equal": same, "ok": same and err < TOL}
 
 
+def _retention_case(slots: int, heads: int, group: int, dim: int) -> dict:
+    """One step of the power retention recurrence over ``slots`` slots'
+    states at the published shapes (8,256 products of a 128-wide key,
+    128 values each, float32) against the plain update at ``highest``
+    precision: slots live, dead (their states must come back bit for
+    bit) and begun anew, in a mixed order.  The kernel alone is timed:
+    the mean of 10 calls with every slot live, each consuming the
+    states the last returned, and the least bytes (each state read and
+    written once) over that time."""
+    import time
+
+    rng = np.random.default_rng(slots * 7 + dim)
+
+    def draw(*shape, scale=1.0):
+        return jnp.asarray(scale * rng.standard_normal(shape), jnp.float32)
+
+    s_shape, z_shape = retention.state_shapes(slots, heads, dim, dim)
+    state, norm = draw(*s_shape), draw(*z_shape)
+    q = draw(slots, heads, group, dim).astype(jnp.bfloat16)
+    k = draw(slots, heads, dim).astype(jnp.bfloat16)
+    v = draw(slots, heads, dim)
+    g = jnp.asarray(rng.uniform(0.9, 1.0, (slots, heads)), jnp.float32)
+    live = np.ones(slots, bool)
+    live[[0, 5, slots - 1] if slots > 6 else [0]] = False
+    fresh = np.zeros(slots, bool)
+    fresh[[1, 5, slots // 2] if slots > 6 else [1]] = True
+
+    def run(fn):
+        def step(state, norm, live, fresh):
+            return fn(state, norm, retention.feature_map(q),
+                      retention.feature_map(k), v, g, live, fresh)
+        # the states are donated, as the engine's step donates them
+        return jax.jit(step, donate_argnums=(0, 1))
+
+    ref = run(retention.plain_retention_step)(
+        state + 0, norm + 0, jnp.asarray(live), jnp.asarray(fresh))
+    kernel = run(retention.retention_step_kernel)
+    got = kernel(state + 0, norm + 0, jnp.asarray(live), jnp.asarray(fresh))
+    scale = max(1.0, float(jnp.max(jnp.abs(ref[0]))))
+    errs = {
+        name: _max_err(r, o) / (scale if name in ("num", "den") else 1.0)
+        for name, r, o in zip(("num", "den", "state", "norm"), ref, got)
+    }
+    dead_same = bool(
+        jnp.array_equal(got[2][~live], state[~live])
+        and jnp.array_equal(got[3][~live], norm[~live])
+    )
+    on, off = jnp.ones(slots, bool), jnp.zeros(slots, bool)
+    carried = kernel(got[2], got[3], on, off)
+    jax.block_until_ready(carried)
+    t0 = time.perf_counter()
+    for _ in range(10):
+        carried = kernel(carried[2], carried[3], on, off)
+    jax.block_until_ready(carried)
+    seconds = (time.perf_counter() - t0) / 10
+    moved = 2.0 * slots * heads * retention.state_rows(dim) * (dim + 1) * 4
+    return {
+        "errs": errs, "dead_states_equal": dead_same,
+        "step_ms_with_feature_maps": 1e3 * seconds,
+        "least_gb_per_s": moved / seconds / 1e9,
+        "ok": dead_same and all(e < 1e-4 for e in errs.values()),
+    }
+
+
 def main() -> int:
     only = sys.argv[1] if len(sys.argv) > 1 else ""
     dev = jax.devices()[0]
@@ -231,6 +299,10 @@ def main() -> int:
                   lambda: _latent_case(64, 512, 64, 2048)))
     cases.append(("latent_attend:64h:512+64:Tk256:bf16",
                   lambda: _latent_case(64, 512, 64, 256)))
+    cases.append(("retention_step:16slots:8x5h:8256x128:f32",
+                  lambda: _retention_case(16, 8, 5, 128)))
+    cases.append(("retention_step:2slots:8x5h:8256x128:f32",
+                  lambda: _retention_case(2, 8, 5, 128)))
     if jax.device_count() >= 4:
         # chip_smoke.py's multi-chip phase owns the ring-flash check
         # (it raises on a miss).

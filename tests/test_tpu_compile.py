@@ -252,3 +252,54 @@ def test_latent_step_reads_the_packed_pages_once_at_kimi_k2_widths(
     assert f"bf16[{slots},1024,1152]{{2,1,0" in text  # row-major
     if slots * 8 % 16 == 0:
         assert "ragged-dot" not in text
+
+
+@pytest.mark.parametrize("slots", [1, 2, 4, 8, 16])
+def test_retention_step_updates_the_states_in_place_at_brumby_widths(
+        slots, one_chip, monkeypatch):
+    """The engine's step program of ``RetentionLM`` at Brumby-14B's
+    widths (two layers; 40 query heads over 8 key/value heads of 128,
+    the whole vocabulary, bfloat16) at every slot bucket: one
+    ``retention_step`` kernel a layer over ONE state leaf a layer of
+    8,320 rows of 128 lanes a value (8,256 products and their padding,
+    not 16,384), float32; the leaf aliased through the kernel and never
+    copied, relaid or made anew; no leaf as long as the token buffer."""
+    import re
+
+    from learningorchestra_tpu.models.retention import RetentionLM
+    from learningorchestra_tpu.serve.decode.pages import build_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    est = RetentionLM(
+        vocab_size=151936, hidden_dim=5120, num_layers=2, num_heads=40,
+        num_kv_heads=8, head_dim=128, mlp_dim=17408, rope_theta=1e6,
+        norm_eps=1e-6, max_len=32768,
+    )
+    step, states = build_step(est.module, slots, 2048)
+    leaves = jax.tree_util.tree_leaves(states)
+    assert sorted(leaf.shape for leaf in leaves) == sorted(
+        [(slots, 8, 65, 128, 128), (slots, 8, 65, 128)] * 2)
+    assert {leaf.dtype for leaf in leaves} == {jnp.dtype("float32")}
+    variables = jax.eval_shape(
+        est.module.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )
+    text = step.program.lower(
+        _on(one_chip, variables), _on(one_chip, states),
+        jax.ShapeDtypeStruct((slots, 2048), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((3, slots), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+    calls = re.findall(r"%(\S+) = [^=]* custom-call\(", text)
+    assert sum(c.startswith("retention_step") for c in calls) == 2
+    # every state leaf and the token buffer: each its own alias
+    assert text.split("\n", 1)[0].count("-alias)") >= 5
+    makers = {
+        op for shape, op in re.findall(
+            r"= (\w+\[[\d,]*\])\{[^ ]*\} ([\w-]+)\(", text
+        ) if shape == f"f32[{slots},8,65,128,128]"
+    }
+    # in place: the kernel writes each state back into the aliased
+    # leaf; no copy, no relayout, no select, no fusion makes one
+    assert makers <= {"parameter", "get-tuple-element"}, makers
+    for scope in ("retention_proj", "retention_gate", "retention_step",
+                  "retention_out"):
+        assert scope in text, scope
