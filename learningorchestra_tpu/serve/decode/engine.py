@@ -35,6 +35,7 @@ from learningorchestra_tpu.serve.decode.blocks import BlockPlan
 from learningorchestra_tpu.serve.decode.pages import (
     PagePool,
     build_step,
+    chunk_width,
     first_pages,
     keyed_by_length,
     step_donates,
@@ -140,9 +141,12 @@ class _ModelDecoder:
         # (replica_idx | None, kv | None) → PagePool; kv None where the
         # model's cache has no length axis: one pool for every length
         self._pools: dict = {}
-        self._by_length: bool | None = None  # asked of the model once
+        # (pools keyed by a KV bucket?, prompt positions a step may
+        # take): asked of the model's own cache, once
+        self._layout: tuple | None = None
         self._streams: dict = {}  # stream_id → DecodeStream (active)
-        self._step_state: dict = {}  # (S, kv) → (step fn, cache shapes)
+        # (S, kv, chunk) → (step fn, cache shapes)
+        self._step_state: dict = {}
         self._thread: threading.Thread | None = None
         self._closed = False
         self.steps = 0
@@ -155,15 +159,24 @@ class _ModelDecoder:
         self.steps_ahead = 0
         # Written by the worker thread alone, read by stats().
         self.phases = obs_tracing.Phases("decode", _PHASES)
-        self.prompt_steps = 0  # slot-steps that consumed a prompt token
-        self.output_steps = 0  # slot-steps that produced an output token
+        # A slot-step is a prompt step while prompt lies beyond its
+        # position (it moves past ``prompt_positions`` prompt tokens
+        # without producing: one in a one-token program, up to the
+        # chunk's width in the chunk program, a prompt's ``t0 - 1`` in
+        # all; the chunk that reaches a prompt's end also produces the
+        # request's first token), else an output step (one token).
+        self.prompt_steps = 0
+        self.output_steps = 0
+        self.prompt_positions = 0
+        self.chunk_steps = 0  # steps that ran the prompt-chunk program
         self.keys_attended = 0
         self.admitted = 0
         self.admit_wait_s = 0.0
         # One turn's share of the three counters above and what it
         # stepped: the ``lo:decode.step`` annotation's metadata.
         self._turn = {"prompt": 0, "output": 0, "keys": 0, "slots": 0,
-                      "kv": 0, "inplace": 0, "ahead": 0,
+                      "kv": 0, "inplace": 0, "ahead": 0, "chunk": 0,
+                      "prompt_positions": 0,
                       "kv_bytes_per_token": 0, "pools": 0,
                       "state_bytes_per_slot": 0, "state_resets": 0}
         # Slot-steps that began a recurrent state from zero (a request's
@@ -401,14 +414,15 @@ class _ModelDecoder:
             # takes every length, its token buffer as long as a request
             # may be.
             cap = min(self.cfg.max_kv, self._max_len())
-            kvlen = bucket_for(stream.span, cap) \
-                if self._keyed_by_length() else None
+            by_length, chunk = self._pool_layout()
+            kvlen = bucket_for(stream.span, cap) if by_length else None
             pool = self._pools.get((ridx, kvlen))
             if pool is None:
                 pool = self._pools[(ridx, kvlen)] = PagePool(
                     kvlen or cap, self.cfg.max_slots, replica_idx=ridx,
                     width=1 if stream.plan is None
                     else stream.plan.block,
+                    chunk=chunk,
                 )
                 obs_flight.record(
                     "decode", "pool_grow",
@@ -450,22 +464,28 @@ class _ModelDecoder:
         entry = self.engine.service.registry.get(self.name)
         return int(getattr(entry.estimator, "max_len", self.cfg.max_kv))
 
-    def _keyed_by_length(self) -> bool:
-        if self._by_length is None:
-            entry = self.engine.service.registry.get(self.name)
-            self._by_length = keyed_by_length(entry.estimator.module)
-        return self._by_length
+    def _pool_layout(self) -> tuple:
+        """(whether pools are keyed by a KV-length bucket, the prompt
+        positions a slot may take in one step): both read from the
+        model's own decode cache (``pages.py``)."""
+        if self._layout is None:
+            module = self.engine.service.registry.get(
+                self.name
+            ).estimator.module
+            self._layout = (keyed_by_length(module), chunk_width(module))
+        return self._layout
 
     # -- stepping ------------------------------------------------------------
 
-    def _step_for(self, nslots: int, kvlen: int):
-        """(jitted step, cache shapes) for one (S, Tk) cell, resolved
+    def _step_for(self, nslots: int, kvlen: int, chunk: int = 1):
+        """(jitted step, cache shapes) for one (S, Tk) cell, the
+        prompt-chunk program where ``chunk`` is over 1, resolved
         through the cross-job compile cache: fingerprints, hit/miss
         stats, warm-start hints and AOT eligibility — never a private
         dict of executables.  Memoized on the decoder (dies with the
         model teardown) and recorded on the registry entry's
         ``decode_warm`` so replica pre-warm can replay it."""
-        state = self._step_state.get((nslots, kvlen))
+        state = self._step_state.get((nslots, kvlen, chunk))
         if state is None:
             from learningorchestra_tpu.train import compile_cache as cc
 
@@ -477,7 +497,7 @@ class _ModelDecoder:
                 optimizer=None,
                 loss="-",
                 dtype="-",
-                shapes=("decode_step", nslots, kvlen),
+                shapes=("decode_step", nslots, kvlen, chunk),
                 # The step consumes its cache and buffer (a block
                 # pool's, the blocks' state too): a store keyed without
                 # this must not hand back one that copies.
@@ -485,14 +505,16 @@ class _ModelDecoder:
             )
             label = (
                 f"decode:{type(module).__name__}"
-                f":s{nslots}:k{kvlen}"
+                f":s{nslots}:k{kvlen}" + (f":c{chunk}" if chunk > 1 else "")
             )
+            # a one-token or block program is asked for as it always was
+            width = (chunk,) if chunk > 1 else ()
             state = cc.get_cache().get_or_build(
-                key, lambda: build_step(module, nslots, kvlen),
+                key, lambda: build_step(module, nslots, kvlen, *width),
                 label=label,
             )
-            self._step_state[(nslots, kvlen)] = state
-            entry.decode_warm[(nslots, kvlen)] = True
+            self._step_state[(nslots, kvlen, chunk)] = state
+            entry.decode_warm[(nslots, kvlen, chunk)] = True
         return state
 
     def _params_for(self, pool: PagePool):
@@ -568,7 +590,9 @@ class _ModelDecoder:
         own step has ended on the chip.  Reading step k-1 before step
         k+1 is enqueued is what bounds the run-ahead at one step.
         ``pool.width``, the model's own, chooses the step program and
-        the reader of its result, nothing else."""
+        the reader of its result, nothing else; ``pool.chunk``, the
+        model's own too, how many prompt positions a slot may take in
+        one step of a one-token pool (``_dispatch``)."""
         from learningorchestra_tpu import faults
 
         # A one-token pool's positions advance when a step is
@@ -599,7 +623,6 @@ class _ModelDecoder:
         stepped and, of a one-token pool, their positions after it and
         the terminal buffer rows of the lazy streams it ends.
         ``ahead``: the step before it is still unread."""
-        step, _ = self._step_for(pool.nslots, pool.kv)
         turn = self._turn
         turn["slots"] += pool.nslots
         turn["pools"] += 1
@@ -625,6 +648,7 @@ class _ModelDecoder:
                     )
             pool.fresh[:] = False
             nxt, rows = None, {}
+            step, _ = self._step_for(pool.nslots, pool.kv)
             col = self._call(pool, step, slots)
         else:
             t0s = np.array(
@@ -638,20 +662,39 @@ class _ModelDecoder:
             # from the FUTURE); a slot not live in this step goes in at
             # position 0, like a free one.
             pos_now = np.where(live, pool.pos, 0).astype(np.int32)
-            # What this step does, slot by slot: a live slot whose next
-            # position is still inside its prompt consumes a prompt
-            # token (prefill, one token a step), any other live slot
-            # produces an output token; each attends over the keys up
-            # to and with its own position.
-            nxt = pos_now + 1
-            n_prompt = int((live & (nxt < t0s)).sum())
+            # What this step does, slot by slot, follows from lengths
+            # (the step program derives the same ``n`` from the same
+            # three vectors): a live slot with prompt beyond its
+            # position takes up to ``pool.chunk`` prompt positions
+            # (prefill; the chunk that reaches the prompt's end
+            # produces the first token too), any other live slot its
+            # one position and an output token; each attends over the
+            # keys up to and with its last position.  Only a step in
+            # which some slot takes several runs the chunk program: a
+            # pool that only decodes runs the one-token program.
+            n = np.where(
+                live, np.clip(t0s - pos_now, 1, pool.chunk), 1
+            ).astype(np.int32)
+            chunked = bool((n > 1).any())
+            nxt = pos_now + n
+            feeding = live & (pos_now < t0s - 1)
+            n_prompt = int(feeding.sum())
             self._count(
-                n_prompt, int(live.sum()) - n_prompt, int(nxt[live].sum())
+                n_prompt, int(live.sum()) - n_prompt, int(nxt[live].sum()),
+                # prompt tokens the step moves past without producing:
+                # a prompt's t0 - 1 in all, however many steps take them
+                positions=int(np.minimum(n, t0s - 1 - pos_now)[feeding].sum()),
             )
+            if chunked:
+                self.chunk_steps += 1
+                turn["chunk"] += 1
             if not pool.holds_pages:
                 resets = int((live & (pos_now == 0)).sum())
                 self.state_resets += resets
                 turn["state_resets"] += resets
+            step, _ = self._step_for(
+                pool.nslots, pool.kv, pool.chunk if chunked else 1
+            )
             col = self._call(pool, step, pos_now, t0s, live)
             pool.pos[live] = nxt[live]
             # Terminal: the full row (prompt + continuation) is in the
@@ -732,6 +775,7 @@ class _ModelDecoder:
         self._count(
             by_kind["prefill"], stepped_n - by_kind["prefill"],
             q * int((starts + q)[kinds != blocks.IDLE].sum()),
+            positions=q * by_kind["prefill"],
         )
         bturn = self._block_turn
         for name, n in by_kind.items():
@@ -773,19 +817,23 @@ class _ModelDecoder:
         )
         _decode_hists.tokens(len(stream.tokens), self.name)
 
-    def _count(self, prompt: int, output: int, keys: int) -> None:
-        """A step's slot-steps and attended keys, into the cumulative
-        counters and the turn's annotation.  A one-token pool's are
+    def _count(self, prompt: int, output: int, keys: int,
+               positions: int) -> None:
+        """A step's slot-steps (and the ``positions`` its prompt steps
+        took) and attended keys, into the cumulative counters and the
+        turn's annotation.  A one-token pool's are
         counted at dispatch (they follow from lengths), a block pool's
         when its result is read (they are the step program's word), and
         never at the turn's end: a stream this step finishes may read
         stats() before the turn is over."""
         self.prompt_steps += prompt
         self.output_steps += output
+        self.prompt_positions += positions
         self.keys_attended += keys
         turn = self._turn
         turn["prompt"] += prompt
         turn["output"] += output
+        turn["prompt_positions"] += positions
         turn["keys"] += keys
 
     def _count_experts(self, counted) -> None:
@@ -868,8 +916,8 @@ class _ModelDecoder:
         load/compile before the router may pick the replica (the
         decode leg of PR-16 replica pre-warm)."""
         width = step_width(entry.estimator.module)
-        for (nslots, kvlen) in sorted(entry.decode_warm):
-            step, cache_shapes = self._step_for(nslots, kvlen)
+        for (nslots, kvlen, chunk) in sorted(entry.decode_warm):
+            step, cache_shapes = self._step_for(nslots, kvlen, chunk)
             pool = PagePool(kvlen, nslots, replica_idx=replica.idx,
                             width=width)
             pool._alloc(cache_shapes, nslots)
@@ -915,15 +963,20 @@ class _ModelDecoder:
             "steps": self.steps,
             "stepsInPlace": self.steps_in_place,
             "stepsAhead": self.steps_ahead,
+            # Steps that ran the prompt-chunk program (some slot took
+            # several prompt positions), of ``steps``.
+            "chunkSteps": self.chunk_steps,
             "pools": pools,
             "poolsLive": sum(1 for pool in pools if pool["live"]),
             # Slots a step began from a zero state (pools of states).
             "stateResets": self.state_resets,
             # Cumulative, from the worker's own counts (each step, each
-            # live slot is one slot-step: prompt while it consumes its
-            # prompt, output once it produces tokens).
+            # live slot is one slot-step: prompt while prompt lies
+            # beyond its position, output once it only produces
+            # tokens), and the positions the prompt steps took.
             "slotSteps": {"prompt": self.prompt_steps,
                           "output": self.output_steps},
+            "promptPositions": self.prompt_positions,
             "keysAttended": self.keys_attended,
             "phaseS": dict(self.phases.total),
             "phaseMaxS": dict(self.phases.peak),

@@ -13,8 +13,10 @@ hit/miss stats and AOT eligibility all apply.
 The continuous-batching trick is the per-row ``cache_index``: the
 attention decode branch (ops/layers.py) accepts a (S,)-shaped index,
 so slots sit at DIFFERENT sequence positions inside one jitted step —
-a newly admitted prompt starts its one-token-per-step prefill in the
-same dispatch that extends its neighbours.  Freed slots are simply
+a newly admitted prompt starts its prefill (a chunk of positions a
+step where every cache layer is K/V pages, :func:`chunk_width`; else
+one token a step) in the same dispatch that extends its neighbours.
+Freed slots are simply
 zeroed in the token buffer: an all-pad row masks to an exact-zero
 attention output (the masked-softmax double-where), so stale KV pages
 cost nothing and need no scrubbing.
@@ -105,16 +107,39 @@ def holds_pages(cache) -> bool:
         or first_pages(cache, STATE_LEAVES) is None
 
 
+#: (module fingerprint, kv) -> the decode cache's shape tree for ONE
+#: slot.  Tracing a model's init takes seconds at a published depth
+#: (6.5 s for 48 layers on the chip machine's host, PR 37), and a pool
+#: asks at every slot bucket it grows through and for each of its
+#: step programs: asked once a length, scaled by the slots.
+_ONE_SLOT: dict = {}
+
+
 def cache_shapes(module, nslots: int, kv: int):
     """Shape tree of the decode cache a pool of ``module`` carries for
-    ``nslots`` slots over ``kv`` positions."""
+    ``nslots`` slots over ``kv`` positions.  Every leaf's first axis is
+    the slots' (what ``PagePool._grow`` pads) and nothing else of a
+    leaf depends on them."""
     import jax
     import jax.numpy as jnp
 
-    return strip_index(jax.eval_shape(
-        module.clone(decode=True).init, jax.random.PRNGKey(0),
-        jnp.zeros((nslots, kv), jnp.int32),
-    )["cache"])
+    from learningorchestra_tpu.train.compile_cache import (
+        module_fingerprint,
+    )
+
+    key = (module_fingerprint(module), int(kv))
+    one = _ONE_SLOT.get(key)
+    if one is None:
+        if len(_ONE_SLOT) >= 64:
+            _ONE_SLOT.clear()
+        one = _ONE_SLOT[key] = strip_index(jax.eval_shape(
+            module.clone(decode=True).init, jax.random.PRNGKey(0),
+            jnp.zeros((1, kv), jnp.int32),
+        )["cache"])
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct((nslots, *s.shape[1:]), s.dtype),
+        one,
+    )
 
 
 def keyed_by_length(module) -> bool:
@@ -157,16 +182,50 @@ def step_width(module) -> int:
     return int(getattr(module, "block_length", None) or 1)
 
 
+#: Prompt positions a slot takes in ONE step of a pool whose every
+#: cache layer is K/V pages (:func:`chunk_width`).  Every slot of a
+#: chunk step carries this many rows, the decoding ones too, and the
+#: gap between a decoding neighbour's tokens is that step's time: so
+#: it is the widest of 4, 8, 16, 32 whose step at GPT-2 XL's widths, 8
+#: slots over a 512 bucket, takes no more than 1.04 times the one-token
+#: step's device time on a v5e (``scripts/chip_chunk_sweep.py``).
+#: Measured 11.19, 11.39, 14.18, 21.00 ms against 11.94 (``PERF.md``
+#: section 6, PR 37): 8 passes, 16 does not.
+PROMPT_CHUNK = 8
+
+
+def chunk_width(module) -> int:
+    """Prompt positions a slot of ``module`` may take in one step:
+    :data:`PROMPT_CHUNK` where every cache layer is K/V pages of
+    ``MultiHeadSelfAttention`` (whose decode branch takes a causal
+    chunk at a per-row index and writes its rows in one pass) and the
+    model produces one token a step; else 1.  Read from the model's own
+    cache, as pages or states is (:func:`keyed_by_length`): a latent
+    layer's chunk wants its non-absorbed form, a retention layer's a
+    chunked scan, a block pool's prefill is whole blocks already."""
+    if step_width(module) > 1:
+        return 1
+    cache = cache_shapes(module, 1, 8)
+    others = tuple(
+        name for name in PAGE_LEAVES + STATE_LEAVES if name != "cached_key"
+    )
+    every = first_pages(cache, ("cached_key",)) is not None \
+        and first_pages(cache, others) is None
+    return PROMPT_CHUNK if every else 1
+
+
 def step_donates(module) -> tuple:
     """The arguments a step of ``module`` consumes: cache and token
     buffer, and a block pool's state."""
     return (1, 2, 3) if step_width(module) > 1 else (1, 2)
 
 
-def build_step(module, nslots: int, kv: int):
+def build_step(module, nslots: int, kv: int, chunk: int = 1):
     """(step fn, shape tree of the K/V pages a pool carries) for one
     (arch, S, Tk) cell.  ``step(variables, cache, buf, pos, t0s, live)``
-    returns ``(cache, buf, col)``.
+    returns ``(cache, buf, col)``.  ``chunk`` over 1 builds the
+    prompt-chunk program of a one-token model (below) in the one-token
+    program's place: same arguments, same result.
 
     The step CONSUMES its ``cache`` and ``buf`` arguments (donated: the
     K/V pages and the token buffer are updated in place, no second copy
@@ -182,6 +241,24 @@ def build_step(module, nslots: int, kv: int):
     same write-at-``pos+1`` — but with per-slot positions, so a slot
     admitted mid-flight produces bit-identical tokens to a solo decode
     of the same prompt (greedy only; sampling stays on the solo path).
+
+    The prompt-chunk program (``chunk`` = C > 1; a model of
+    :func:`chunk_width` C) lets a live slot at ``pos`` take ``n =
+    clip(t0 - pos, 1, C)`` positions ``pos .. pos+n-1`` in the one
+    step, all of them known: prompt tokens, or for a decoding slot
+    (n = 1) the token the last step wrote.  ``n`` follows on the device
+    from the same (3, S) array; nothing C wide comes from the host.
+    Every slot carries C rows: their tokens gathered from the buffer
+    at ``pos + arange(C)``, the layer's own causal in-chunk mask under
+    the ``buf != 0`` key mask, their K/V written in place by the
+    layer's one ``cached_attend``.  The slot's next token is the argmax
+    of the float32 logits of row ``n-1``, written at ``pos+n`` under
+    the one-token rule.  Rows ``n .. C-1`` are dead weight: what they
+    write lies beyond the slot's position, where no query may look
+    before the step that reaches it has rewritten it (as a block's
+    denoising forwards rely on); a row beyond the bucket is dropped;
+    their logits are never read.  The engine enqueues this program
+    only for a step in which some live slot has n > 1.
 
     A slot-step is ``q`` positions wide: 1 for a next-token model, the
     module's ``block_length`` for one that generates by diffusion over
@@ -302,19 +379,11 @@ def build_step(module, nslots: int, kv: int):
         ], 1).astype(jnp.int32)
         return strip_index(mut["cache"]), buf, state, col
 
-    def token_step(variables, cache, buf, slots):
-        pos, t0s, live = slots[0], slots[1], slots[2] != 0
-        cache = set_index(cache, pos)
-        tok = jnp.take_along_axis(buf, pos[:, None], axis=1)
-        kmask = (jnp.arange(kv)[None, :] <= pos[:, None]) & (buf != 0)
-        logits, mut = decode_mod.apply(
-            {**variables, "cache": cache}, tok,
-            positions=pos[:, None], key_mask=kmask,
-            mutable=["cache", "moe_stats"],
-        )
-        step_logits = logits[:, 0].astype(jnp.float32)
-        nxt = jnp.argmax(step_logits, -1).astype(jnp.int32)
-        nxt_pos = pos + 1
+    def write_next(buf, logits, nxt_pos, t0s, live, mut):
+        """A one-token model's step from its slots' last logits on:
+        the next tokens into the buffer at ``nxt_pos``, and the step's
+        result."""
+        nxt = jnp.argmax(logits.astype(jnp.float32), -1).astype(jnp.int32)
         prev = jnp.take_along_axis(buf, nxt_pos[:, None], axis=1)[:, 0]
         # ``live`` gates the write: a free slot's buffer row stays
         # all-pad (its attention mask stays empty: nothing attends,
@@ -333,10 +402,43 @@ def build_step(module, nslots: int, kv: int):
             )
         return strip_index(mut["cache"]), buf, col
 
+    def token_step(variables, cache, buf, slots):
+        pos, t0s, live = slots[0], slots[1], slots[2] != 0
+        cache = set_index(cache, pos)
+        tok = jnp.take_along_axis(buf, pos[:, None], axis=1)
+        kmask = (jnp.arange(kv)[None, :] <= pos[:, None]) & (buf != 0)
+        logits, mut = decode_mod.apply(
+            {**variables, "cache": cache}, tok,
+            positions=pos[:, None], key_mask=kmask,
+            mutable=["cache", "moe_stats"],
+        )
+        return write_next(buf, logits[:, 0], pos + 1, t0s, live, mut)
+
+    def chunk_step(variables, cache, buf, slots):
+        pos, t0s, live = slots[0], slots[1], slots[2] != 0
+        n = jnp.where(live, jnp.clip(t0s - pos, 1, chunk), 1)
+        # A row beyond the bucket reads the bucket's last token at its
+        # last position, and its K/V is dropped.
+        lanes = jnp.minimum(
+            pos[:, None] + jnp.arange(chunk)[None, :], kv - 1
+        )
+        tok = jnp.take_along_axis(buf, lanes, axis=1)
+        logits, mut = decode_mod.apply(
+            {**variables, "cache": set_index(cache, pos)}, tok,
+            positions=lanes, key_mask=buf != 0,
+            mutable=["cache", "moe_stats"],
+        )
+        last = jnp.take_along_axis(
+            logits, (n - 1)[:, None, None], axis=1
+        )[:, 0]
+        return write_next(buf, last, pos + n, t0s, live, mut)
+
     def step(variables, cache, buf, *rest):
         # One name for the program whatever its width: the trace's
         # readers find a pool's steps as ``jit_step``.
-        return (token_step if q == 1 else block_step)(
+        if q > 1:
+            return block_step(variables, cache, buf, *rest)
+        return (token_step if chunk == 1 else chunk_step)(
             variables, cache, buf, *rest
         )
 
@@ -370,11 +472,12 @@ class PagePool:
 
     __slots__ = ("kv", "nslots", "max_slots", "cache", "buf", "state",
                  "pos", "fresh", "streams", "steps", "replica_idx",
-                 "unread", "read_at", "width", "holds_pages",
+                 "unread", "read_at", "width", "chunk", "holds_pages",
                  "_token_bytes", "_slot_bytes")
 
     def __init__(self, kv: int, max_slots: int,
-                 replica_idx: int | None = None, width: int = 1):
+                 replica_idx: int | None = None, width: int = 1,
+                 chunk: int = 1):
         self.kv = int(kv)
         self.max_slots = int(max_slots)
         # Positions a slot-step processes (``step_width``).  Over 1,
@@ -384,6 +487,10 @@ class PagePool:
         # marks the slots seated since the last dispatch, whose state
         # the next step begins anew.
         self.width = int(width)
+        # Prompt positions a slot may take in one step
+        # (``chunk_width``): a step in which some slot takes several
+        # runs the chunk program, any other the one-token program.
+        self.chunk = int(chunk)
         self.streams: list = []
         self.drop()
         self.steps = 0
@@ -518,8 +625,9 @@ class PagePool:
         """Seat ``stream`` in a free slot (growing to the next slot
         bucket if needed, up to ``max_slots``); None when full.  The
         slot's buffer row gets the prompt, position 0 — prefill runs
-        through the shared step one token at a time, exactly like the
-        solo scan (a block pool's, a whole prompt block a step)."""
+        through the shared step, ``chunk`` positions at a time with the
+        solo scan's result (a block pool's, a whole prompt block a
+        step)."""
         from learningorchestra_tpu.serve.bucketing import bucket_for
 
         slot = None

@@ -56,6 +56,9 @@ _kernel = jax.jit(
     (4, 8, 2, 64, jnp.float32, False),
     (4, 4, 4, 128, jnp.float32, False),
     (4, 5, 5, 64, jnp.bfloat16, True),
+    # a prompt chunk (``pages.PROMPT_CHUNK`` causal rows a slot)
+    (8, 5, 5, 64, jnp.float32, False),
+    (8, 8, 2, 64, jnp.bfloat16, False),
 ])
 def test_kernel_equals_the_plain_path(t, h, kvh, hd, dtype, block):
     """Slots at different lengths: at 0, mid-tile, astride two tiles

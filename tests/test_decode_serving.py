@@ -29,6 +29,23 @@ from tests.lm_oracle import naive_greedy_decode
 PREFIX = "/api/learningOrchestra/v1"
 
 
+def _slot_steps(t0: int, new: int, chunk: int | None = None,
+                cap: int = 16) -> list:
+    """(position, positions taken) of every step a request of ``t0``
+    prompt tokens and ``new`` outputs takes in a pool whose steps take
+    up to ``chunk`` prompt positions (the engine's own, by default):
+    the schedule ``_dispatch`` follows from lengths alone."""
+    from learningorchestra_tpu.serve.decode.pages import PROMPT_CHUNK
+
+    chunk = PROMPT_CHUNK if chunk is None else chunk
+    total, pos, out = min(cap, t0 + new), 0, []
+    while pos < total - 1:
+        n = min(max(t0 - pos, 1), chunk)
+        out.append((pos, n))
+        pos += n
+    return out
+
+
 def _install_trained_lm(server, name, *, vocab=16, hidden=32,
                         layers=2, heads=4, max_len=16):
     """Finished train artifact holding a fitted tiny DecoderLM (the
@@ -142,9 +159,12 @@ class TestSSERoundTrip:
         assert entry is not None and entry.decode_warm, (
             "decode step shapes must be recorded for replica pre-warm"
         )
-        for slots, kvlen in entry.decode_warm:
+        from learningorchestra_tpu.serve.decode.pages import PROMPT_CHUNK
+
+        for slots, kvlen, chunk in entry.decode_warm:
             assert slots & (slots - 1) == 0  # power-of-two bucketed
             assert kvlen & (kvlen - 1) == 0
+            assert chunk in (1, PROMPT_CHUNK)  # which of the two programs
 
     def test_validation_errors_are_406(self, decode_api):
         _, base, _ = decode_api
@@ -390,19 +410,29 @@ class TestDecodeLoopAccounting:
             assert stream.error is None
         after = self._model_stats(eng)
 
-        prompt_steps = sum(len(p) - 1 for p, _ in shapes)
-        output_steps = sum(new for _, new in shapes)
+        steps = [
+            (len(p), pos, n)
+            for p, new in shapes for pos, n in _slot_steps(len(p), new)
+        ]
         grew = {
             kind: after["slotSteps"][kind] - before["slotSteps"][kind]
             for kind in ("prompt", "output")
         }
-        # A stream of t0 prompt tokens and n outputs takes t0 - 1
-        # prompt steps (each feeds the next prompt token) and n output
-        # steps; every step attends the keys up to its own position.
-        assert grew == {"prompt": prompt_steps, "output": output_steps}
+        # A slot-step is a prompt step while prompt lies beyond its
+        # position (it takes up to a chunk of it; the chunk that
+        # reaches the prompt's end produces the first token too), an
+        # output step after; every step attends the keys up to the
+        # last position it took.  These prompts fit one chunk: one
+        # prompt step a stream, then its other outputs.
+        feeding = [(t0, pos, n) for t0, pos, n in steps if pos < t0 - 1]
+        assert grew == {"prompt": len(feeding),
+                        "output": len(steps) - len(feeding)}
+        assert grew == {"prompt": len(shapes), "output": sum(
+            new - 1 for _, new in shapes)}
+        assert after["promptPositions"] - before.get(
+            "promptPositions", 0) == sum(len(p) - 1 for p, _ in shapes)
         assert after["keysAttended"] - before["keysAttended"] == sum(
-            pos + 1
-            for p, new in shapes for pos in range(len(p) - 1 + new)
+            pos + n for _t0, pos, n in steps
         )
         assert after["admitted"] - before["admitted"] == len(shapes)
         assert after["admitWaitS"] >= before["admitWaitS"] >= 0.0
@@ -474,8 +504,8 @@ class TestStepOwnsItsState:
         ``wrap(step)`` (the compiled programs stay in the cache)."""
         real = decoder._step_for
 
-        def step_for(nslots, kvlen):
-            step, shapes = real(nslots, kvlen)
+        def step_for(*cell):  # slots, KV bucket, the program's width
+            step, shapes = real(*cell)
             return wrap(step), shapes
 
         monkeypatch.setattr(decoder, "_step_for", step_for)
@@ -505,8 +535,9 @@ class TestStepOwnsItsState:
         )
         assert stream.wait_done(60) and stream.error is None
         after = eng.stats()["models"]["lm_srv"]
-        # 2 prompt steps + 6 output steps, each in place.
-        assert after["steps"] - before["steps"] == len(went_in) == 8
+        # the prompt's chunk (with the first token) + 5 output steps,
+        # each in place.
+        assert after["steps"] - before["steps"] == len(went_in) == 6
         assert after["stepsInPlace"] == after["steps"]
         for pages, buf in went_in:
             assert pages.is_deleted() and buf.is_deleted()
@@ -674,7 +705,8 @@ class TestOneStepInFlight:
             tokens = out["tokens"][0]
         assert tokens == self._solo(est, prompt, new)
         after = eng.stats()["models"]["lm_srv"]
-        n = len(prompt) - 1 + new
+        n = len(_slot_steps(len(prompt), new))
+        assert n == new  # the prompt's one chunk brings the first token
         assert after["steps"] - before["steps"] == n
         steps = [col for kind, col in events if kind == "step"]
         reads = [col for kind, col in events if kind == "read"]
@@ -741,7 +773,8 @@ class TestOneStepInFlight:
         assert stream.done()
         dispatched = sum(kind == "step" for kind, _ in events)
         assert sum(kind == "read" for kind, _ in events) == dispatched
-        assert len(stream.tokens) == dispatched - (len(prompt) - 1)
+        # every step from the prompt's one chunk on brought a token
+        assert len(stream.tokens) == dispatched
         assert len(stream.tokens) < new
         assert stream.error == "decode engine shut down"
         solo = self._solo(est, prompt, new)
@@ -879,6 +912,406 @@ class TestOneStepInFlight:
         }
         assert slots[b_id] == slots[a.stream_id]
         assert slots[c.stream_id] != slots[a.stream_id]
+
+
+@pytest.fixture(scope="module")
+def long_lm(decode_api):
+    """A second model on the same server, 64 positions: prompts of
+    several chunks in one KV bucket."""
+    server, _, _ = decode_api
+    return _install_trained_lm(server, "lm_long", vocab=32, max_len=64)
+
+
+@pytest.fixture
+def step_annotations(monkeypatch):
+    """The metadata of every ``lo:decode.step`` annotation that closes
+    while the test runs."""
+    from learningorchestra_tpu.obs import tracing
+
+    seen = []
+
+    class Recorded:
+        def __init__(self, name, **metadata):
+            self.name, self.metadata = name, dict(metadata)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            if self.name == "decode.step":
+                seen.append(self.metadata)
+
+        def set_metadata(self, **metadata):
+            self.metadata.update(metadata)
+
+    monkeypatch.setattr(tracing, "annotation", Recorded)
+    return seen
+
+
+class TestPromptChunks:
+    """A one-token pool of K/V pages takes a prompt a chunk of
+    positions a step (``pages.PROMPT_CHUNK``) and its tokens are the
+    solo scan's, whatever the prompt's length against the chunk's,
+    wherever its neighbours stand; a step in which no slot has prompt
+    pending runs the one-token program."""
+
+    @staticmethod
+    def _chunk():
+        from learningorchestra_tpu.serve.decode.pages import PROMPT_CHUNK
+
+        assert PROMPT_CHUNK > 1
+        return PROMPT_CHUNK
+
+    @staticmethod
+    def _solo(est, prompt, new):
+        return np.asarray(est.generate(
+            np.asarray([prompt], np.int32), max_new_tokens=new
+        ))[0].tolist()
+
+    @staticmethod
+    def _stats(eng, name="lm_long"):
+        return eng.stats()["models"].get(name) or {
+            "steps": 0, "chunkSteps": 0, "promptPositions": 0,
+            "slotSteps": {"prompt": 0, "output": 0},
+        }
+
+    def test_mixed_prompt_lengths_admitted_at_different_turns(
+            self, decode_api, long_lm):
+        """Prompts of 1, 2, C-1, C, C+1 and 3C+2 positions, each
+        admitted while the ones before it are in flight in the same
+        pool (the 64 bucket, grown 1 -> 8 slots on the way)."""
+        server, _, _ = decode_api
+        eng = server.serving.decode
+        chunk = self._chunk()
+        rng = np.random.default_rng(37)
+        lengths = [1, 2, chunk - 1, chunk, chunk + 1, 3 * chunk + 2]
+        assert lengths[-1] + 8 <= 64
+        # every total in (32, 64]: one pool
+        shapes = [
+            (rng.integers(1, 32, t0).tolist(), max(8, 40 - t0))
+            for t0 in lengths
+        ]
+        before = self._stats(eng)
+        streams = []
+        try:
+            faults.arm("serve.decode_step", "delay", delay_ms=10,
+                       max_triggers=4096)
+            for prompt, new in shapes:
+                streams.append(eng.generate(
+                    "lm_long", prompt, max_new_tokens=new, stream=True
+                ))
+                held = streams[0] if len(streams) > 1 else None
+                deadline = time.monotonic() + 30
+                while not streams[-1].tokens \
+                        and time.monotonic() < deadline:
+                    time.sleep(0.002)
+                assert held is None or not held.done(), \
+                    "the first stream ended before the last was admitted"
+            for stream in streams:
+                assert stream.wait_done(60) and stream.error is None
+        finally:
+            faults.reset()
+        for (prompt, new), stream in zip(shapes, streams):
+            assert prompt + stream.tokens == self._solo(long_lm, prompt, new)
+        after = self._stats(eng)
+        pool = eng._decoder_for("lm_long")._pools[(None, 64)]
+        assert pool.chunk == chunk and pool.nslots == 8
+        steps = [
+            (len(p), pos, n) for p, new in shapes
+            for pos, n in _slot_steps(len(p), new, cap=64)
+        ]
+        feeding = [min(n, t0 - 1 - pos) for t0, pos, n in steps
+                   if pos < t0 - 1]
+        assert after["slotSteps"]["prompt"] \
+            - before["slotSteps"]["prompt"] == len(feeding)
+        # what one-token prefill took a slot-step each for
+        assert after["promptPositions"] - before["promptPositions"] \
+            == sum(feeding) == sum(t0 - 1 for t0 in lengths)
+        # a chunk program ran for every step in which some slot took
+        # several positions: at least the longest prompt's four, at
+        # most one a prompt step
+        assert 4 <= after["chunkSteps"] - before["chunkSteps"] \
+            <= len(feeding)
+
+    def test_a_slot_at_the_buckets_end_beside_a_chunking_neighbour(
+            self, long_lm):
+        """The two programs against each other from one pool's state:
+        slot 0 decodes at ``kv - 2`` (its ``total`` is the bucket's
+        last position, so the rows its chunk carries beyond are
+        dropped), slot 1 is in its prompt's second chunk, slot 2 is
+        free, slot 3 decodes.  The chunk program's buffer and the pages
+        every later query may see are the one-token program's."""
+        import jax
+        import jax.numpy as jnp
+
+        from learningorchestra_tpu.serve.decode.pages import build_step
+
+        chunk, kv, nslots = self._chunk(), 64, 4
+        module, variables = long_lm.module, dict(long_lm.params)
+        one, shapes = build_step(module, nslots, kv)
+        many, _ = build_step(module, nslots, kv, chunk)
+        rng = np.random.default_rng(5)
+        t0s = np.array([4, 2 * chunk + 3, kv + 1, 1], np.int32)
+        live = np.array([True, True, False, True])
+        rows = np.zeros((nslots, kv), np.int32)
+        for slot in np.flatnonzero(live):
+            rows[slot, : t0s[slot]] = rng.integers(1, 32, t0s[slot])
+
+        def drive(step, width, cache, buf, pos, stop):
+            """The engine's schedule: step the live slots that stand
+            before ``stop`` until none does."""
+            while (on := live & (pos < stop)).any():
+                n = np.where(on, np.clip(t0s - pos, 1, width), 1)
+                cache, buf, _ = step(
+                    variables, cache, buf,
+                    np.where(on, pos, 0).astype(np.int32), t0s, on,
+                )
+                pos = np.where(on, pos + n, pos).astype(np.int32)
+            return cache, np.asarray(buf), pos
+
+        zeros = jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), shapes
+        )
+        start = np.array([kv - 2, chunk, 0, 3], np.int32)
+        cache, buf, pos = drive(
+            one, 1, zeros, jnp.asarray(rows), np.zeros(nslots, np.int32),
+            start,
+        )
+        assert (pos == start).all()
+        stop = np.array([kv - 1, 2 * chunk, 0, 3 + chunk], np.int32)
+        (c1, b1, p1), (c2, b2, p2) = (
+            drive(step, width, jax.tree_util.tree_map(jnp.array, cache),
+                  jnp.asarray(buf), pos, stop)
+            for step, width in ((one, 1), (many, chunk))
+        )
+        assert (p1 == stop).all() and (p2 == stop).all()
+        # slot 0's last token, at the bucket's last position
+        assert b1[0, kv - 1] == b2[0, kv - 1] != 0
+        assert (b1 == b2).all() and not b2[2].any()
+        for leaf1, leaf2 in zip(jax.tree_util.tree_leaves(c1),
+                                jax.tree_util.tree_leaves(c2)):
+            flat1 = np.asarray(leaf1).reshape(nslots, leaf1.shape[1], kv, -1)
+            flat2 = np.asarray(leaf2).reshape(nslots, leaf2.shape[1], kv, -1)
+            for slot in np.flatnonzero(live):  # up to where it stands
+                np.testing.assert_allclose(
+                    flat2[slot, :, : stop[slot]],
+                    flat1[slot, :, : stop[slot]], rtol=1e-5, atol=1e-6,
+                )
+
+    def test_a_stream_whose_total_is_the_buckets_last_position(
+            self, decode_api, long_lm):
+        """A runs to the model's 64th position while B, admitted when A
+        is near its end, takes its prompt in chunks beside it."""
+        server, _, _ = decode_api
+        eng = server.serving.decode
+        chunk = self._chunk()
+        prompt_a, prompt_b = [4, 9, 2, 6], list(range(1, 2 * chunk + 4))
+        new_b = 64 - len(prompt_b) - 2
+        try:
+            faults.arm("serve.decode_step", "delay", delay_ms=10,
+                       max_triggers=4096)
+            a = eng.generate("lm_long", prompt_a, max_new_tokens=60,
+                             stream=True)
+            deadline = time.monotonic() + 60
+            while len(a.tokens) < 52 and time.monotonic() < deadline:
+                time.sleep(0.002)
+            assert not a.done(), "stream A ended before B was admitted"
+            b = eng.generate("lm_long", prompt_b, max_new_tokens=new_b,
+                             stream=True)
+            assert a.wait_done(60) and a.error is None
+            assert b.wait_done(60) and b.error is None
+        finally:
+            faults.reset()
+        assert a.total == 64 and len(a.tokens) == 60
+        assert prompt_a + a.tokens == self._solo(long_lm, prompt_a, 60)
+        assert prompt_b + b.tokens == self._solo(long_lm, prompt_b, new_b)
+
+    def test_a_slot_aborted_mid_prompt_is_seated_anew_with_a_step_in_flight(
+            self, decode_api, long_lm, monkeypatch):
+        """A is aborted with its prompt's first chunk taken and the
+        second not; B is seated in the slot A left while C's step is in
+        flight: B and C decode their solo results."""
+        server, _, _ = decode_api
+        eng = server.serving.decode
+        decoder = eng._decoder_for("lm_long")
+        chunk = self._chunk()
+        rng = np.random.default_rng(11)
+        prompt_c = rng.integers(1, 32, 5).tolist()
+        prompt_a = rng.integers(1, 32, 3 * chunk + 2).tolist()
+        prompt_b = rng.integers(1, 32, chunk + 3).tolist()
+        in_flight_at_admit = {}
+        real_admit = decoder._admit
+
+        def admit(stream):
+            in_flight_at_admit[stream.stream_id] = any(
+                p.unread is not None for p in decoder._pools.values()
+            )
+            return real_admit(stream)
+
+        monkeypatch.setattr(decoder, "_admit", admit)
+        started_at = time.monotonic()
+        try:
+            faults.arm("serve.decode_step", "delay", delay_ms=40,
+                       max_triggers=4096)
+            c = eng.generate("lm_long", prompt_c, max_new_tokens=40,
+                             stream=True)
+            a = eng.generate("lm_long", prompt_a, max_new_tokens=8,
+                             stream=True)
+            deadline = time.monotonic() + 30
+            while time.monotonic() < deadline:
+                pool = decoder._pools.get((None, 64))
+                slots = [] if pool is None else [
+                    i for i, s in enumerate(pool.streams) if s is a
+                ]
+                if slots and 0 < pool.pos[slots[0]] < a.t0:
+                    break
+                time.sleep(0.001)
+            assert slots and 0 < pool.pos[slots[0]] < a.t0, \
+                "stream A was not caught mid-prompt"
+            assert eng.abort("lm_long", a.stream_id)
+            # swept at the next turn's step boundary: its slot is free
+            assert a.wait_done(10)
+            out = eng.generate("lm_long", [prompt_b], max_new_tokens=30)
+            assert c.wait_done(60) and c.error is None
+        finally:
+            faults.reset()
+        assert a.done() and a.token.cancelled() and not a.tokens
+        assert out["tokens"][0] == self._solo(long_lm, prompt_b, 30)
+        assert prompt_c + c.tokens == self._solo(long_lm, prompt_c, 40)
+        seats = {
+            e["stream"]: e["slot"]
+            for e in obs_flight.snapshot(["decode"])["events"]["decode"]
+            if e["kind"] == "admit" and e["t"] >= started_at
+        }
+        b_id = out["streams"][0]["stream"]
+        assert seats[b_id] == seats[a.stream_id]
+        assert in_flight_at_admit[b_id], "no step in flight at B's admit"
+
+    def test_a_pool_with_no_prompt_pending_runs_the_one_token_program(
+            self, decode_api, long_lm, step_annotations):
+        """A one-position prompt has nothing to chunk; a longer one
+        runs the chunk program for its prompt's steps and the one-token
+        program for every step after.  ``stats()`` and the
+        ``lo:decode.step`` annotation carry both counts."""
+        server, _, _ = decode_api
+        eng = server.serving.decode
+        decoder = eng._decoder_for("lm_long")
+        chunk = self._chunk()
+        before = self._stats(eng)
+        out = eng.generate("lm_long", [[9]], max_new_tokens=40)
+        assert out["tokens"][0] == self._solo(long_lm, [9], 40)
+        mid = self._stats(eng)
+        assert mid["steps"] - before["steps"] == 40
+        assert mid["chunkSteps"] == before["chunkSteps"]
+        assert mid["promptPositions"] == before["promptPositions"]
+        prompt = list(range(1, 2 * chunk + 6))
+        out = eng.generate("lm_long", [prompt], max_new_tokens=12)
+        assert out["tokens"][0] == self._solo(long_lm, prompt, 12)
+        after = self._stats(eng)
+        assert after["chunkSteps"] - mid["chunkSteps"] == 3
+        assert after["steps"] - mid["steps"] == 3 + 11
+        assert after["promptPositions"] - mid["promptPositions"] \
+            == len(prompt) - 1
+        assert after["slotSteps"]["prompt"] - mid["slotSteps"]["prompt"] == 3
+        programs = {cell[2] for cell in decoder._step_state}
+        assert programs == {1, chunk}
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline and not (
+            step_annotations and not step_annotations[-1]["slots"]
+        ):
+            time.sleep(0.01)  # the turn that drains the pool closes last
+        stepped = [md for md in step_annotations if md["slots"]]
+        assert len(stepped) == 40 + 14
+        assert [md["chunk"] for md in stepped] == [0] * 40 + [1] * 3 + [0] * 11
+        assert [md["prompt_positions"] for md in stepped[40:44]] \
+            == [chunk, chunk, 4, 0]
+        assert [md["prompt"] for md in stepped[40:44]] == [1, 1, 1, 0]
+        assert stepped[42]["keys"] == len(prompt)
+
+    @pytest.mark.parametrize("kind", ["latent", "retention", "block"])
+    def test_other_caches_keep_their_programs(self, decode_api, kind):
+        """Latent pages, retained states and block pools are stepped by
+        the programs they had: width 1 read from their own cache, no
+        chunk program built, a fixed prompt's step count what it was."""
+        import importlib
+
+        from learningorchestra_tpu.serve.decode.pages import chunk_width
+
+        server, _, _ = decode_api
+        eng = server.serving.decode
+        module = importlib.import_module({
+            "latent": "tests.test_kimi_decode",
+            "retention": "tests.test_retention_decode",
+            "block": "tests.test_block_diffusion",
+        }[kind])
+        est = module._estimator()
+        assert chunk_width(est.module) == 1
+        name = f"other_{kind}"
+        module._publish(server, name, est)
+        prompt, new = [5, 9, 2, 7, 3, 8, 1, 4, 6], 7
+        out = eng.generate(name, [prompt], max_new_tokens=new)
+        assert len(out["newTokens"][0]) == new
+        st = eng.stats()["models"][name]
+        assert st["chunkSteps"] == 0
+        decoder = eng._decoder_for(name)
+        assert {pool.chunk for pool in decoder._pools.values()} == {1}
+        assert {cell[2] for cell in decoder._step_state} == {1}
+        if kind != "block":  # one prompt token a step, as before
+            assert st["steps"] == len(prompt) - 1 + new
+            assert st["slotSteps"] == {"prompt": len(prompt) - 1,
+                                       "output": new}
+            assert st["promptPositions"] == len(prompt) - 1
+        eng.drop_model(name)
+
+
+@pytest.mark.parametrize("kind", ["dense", "latent", "retention", "block"])
+def test_cache_shapes_are_one_slots_scaled_and_traced_once(
+        kind, monkeypatch):
+    """``pages.cache_shapes`` traces a model's init once a length (the
+    seconds that cost at a published depth were a pool's at every slot
+    bucket and program) and scales the slots' axis: the tree a direct
+    ``eval_shape`` at those slots gives, for every kind of cache."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from learningorchestra_tpu.models.text import DecoderLM
+    from learningorchestra_tpu.serve.decode import pages
+
+    if kind == "dense":
+        module = DecoderLM(vocab_size=16, hidden_dim=32, num_layers=2,
+                           num_heads=4, max_len=64).module
+    else:
+        module = importlib.import_module({
+            "latent": "tests.test_kimi_decode",
+            "retention": "tests.test_retention_decode",
+            "block": "tests.test_block_diffusion",
+        }[kind])._estimator().module
+    direct = pages.strip_index(jax.eval_shape(
+        module.clone(decode=True).init, jax.random.PRNGKey(0),
+        jnp.zeros((4, 32), jnp.int32),
+    )["cache"])
+    monkeypatch.setattr(pages, "_ONE_SLOT", {})
+    traced = []
+    real = jax.eval_shape
+    monkeypatch.setattr(
+        jax, "eval_shape",
+        lambda *a, **kw: traced.append(1) or real(*a, **kw),
+    )
+    pages.cache_shapes(module, 1, 32)
+    first = len(traced)  # the init's own nested calls among them
+    for nslots in (2, 4):
+        got = pages.cache_shapes(module, nslots, 32)
+    assert len(traced) == first > 0 and len(pages._ONE_SLOT) == 1
+    assert jax.tree_util.tree_structure(got) \
+        == jax.tree_util.tree_structure(direct)
+    for mine, theirs in zip(jax.tree_util.tree_leaves(got),
+                            jax.tree_util.tree_leaves(direct)):
+        assert (mine.shape, mine.dtype) == (theirs.shape, theirs.dtype)
+    assert pages.chunk_width(module) == (
+        pages.PROMPT_CHUNK if kind == "dense" else 1
+    )
 
 
 class TestDecodeSLO:

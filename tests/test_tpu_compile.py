@@ -87,20 +87,29 @@ def _assert_one_pass_over_the_pages(text, pages, layers):
     assert makers <= {"parameter", "get-tuple-element"}, makers
 
 
+@pytest.mark.parametrize("chunk", [False, True], ids=["token", "chunk"])
 @pytest.mark.parametrize("slots", [1, 2, 4, 8])
 def test_decode_step_is_one_pass_over_the_pages_at_gpt2_xl_widths(
-        slots, one_chip, monkeypatch):
-    """The engine's step program of a next-token model, two layers at
-    GPT-2 XL's widths over a 512 bucket, at every slot bucket."""
+        slots, chunk, one_chip, monkeypatch):
+    """The engine's step programs of a next-token model, two layers at
+    GPT-2 XL's widths over a 512 bucket, at every slot bucket: the
+    one-token program and the prompt-chunk program (``chunk_width``
+    positions a slot), each donated, pages and buffer aliased, one
+    ``decode_attend`` a layer, no page-shaped copy."""
     from learningorchestra_tpu.models.text import _DecoderLM
-    from learningorchestra_tpu.serve.decode.pages import build_step
+    from learningorchestra_tpu.serve.decode.pages import (
+        PROMPT_CHUNK, build_step, chunk_width,
+    )
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     module = _DecoderLM(
         vocab_size=50257, hidden_dim=1600, num_layers=2, num_heads=25,
         mlp_dim=6400, max_len=1024,
     )
-    step, pages = build_step(module, slots, 512)
+    assert chunk_width(module) == PROMPT_CHUNK > 1
+    step, pages = build_step(
+        module, slots, 512, chunk_width(module) if chunk else 1
+    )
     variables = jax.eval_shape(
         module.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
     )
