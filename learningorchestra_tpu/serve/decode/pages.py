@@ -14,8 +14,9 @@ The continuous-batching trick is the per-row ``cache_index``: the
 attention decode branch (ops/layers.py) accepts a (S,)-shaped index,
 so slots sit at DIFFERENT sequence positions inside one jitted step —
 a newly admitted prompt starts its prefill (a chunk of positions a
-step where every cache layer is K/V pages, :func:`chunk_width`; else
-one token a step) in the same dispatch that extends its neighbours.
+step where every cache layer is K/V pages or every one a recurrent
+state, :func:`chunk_width`; else one token a step) in the same
+dispatch that extends its neighbours.
 Freed slots are simply
 zeroed in the token buffer: an all-pad row masks to an exact-zero
 attention output (the masked-softmax double-where), so stale KV pages
@@ -57,30 +58,36 @@ PAGE_LEAVES = ("cached_key", "cached_latent")
 STATE_LEAVES = ("retained_state",)
 
 
-def set_index(pages, pos):
+def set_index(pages, pos, rows=None):
     """The decode cache tree the module applies on: ``pages`` (the
     leaves a pool carries) with a ``cache_index`` beside every layer's
     page or state leaf (:data:`PAGE_LEAVES`, :data:`STATE_LEAVES`),
     each the per-slot position vector ``pos`` (S,) — the step's single
     source of truth for where each slot writes, how far it may attend
-    and whether its state begins anew."""
+    and whether its state begins anew.  ``rows`` (S,), the positions of
+    a chunk each slot takes, goes beside every state leaf as
+    ``chunk_rows``: a state layer takes those rows into its state and
+    no further (a page layer writes every row and needs no count)."""
     out = {
-        key: set_index(val, pos) if isinstance(val, dict) else val
+        key: set_index(val, pos, rows) if isinstance(val, dict) else val
         for key, val in pages.items()
     }
     if any(name in pages for name in PAGE_LEAVES + STATE_LEAVES):
         out["cache_index"] = pos
+    if rows is not None and any(name in pages for name in STATE_LEAVES):
+        out["chunk_rows"] = rows
     return out
 
 
 def strip_index(cache):
-    """``cache`` without its ``cache_index`` leaves: what a pool
+    """``cache`` without what :func:`set_index` put in it: what a pool
     carries from step to step.  The step sets every index from ``pos``
     before anything reads one, so carrying them would only be one more
     argument and one more output a layer."""
     return {
         key: strip_index(val) if isinstance(val, dict) else val
-        for key, val in cache.items() if key != "cache_index"
+        for key, val in cache.items()
+        if key not in ("cache_index", "chunk_rows")
     }
 
 
@@ -192,26 +199,45 @@ def step_width(module) -> int:
 #: Measured 11.19, 11.39, 14.18, 21.00 ms against 11.94 (``PERF.md``
 #: section 6, PR 37): 8 passes, 16 does not.
 PROMPT_CHUNK = 8
+#: Prompt positions a slot takes in ONE step of a pool whose every
+#: cache layer is a recurrent state (:func:`chunk_width`): its layers
+#: take the chunk in one update of each state (``ops/retention.py``
+#: ``retention_step``), so the state is read and written once a step
+#: whatever the width, and only the slots that prefill pay for the
+#: chunk's rows against it.  Chosen by the same rule as
+#: :data:`PROMPT_CHUNK` at Brumby-14B's widths, 7 layers, 16 slots over
+#: the 1,024-position buffer, 2 of them prefilling
+#: (``scripts/chip_chunk_sweep.py retention``): 20.86, 20.98, 22.54,
+#: 32.34 ms at 4, 8, 16, 32 against 20.62 on a v5e (``PERF.md``
+#: section 6): 8 passes, 16 does not (the weights' matmuls at
+#: 256 rows leave their memory bound).
+STATE_CHUNK = 8
 
 
 def chunk_width(module) -> int:
-    """Prompt positions a slot of ``module`` may take in one step:
-    :data:`PROMPT_CHUNK` where every cache layer is K/V pages of
+    """Prompt positions a slot of ``module`` may take in one step, read
+    from the model's own cache, as pages or states is
+    (:func:`keyed_by_length`), where the model produces one token a
+    step: :data:`PROMPT_CHUNK` where every cache layer is K/V pages of
     ``MultiHeadSelfAttention`` (whose decode branch takes a causal
-    chunk at a per-row index and writes its rows in one pass) and the
-    model produces one token a step; else 1.  Read from the model's own
-    cache, as pages or states is (:func:`keyed_by_length`): a latent
-    layer's chunk wants its non-absorbed form, a retention layer's a
-    chunked scan, a block pool's prefill is whole blocks already."""
+    chunk at a per-row index and writes its rows in one pass);
+    :data:`STATE_CHUNK` where every one is a recurrent state (whose
+    decode branch takes the chunk in one update of the state); else 1:
+    a latent layer's chunk wants its non-absorbed form, a block pool's
+    prefill is whole blocks already."""
     if step_width(module) > 1:
         return 1
     cache = cache_shapes(module, 1, 8)
-    others = tuple(
-        name for name in PAGE_LEAVES + STATE_LEAVES if name != "cached_key"
-    )
-    every = first_pages(cache, ("cached_key",)) is not None \
-        and first_pages(cache, others) is None
-    return PROMPT_CHUNK if every else 1
+
+    def only(names):
+        others = tuple(n for n in PAGE_LEAVES + STATE_LEAVES
+                       if n not in names)
+        return first_pages(cache, names) is not None \
+            and first_pages(cache, others) is None
+
+    if only(("cached_key",)):
+        return PROMPT_CHUNK
+    return STATE_CHUNK if only(STATE_LEAVES) else 1
 
 
 def step_donates(module) -> tuple:
@@ -251,7 +277,9 @@ def build_step(module, nslots: int, kv: int, chunk: int = 1):
     Every slot carries C rows: their tokens gathered from the buffer
     at ``pos + arange(C)``, the layer's own causal in-chunk mask under
     the ``buf != 0`` key mask, their K/V written in place by the
-    layer's one ``cached_attend``.  The slot's next token is the argmax
+    layer's one ``cached_attend``; a retention layer is handed ``n``
+    (:func:`set_index`) and takes those rows into its state in one
+    update.  The slot's next token is the argmax
     of the float32 logits of row ``n-1``, written at ``pos+n`` under
     the one-token rule.  Rows ``n .. C-1`` are dead weight: what they
     write lies beyond the slot's position, where no query may look
@@ -424,7 +452,7 @@ def build_step(module, nslots: int, kv: int, chunk: int = 1):
         )
         tok = jnp.take_along_axis(buf, lanes, axis=1)
         logits, mut = decode_mod.apply(
-            {**variables, "cache": set_index(cache, pos)}, tok,
+            {**variables, "cache": set_index(cache, pos, n)}, tok,
             positions=lanes, key_mask=buf != 0,
             mutable=["cache", "moe_stats"],
         )

@@ -268,6 +268,98 @@ def _retention_case(slots: int, heads: int, group: int, dim: int) -> dict:
     }
 
 
+def _retention_chunk_case(slots: int, heads: int, group: int, dim: int,
+                          width: int) -> dict:
+    """A chunk of ``width`` positions through ``slots`` slots' states
+    in one update at the published shapes against the plain chunk form
+    at ``highest`` precision: slots that take the whole chunk (one of
+    them begun anew), that take 3 positions, that decode (one), that
+    sit out.  Then the kernel alone is timed, as the one-token case is,
+    with every slot live and 0, 1, 2, 4 or all of them taking the whole
+    chunk, the others one position; beside it the one-token kernel at
+    the same slots."""
+    import time
+
+    rng = np.random.default_rng(slots * 11 + width)
+
+    def draw(*shape, scale=1.0):
+        return jnp.asarray(scale * rng.standard_normal(shape), jnp.float32)
+
+    s_shape, z_shape = retention.state_shapes(slots, heads, dim, dim)
+    state, norm = draw(*s_shape), draw(*z_shape)
+    q = draw(slots, heads, group, width, dim).astype(jnp.bfloat16)
+    k = draw(slots, heads, width, dim).astype(jnp.bfloat16)
+    v = draw(slots, heads, width, dim)
+    log_g = jnp.log(jnp.asarray(
+        rng.uniform(0.9, 1.0, (slots, heads, width)), jnp.float32))
+
+    def plan(n):
+        n = jnp.asarray(n, jnp.int32)
+        took = jnp.arange(width)[None, :] < n[:, None]
+        return (jnp.where(took[:, None, :], log_g, 0.0), n)
+
+    n = np.ones(slots, np.int32)
+    n[: max(1, slots // 4)] = width
+    n[slots // 2] = 3
+    n[slots - 1] = 0
+    fresh = np.zeros(slots, bool)
+    fresh[[0, slots // 2]] = True
+
+    def run(fn):
+        def chunk(state, norm, log_g, n, fresh):
+            return fn(state, norm, q, k, v, log_g, n, fresh)
+        return jax.jit(chunk, donate_argnums=(0, 1))
+
+    args = (*plan(n), jnp.asarray(fresh))
+    ref = run(retention.plain_retention_chunk)(state + 0, norm + 0, *args)
+    kernel = run(retention.retention_chunk_kernel)
+    got = kernel(state + 0, norm + 0, *args)
+    scale = max(1.0, float(jnp.max(jnp.abs(ref[0]))))
+    errs = {
+        name: _max_err(r, o) / (scale if name in ("num", "den") else 1.0)
+        for name, r, o in zip(("num", "den", "state", "norm"), ref, got)
+    }
+    off = n == 0
+    dead_same = bool(
+        jnp.array_equal(got[2][off], state[off])
+        and jnp.array_equal(got[3][off], norm[off])
+    )
+    moved = 2.0 * slots * heads * retention.state_rows(dim) * (dim + 1) * 4
+    timed, none = {}, jnp.zeros(slots, bool)
+    carried = got
+    for taking in sorted({0, 1, 2, 4, slots}):
+        n_t = np.where(np.arange(slots) < taking, width, 1)
+        args = (*plan(n_t), none)
+        carried = kernel(carried[2], carried[3], *args)
+        jax.block_until_ready(carried)
+        t0 = time.perf_counter()
+        for _ in range(10):
+            carried = kernel(carried[2], carried[3], *args)
+        jax.block_until_ready(carried)
+        timed[taking] = 1e3 * (time.perf_counter() - t0) / 10
+    one = jax.jit(
+        lambda state, norm, live: retention.retention_step_kernel(
+            state, norm, retention.feature_map(q[:, :, :, 0]),
+            retention.feature_map(k[:, :, 0]), v[:, :, 0],
+            jnp.exp(log_g[:, :, 0]), live, none),
+        donate_argnums=(0, 1))
+    on = jnp.ones(slots, bool)
+    carried = one(carried[2], carried[3], on)
+    jax.block_until_ready(carried)
+    t0 = time.perf_counter()
+    for _ in range(10):
+        carried = one(carried[2], carried[3], on)
+    jax.block_until_ready(carried)
+    one_ms = 1e3 * (time.perf_counter() - t0) / 10
+    return {
+        "errs": errs, "dead_states_equal": dead_same,
+        "one_token_ms": one_ms,
+        "chunk_ms_by_slots_taking_it": timed,
+        "least_gb_per_s_all_one": moved / timed[0] * 1e-6,
+        "ok": dead_same and all(e < 1e-4 for e in errs.values()),
+    }
+
+
 def main() -> int:
     only = sys.argv[1] if len(sys.argv) > 1 else ""
     dev = jax.devices()[0]
@@ -303,6 +395,11 @@ def main() -> int:
                   lambda: _retention_case(16, 8, 5, 128)))
     cases.append(("retention_step:2slots:8x5h:8256x128:f32",
                   lambda: _retention_case(2, 8, 5, 128)))
+    for width in (8, 16):
+        cases.append((
+            f"retention_step:chunk{width}:16slots:8x5h:8256x128:f32",
+            lambda width=width: _retention_chunk_case(16, 8, 5, 128, width),
+        ))
     if jax.device_count() >= 4:
         # chip_smoke.py's multi-chip phase owns the ring-flash check
         # (it raises on a miss).

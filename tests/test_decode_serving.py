@@ -1228,11 +1228,43 @@ class TestPromptChunks:
         assert [md["prompt"] for md in stepped[40:44]] == [1, 1, 1, 0]
         assert stepped[42]["keys"] == len(prompt)
 
-    @pytest.mark.parametrize("kind", ["latent", "retention", "block"])
+    def test_a_retention_pool_takes_its_prompt_a_chunk_a_step(
+            self, decode_api):
+        """Retained states are stepped by both programs too, at their
+        own width read from their own cache: a 9-position prompt takes
+        one chunk step of ``STATE_CHUNK`` positions and one step that
+        brings its first token, not 8 one-token steps, with the solo
+        decode's tokens."""
+        from learningorchestra_tpu.serve.decode.pages import (
+            PROMPT_CHUNK, STATE_CHUNK, chunk_width,
+        )
+        from tests import test_retention_decode as module
+
+        server, _, _ = decode_api
+        eng = server.serving.decode
+        est = module._estimator()
+        assert chunk_width(est.module) == STATE_CHUNK == 8
+        assert PROMPT_CHUNK == 8
+        module._publish(server, "other_retention", est)
+        prompt, new = [5, 9, 2, 7, 3, 8, 1, 4, 6], 7
+        out = eng.generate("other_retention", [prompt], max_new_tokens=new)
+        assert out["tokens"][0] == self._solo(est, prompt, new)
+        st = eng.stats()["models"]["other_retention"]
+        assert st["chunkSteps"] == 1
+        assert st["steps"] == 1 + 1 + new - 1
+        assert st["slotSteps"] == {"prompt": 1, "output": new}
+        assert st["promptPositions"] == len(prompt) - 1
+        decoder = eng._decoder_for("other_retention")
+        assert {pool.chunk for pool in decoder._pools.values()} \
+            == {STATE_CHUNK}
+        assert {cell[2] for cell in decoder._step_state} == {1, STATE_CHUNK}
+        eng.drop_model("other_retention")
+
+    @pytest.mark.parametrize("kind", ["latent", "block"])
     def test_other_caches_keep_their_programs(self, decode_api, kind):
-        """Latent pages, retained states and block pools are stepped by
-        the programs they had: width 1 read from their own cache, no
-        chunk program built, a fixed prompt's step count what it was."""
+        """Latent pages and block pools are stepped by the programs they
+        had: width 1 read from their own cache, no chunk program built,
+        a fixed prompt's step count what it was."""
         import importlib
 
         from learningorchestra_tpu.serve.decode.pages import chunk_width
@@ -1241,7 +1273,6 @@ class TestPromptChunks:
         eng = server.serving.decode
         module = importlib.import_module({
             "latent": "tests.test_kimi_decode",
-            "retention": "tests.test_retention_decode",
             "block": "tests.test_block_diffusion",
         }[kind])
         est = module._estimator()
@@ -1309,9 +1340,9 @@ def test_cache_shapes_are_one_slots_scaled_and_traced_once(
     for mine, theirs in zip(jax.tree_util.tree_leaves(got),
                             jax.tree_util.tree_leaves(direct)):
         assert (mine.shape, mine.dtype) == (theirs.shape, theirs.dtype)
-    assert pages.chunk_width(module) == (
-        pages.PROMPT_CHUNK if kind == "dense" else 1
-    )
+    assert pages.chunk_width(module) == {
+        "dense": pages.PROMPT_CHUNK, "retention": pages.STATE_CHUNK,
+    }.get(kind, 1)
 
 
 class TestDecodeSLO:

@@ -2,9 +2,11 @@
 widths on the CPU, float32, against the plain reference of
 ``tests/retention_oracle.py`` (the attention form, no state): the full
 forward, the recurrence against the masked form, the kernel against
-the plain step, and the decode engine serving every length from ONE
-pool of states: prefill then decode, slots admitted mid-flight, a slot
-reused, a pool grown."""
+the plain step, a chunk of positions in one update against its
+positions one at a time (plain form, kernel, layer), and the decode
+engine serving every length from ONE pool of states: prefill a chunk a
+step then decode, slots admitted mid-flight, a slot reused, a pool
+grown."""
 
 import functools
 import json
@@ -205,6 +207,156 @@ def test_kernel_is_the_plain_step(live, fresh):
     assert not np.asarray(got[0])[dead].any()
 
 
+#: A chunk of 6 rows over 4 slots: each case the rows every slot takes
+#: (0: it sits the step out) and which slots begin anew.
+CHUNK = 6
+CHUNK_CASES = {
+    "one": ([1, 1, 1, 1], [0, 0, 0, 0]),
+    "three": ([3, 3, 1, 3], [0, 0, 0, 0]),
+    "whole": ([CHUNK] * 4, [0, 0, 0, 0]),
+    "fresh": ([CHUNK, 3, 1, CHUNK], [1, 0, 1, 0]),
+    "dead": ([0, CHUNK, 0, 3], [1, 0, 0, 0]),
+    # a row's key not seen (a pad token inside a prompt): it decays
+    "unseen": ([CHUNK, 3, CHUNK, 1], [0, 0, 0, 0]),
+}
+
+
+def _chunk_args(case, b=4, h=2, g=3, d=16):
+    """(state, norm, q, k, v, log_g, n, fresh) of a chunk case, as
+    ``retention_step`` hands them over: a key that may not be seen is
+    zero, a row not taken has ``log_g`` 0."""
+    from learningorchestra_tpu.ops import retention
+
+    rng = np.random.default_rng(17)
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    s_shape, z_shape = retention.state_shapes(b, h, d, d)
+    n, fresh = (np.asarray(a) for a in CHUNK_CASES[case])
+    k = np.array(draw(b, h, CHUNK, d))
+    if case == "unseen":
+        k[0, :, 2] = k[2, :, 0] = 0.0
+    log_g = np.log(rng.uniform(0.5, 1.0, (b, h, CHUNK)))
+    log_g = np.where((np.arange(CHUNK)[None, :] < n[:, None])[:, None],
+                     log_g, 0.0)
+    return (draw(*s_shape), draw(*z_shape), draw(b, h, g, CHUNK, d),
+            jnp.asarray(k), draw(b, h, CHUNK, d),
+            jnp.asarray(log_g, jnp.float32), jnp.asarray(n, jnp.int32),
+            jnp.asarray(fresh, bool))
+
+
+def _close(got, want, tol):
+    scale = max(1.0, float(np.abs(np.asarray(want)).max(initial=0.0)))
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol * scale)
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_a_chunk_is_its_positions_one_step_at_a_time(case):
+    """``plain_retention_chunk``: the read-out of every row a slot takes
+    and the state after the chunk are what ``n`` calls of
+    ``plain_retention_step`` give, one position each, to 1e-5; a row
+    not taken reads zeros, a slot that takes none keeps its state bit
+    for bit."""
+    from learningorchestra_tpu.ops import retention
+
+    state, norm, q, k, v, log_g, n, fresh = _chunk_args(case)
+    num, den, s_end, z_end = retention.plain_retention_chunk(
+        state, norm, q, k, v, log_g, n, fresh)
+    s, z = state, norm
+    for r in range(CHUNK):
+        live = r < n
+        step = retention.plain_retention_step(
+            s, z, retention.feature_map(q[:, :, :, r]),
+            retention.feature_map(k[:, :, r]),
+            v[:, :, r], jnp.exp(log_g[:, :, r]), live, fresh & (r == 0))
+        s, z = step[2], step[3]
+        took = np.asarray(live)
+        _close(num[:, :, :, r][took], step[0][took], 1e-5)
+        _close(den[:, :, :, r][took], step[1][took], 1e-5)
+        assert not np.asarray(num[:, :, :, r])[~took].any()
+        assert not np.asarray(den[:, :, :, r])[~took].any()
+    _close(s_end, s, 1e-5)
+    _close(z_end, z, 1e-5)
+    off = np.asarray(n) == 0
+    assert np.array_equal(np.asarray(s_end)[off], np.asarray(state)[off])
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_chunk_kernel_is_the_plain_chunk(case):
+    """``retention_chunk_kernel`` (interpreted) against the plain chunk
+    form over the same cases: a slot that takes one position goes
+    through the one-position path, one that takes several through the
+    matrix-unit path, a dead one's state comes back bit for bit."""
+    from learningorchestra_tpu.ops import retention
+
+    args = _chunk_args(case)
+    want = retention.plain_retention_chunk(*args)
+    got = retention.retention_chunk_kernel(*args, interpret=True)
+    for g, w in zip(got, want):
+        _close(g, w, 1e-5)
+    off = np.asarray(args[6]) == 0
+    assert np.array_equal(np.asarray(got[2])[off], np.asarray(args[0])[off])
+    assert np.array_equal(np.asarray(got[3])[off], np.asarray(args[1])[off])
+
+
+def test_a_chunk_through_the_layer_takes_what_its_mask_shows():
+    """``PowerRetention``'s decode branch fed 6 positions a row at once
+    gives, on every row a slot takes, what 6 one-position calls give:
+    each row takes the ``chunk_rows`` the step program hands it (a
+    prompt row the whole chunk, where a pad token inside it decays the
+    state and adds no key; a decoding row its one token; a row near the
+    buffer's end the positions inside it), a row with no key none."""
+    from learningorchestra_tpu.ops.retention import PowerRetention
+
+    layer = PowerRetention(num_heads=4, num_kv_heads=2, head_dim=16,
+                           rope_theta=1e6, decode=True)
+    rng = np.random.default_rng(23)
+    b, kv = 4, 24
+    x = jnp.asarray(rng.standard_normal((b, CHUNK, 32)), jnp.float32)
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    params = jax.tree_util.tree_map(jnp.asarray, variables["params"])
+    params["gate"]["bias"] = params["gate"]["bias"] + 2.0
+    cache = dict(variables["cache"])
+    cache["retained_state"] = jnp.asarray(rng.standard_normal(
+        cache["retained_state"].shape), jnp.float32)
+    cache["retained_norm"] = jnp.asarray(rng.standard_normal(
+        cache["retained_norm"].shape), jnp.float32)
+    at = np.array([0, 5, 20, 7], np.int32)
+    mask = np.zeros((b, kv), bool)
+    mask[0, :9] = True
+    mask[0, 3] = False  # a pad token inside the prompt
+    mask[1, :6] = True  # decoding: its last token at 5
+    mask[2, :] = True  # rows 24, 25 lie past the buffer
+    # the engine's n: a slot with no key is handed 1 and takes none
+    handed, taken = np.array([CHUNK, 1, 4, 1]), np.array([CHUNK, 1, 4, 0])
+
+    def call(cache, x, index, key_mask):
+        out, mut = layer.apply(
+            {"params": params, "cache": {**cache, "cache_index": index}},
+            x, key_mask=key_mask, mutable=["cache"])
+        return out, mut["cache"]
+
+    got, after = call({**cache, "chunk_rows": jnp.asarray(handed)}, x,
+                      jnp.asarray(at), jnp.asarray(mask))
+    assert np.asarray(after["cache_index"]).tolist() == (at + taken).tolist()
+    one = cache
+    for r in range(CHUNK):
+        # one position a row as the one-token program hands it over; a
+        # row past what it takes sits out with an empty mask
+        seen = (np.arange(kv)[None, :] <= (at + r)[:, None]) & mask
+        seen[r >= taken] = False
+        want, one = call(one, x[:, r:r + 1], jnp.asarray(at + r),
+                         jnp.asarray(seen))
+        rows = r < taken
+        _close(got[rows, r], want[rows, 0], 1e-5)
+        assert not np.asarray(got[~rows, r]).any()
+    for leaf in ("retained_state", "retained_norm"):
+        _close(after[leaf], one[leaf], 1e-5)
+    assert np.array_equal(after["retained_state"][3],
+                          cache["retained_state"][3])
+
+
 def test_solo_decode_through_the_state_is_the_full_forward(est):
     prompt = [5, 6, 7, 8, 9]
     got = np.asarray(est.generate(np.array([prompt], np.int32),
@@ -303,8 +455,9 @@ def test_five_lengths_share_one_pool_and_every_token_is_the_references(
         api, est):
     """Totals of 9 to 200 tokens (three KV buckets of an attention
     model) admitted at different turns: one pool, keyed by no length;
-    prefill a token a step, then decode; every served token's logit is
-    the reference's best to 1e-4."""
+    prefill a chunk of positions a step beside slots that decode, then
+    decode; every served token's logit is the reference's best to
+    1e-4."""
     server, base = api
     rng = np.random.default_rng(11)
     shapes = [(3, 6), (40, 17), (9, 110), (120, 60), (180, 20)]
@@ -333,7 +486,10 @@ def test_five_lengths_share_one_pool_and_every_token_is_the_references(
     assert pool["pageBytes"] == pool["slots"] * STATE_BYTES
     assert stats["stepsInPlace"] == stats["steps"] > 0
     assert stats["stateResets"] >= len(shapes)
-    assert stats["slotSteps"]["prompt"] >= sum(p - 1 for p, _ in shapes)
+    # the prompts went through the state a chunk of positions a step
+    assert stats["chunkSteps"] > 0
+    assert stats["promptPositions"] >= sum(p - 1 for p, _ in shapes)
+    assert stats["promptPositions"] > 1.5 * stats["slotSteps"]["prompt"]
 
 
 def test_a_reused_slot_begins_from_zero(api, est):
@@ -358,14 +514,14 @@ def test_without_the_reset_a_reused_slot_reads_other_tokens(
     from learningorchestra_tpu.ops import retention
     from learningorchestra_tpu.train import compile_cache
 
-    plain = retention.plain_retention_step
+    real = retention.retention_step
 
-    def never_fresh(state, norm, fq, fk, v, g, live, fresh):
-        return plain(state, norm, fq, fk, v, g, live,
-                     jnp.zeros_like(fresh))
+    def never_fresh(*args):
+        *args, fresh = args
+        return real(*args, jnp.zeros_like(fresh))
 
     server, base = api
-    monkeypatch.setattr(retention, "plain_retention_step", never_fresh)
+    monkeypatch.setattr(retention, "retention_step", never_fresh)
     server.serving.decode.drop_model("ret")
     compile_cache.get_cache().clear()
     try:

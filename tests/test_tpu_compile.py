@@ -312,3 +312,49 @@ def test_retention_step_updates_the_states_in_place_at_brumby_widths(
     for scope in ("retention_proj", "retention_gate", "retention_step",
                   "retention_out"):
         assert scope in text, scope
+
+
+@pytest.mark.parametrize("slots", [2, 16])
+def test_retention_chunk_program_updates_the_states_in_place(
+        slots, one_chip, monkeypatch):
+    """The prompt-chunk program of ``RetentionLM`` (``STATE_CHUNK``
+    positions a slot) at Brumby-14B's widths, two layers, over the
+    1,024-position buffer: every kernel it holds is a ``retention_step``
+    (one a layer: the chunk's work is under the name its roofline reads
+    by), each state leaf and the token buffer aliased, and nothing but
+    that kernel makes an array of a state leaf's shape."""
+    import re
+
+    from learningorchestra_tpu.models.retention import RetentionLM
+    from learningorchestra_tpu.serve.decode.pages import (
+        STATE_CHUNK, build_step, chunk_width,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    est = RetentionLM(
+        vocab_size=151936, hidden_dim=5120, num_layers=2, num_heads=40,
+        num_kv_heads=8, head_dim=128, mlp_dim=17408, rope_theta=1e6,
+        norm_eps=1e-6, max_len=32768,
+    )
+    assert chunk_width(est.module) == STATE_CHUNK > 1
+    step, states = build_step(est.module, slots, 1024, STATE_CHUNK)
+    variables = jax.eval_shape(
+        est.module.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )
+    text = step.program.lower(
+        _on(one_chip, variables), _on(one_chip, states),
+        jax.ShapeDtypeStruct((slots, 1024), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((3, slots), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+    kernels = re.findall(
+        r"%(\S+) = [^=]* custom-call\([^\n]*custom_call_target="
+        r"\"tpu_custom_call\"", text)
+    assert len(kernels) == 2
+    assert all(name.startswith("retention_step") for name in kernels)
+    assert text.split("\n", 1)[0].count("-alias)") >= 5
+    makers = {
+        op for shape, op in re.findall(
+            r"= (\w+\[[\d,]*\])\{[^ ]*\} ([\w-]+)\(", text
+        ) if shape == f"f32[{slots},8,65,128,128]"
+    }
+    assert makers <= {"parameter", "get-tuple-element"}, makers
