@@ -31,6 +31,7 @@ from learningorchestra_tpu.models.moe import (
     MoETransformerClassifier,
 )
 from learningorchestra_tpu.models.retention import RetentionLM
+from learningorchestra_tpu.models.hybrid import HybridLM
 
 __all__ = [
     "MLPClassifier",
@@ -49,4 +50,5 @@ __all__ = [
     "BlockDiffusionMoELM",
     "MoETransformerClassifier",
     "RetentionLM",
+    "HybridLM",
 ]

@@ -183,6 +183,10 @@ class MultiHeadSelfAttention(nn.Module):
     # causal, also inside a decode chunk.
     block: int | None = None
     param_dtype: jnp.dtype = jnp.float32
+    # A factor on every key after its projection (and norm), before the
+    # rotation and the cache: a model that scales its keys (a muP
+    # multiplier).  1 adds nothing to the program.
+    key_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x, key_mask=None):
@@ -230,6 +234,8 @@ class MultiHeadSelfAttention(nn.Module):
                         name="q_norm")(q)
             k = RMSNorm(self.qk_norm_eps, param_dtype=self.param_dtype,
                         name="k_norm")(k)
+        if self.key_scale != 1.0:
+            k = (k.astype(jnp.float32) * self.key_scale).astype(k.dtype)
         is_initialized = self.decode and self.has_variable(
             "cache", "cached_key"
         )

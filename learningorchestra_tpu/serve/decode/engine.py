@@ -180,7 +180,7 @@ class _ModelDecoder:
                       "kv_bytes_per_token": 0, "pools": 0,
                       "state_bytes_per_slot": 0, "state_resets": 0}
         # Slot-steps that began a recurrent state from zero (a request's
-        # first, in a pool of states).
+        # first, in a pool that holds states).
         self.state_resets = 0
         # Generation by diffusion over blocks: slot-steps by phase
         # (whole prompt blocks prefilled, denoising forwards, commits),
@@ -636,7 +636,7 @@ class _ModelDecoder:
         if pool.holds_pages:
             turn["kv"] = max(turn["kv"], pool.kv)
             turn["kv_bytes_per_token"] = pool.token_bytes()
-        else:
+        if pool.holds_states:
             turn["state_bytes_per_slot"] = pool.slot_bytes()
         if ahead:
             self.steps_ahead += 1
@@ -695,7 +695,7 @@ class _ModelDecoder:
             if chunked:
                 self.chunk_steps += 1
                 turn["chunk"] += 1
-            if not pool.holds_pages:
+            if pool.holds_states:
                 resets = int((live & (pos_now == 0)).sum())
                 self.state_resets += resets
                 turn["state_resets"] += resets
@@ -975,7 +975,8 @@ class _ModelDecoder:
             "chunkSteps": self.chunk_steps,
             "pools": pools,
             "poolsLive": sum(1 for pool in pools if pool["live"]),
-            # Slots a step began from a zero state (pools of states).
+            # Slots a step began from a zero state (pools that hold
+            # states).
             "stateResets": self.state_resets,
             # Cumulative, from the worker's own counts (each step, each
             # live slot is one slot-step: prompt while prompt lies

@@ -29,9 +29,13 @@ its requests' lengths, and one step program a slot bucket; the pool's
 not harmless as a stale page is: whatever the slot's last request left
 would decay into the next one's output.  So the layer itself begins a
 slot that stands at position 0 from a zero state, inside the step
-program, from the ``cache_index`` it is handed (``ops/retention.py``):
-no scrub on ``release``, no further host array.  The layer takes a slot
-whose key mask is empty (a free one) to sit the step out.
+program, from the ``cache_index`` it is handed (``ops/retention.py``,
+``ops/ssm.py``): no scrub on ``release``, no further host array.  The
+layer takes a slot whose key mask is empty (a free one) to sit the step
+out.  A model whose layers keep both (a state space mixer beside
+attention, ``models/hybrid.py``) is keyed by length like any model with
+pages, and each of its pools carries the pages and the states side by
+side, one token a step.
 
 The step program OWNS the pool's device state: it consumes the cache
 and the token buffer it is called with (both are donated, XLA updates
@@ -54,8 +58,10 @@ from learningorchestra_tpu.serve.decode import blocks
 PAGE_LEAVES = ("cached_key", "cached_latent")
 #: The leaf a layer's recurrent state is found by: a layer of
 #: ``PowerRetention`` keeps ``retained_state`` (with ``retained_norm``
-#: beside it).  No axis of it is a sequence length.
-STATE_LEAVES = ("retained_state",)
+#: beside it), a ``Mamba2Mixer`` its ``ssm_state`` (with the
+#: convolution's ``conv_tail`` beside it).  No axis of them is a
+#: sequence length.
+STATE_LEAVES = ("retained_state", "ssm_state")
 
 
 def set_index(pages, pos, rows=None):
@@ -112,6 +118,23 @@ def holds_pages(cache) -> bool:
     not, and its pool serves every length."""
     return first_pages(cache, PAGE_LEAVES) is not None \
         or first_pages(cache, STATE_LEAVES) is None
+
+
+def holds_states(cache) -> bool:
+    """Whether a decode cache tree holds a recurrent state a slot, with
+    or without pages beside it."""
+    return first_pages(cache, STATE_LEAVES) is not None
+
+
+def state_bytes(cache) -> int:
+    """Bytes of the leaves that sit beside a state leaf (a state, its
+    normaliser or convolution tail), all layers, of a cache tree."""
+    import jax
+
+    if any(name in cache for name in STATE_LEAVES):
+        return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(cache))
+    return sum(state_bytes(val) for val in cache.values()
+               if isinstance(val, dict))
 
 
 #: (module fingerprint, kv) -> the decode cache's shape tree for ONE
@@ -224,7 +247,8 @@ def chunk_width(module) -> int:
     :data:`STATE_CHUNK` where every one is a recurrent state (whose
     decode branch takes the chunk in one update of the state); else 1:
     a latent layer's chunk wants its non-absorbed form, a block pool's
-    prefill is whole blocks already."""
+    prefill is whole blocks already, and a layer that keeps pages and a
+    state side by side steps its state one position a call."""
     if step_width(module) > 1:
         return 1
     cache = cache_shapes(module, 1, 8)
@@ -501,7 +525,7 @@ class PagePool:
     __slots__ = ("kv", "nslots", "max_slots", "cache", "buf", "state",
                  "pos", "fresh", "streams", "steps", "replica_idx",
                  "unread", "read_at", "width", "chunk", "holds_pages",
-                 "_token_bytes", "_slot_bytes")
+                 "holds_states", "_token_bytes", "_slot_bytes")
 
     def __init__(self, kv: int, max_slots: int,
                  replica_idx: int | None = None, width: int = 1,
@@ -552,17 +576,17 @@ class PagePool:
         )
 
     def token_bytes(self) -> float | None:
-        """Resident KV bytes a cached position (``page_bytes`` over
+        """Resident KV bytes a cached position (the pages' bytes over
         slots x pages): what a token costs the pool, all layers.  The
         same at every slot bucket: worked out when the pool allocates.
-        None for a pool of states: a token costs it nothing."""
+        None for a pool of states alone: a token costs it nothing."""
         return self._token_bytes
 
     def slot_bytes(self) -> float | None:
-        """Resident state bytes a slot (``page_bytes`` over slots), all
-        layers, of a pool whose cache has no length axis: what a
-        request costs it, whatever its length.  None for a pool of
-        pages, which a request fills by the token."""
+        """Resident state bytes a slot (the states' bytes over slots),
+        all layers: what a request costs the pool's states, whatever its
+        length.  None for a pool of pages alone, which a request fills
+        by the token.  A pool that holds both has both."""
         return self._slot_bytes
 
     @property
@@ -592,8 +616,8 @@ class PagePool:
         # kept from its dispatch to the turn after.
         self.unread = None
         self.nslots = 0
-        # Pages or states: the allocated tree says (``_alloc``).
-        self.holds_pages = True
+        # Pages, states or both: the allocated tree says (``_alloc``).
+        self.holds_pages, self.holds_states = True, False
         self._token_bytes = self._slot_bytes = None
         self.pos = np.zeros(0, np.int32)
         self.fresh = np.zeros(0, bool)
@@ -618,14 +642,17 @@ class PagePool:
         self.streams = [None] * nslots
         self.nslots = nslots
         self.holds_pages = holds_pages(cache_shapes)
+        self.holds_states = holds_states(cache_shapes)
+        states = state_bytes(self.cache)
         if self.holds_pages:
-            self._token_bytes = self.page_bytes() / (nslots * self.kv)
-        else:
-            self._slot_bytes = self.page_bytes() / nslots
+            self._token_bytes = (self.page_bytes() - states) \
+                / (nslots * self.kv)
+        if self.holds_states:
+            self._slot_bytes = states / nslots
 
     def _grow(self, cache_shapes, nslots: int) -> None:
         """Pad every per-slot axis up to the next slot bucket; existing
-        slots keep their pages and positions bit-for-bit."""
+        slots keep their pages, states and positions bit-for-bit."""
         import jax
         import jax.numpy as jnp
 

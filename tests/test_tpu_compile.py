@@ -358,3 +358,59 @@ def test_retention_chunk_program_updates_the_states_in_place(
         ) if shape == f"f32[{slots},8,65,128,128]"
     }
     assert makers <= {"parameter", "get-tuple-element"}, makers
+
+
+@pytest.mark.parametrize("slots", [2, 64])
+def test_hybrid_step_updates_pages_and_states_in_place_at_falcon_h1_widths(
+        slots, one_chip, monkeypatch):
+    """The engine's step program of ``HybridLM`` at Falcon-H1-34B's
+    widths (two layers; a Mamba-2 mixer of 32 heads of 128 over a state
+    of 256 beside attention of 20 query heads over 4 key/value heads of
+    128, the whole vocabulary, bfloat16) over the 512 bucket: one
+    ``ssm_step`` and one ``decode_attend`` kernel a layer; the float32
+    state, the tail, the pages and the token buffer each aliased; nothing
+    but the kernel makes an array of the state's shape."""
+    import re
+
+    from learningorchestra_tpu.models.hybrid import HybridLM
+    from learningorchestra_tpu.serve.decode.pages import (
+        build_step, chunk_width,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    est = HybridLM(
+        vocab_size=261120, hidden_dim=5120, num_layers=2, num_heads=20,
+        num_kv_heads=4, head_dim=128, mlp_dim=21504, rope_theta=1e11,
+        mamba_heads=32, mamba_head_dim=128, mamba_groups=2,
+        mamba_state=256, mamba_conv=4, mamba_chunk=128,
+        key_multiplier=0.011048543456039804, max_len=262144,
+    )
+    assert chunk_width(est.module) == 1
+    step, cache = build_step(est.module, slots, 512)
+    mixer = cache["HybridBlock_0"]["Mamba2Mixer_0"]
+    assert mixer["ssm_state"].shape == (slots, 32, 256, 128)
+    assert mixer["conv_tail"].shape == (slots, 3, 5120)
+    variables = jax.eval_shape(
+        est.module.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )
+    text = step.program.lower(
+        _on(one_chip, variables), _on(one_chip, cache),
+        jax.ShapeDtypeStruct((slots, 512), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((3, slots), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+    kernels = re.findall(
+        r"%(\S+) = [^=]* custom-call\([^\n]*custom_call_target="
+        r"\"tpu_custom_call\"", text)
+    assert sorted(re.sub(r"[.\d]+$", "", k) for k in kernels) \
+        == ["decode_attend"] * 2 + ["ssm_step"] * 2
+    # per layer: the state, the tail, K and V pages; and the buffer
+    assert text.split("\n", 1)[0].count("-alias)") >= 9
+    makers = {
+        op for shape, op in re.findall(
+            r"= (\w+\[[\d,]*\])\{[^ ]*\} ([\w-]+)\(", text
+        ) if shape == f"f32[{slots},32,256,128]"
+    }
+    assert makers <= {"parameter", "get-tuple-element"}, makers
+    for scope in ("ssm_proj", "ssm_conv", "ssm_step", "ssm_norm",
+                  "ssm_out"):
+        assert scope in text, scope
