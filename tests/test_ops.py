@@ -27,6 +27,34 @@ def qkv():
     return mk(), mk(), mk()
 
 
+#: (heads a grid step, sequence length) of the head-blocked schedule at
+#: 4 heads: 500 positions are padded to one block of 512.
+SCHEDULES = [(hb, t) for hb in (1, 2, 4) for t in (128, 500)]
+
+
+def _schedule_case(hb, t, monkeypatch):
+    """q, k, v of 2 x 4 heads x ``t`` x 16 and a key mask (batch 0 with
+    its last third padded, batch 1 with every key masked), the kernels
+    held to ``hb`` heads a grid step over one block of the sequence."""
+    from learningorchestra_tpu.ops import attention
+
+    real = attention.flash_schedule
+
+    def schedule(*args):
+        _, block_q, block_k = real(*args)
+        return hb, block_q, block_k
+
+    monkeypatch.setattr(attention, "flash_schedule", schedule)
+    assert real(t, t, 4, 16, None)[1:] == (-(-t // 128) * 128,) * 2
+    rng = np.random.default_rng(hb * 1000 + t)
+    q, k, v = (
+        jnp.asarray(rng.standard_normal((2, 4, t, 16)), jnp.float32)
+        for _ in range(3)
+    )
+    mask = jnp.zeros((2, t), bool).at[0, : t - t // 3].set(True)
+    return q, k, v, mask
+
+
 class TestFlashAttentionForward:
     def test_matches_reference_unmasked(self, qkv):
         q, k, v = qkv
@@ -76,6 +104,16 @@ class TestFlashAttentionForward:
             f(q, k, v), mha_reference(q, k, v), atol=2e-5, rtol=2e-5
         )
 
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("hb,t", SCHEDULES)
+    def test_head_blocked_schedule_matches_reference(
+            self, hb, t, causal, monkeypatch):
+        q, k, v, mask = _schedule_case(hb, t, monkeypatch)
+        out = flash_attention(q, k, v, mask, causal=causal)
+        ref = mha_reference(q, k, v, mask, causal=causal)
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+        assert float(jnp.max(jnp.abs(out[1]))) == 0.0  # all keys masked
+
 
 class TestFlashAttentionBackward:
     def _grads(self, fn, q, k, v):
@@ -119,6 +157,23 @@ class TestFlashAttentionBackward:
             lambda q, k, v: jnp.sum(mha_reference(q, k, v) ** 2)
         )(q, k, v)
         np.testing.assert_allclose(f(q, k, v), g_ref, atol=5e-5, rtol=5e-5)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("hb,t", SCHEDULES)
+    def test_head_blocked_schedule_grads_match_reference(
+            self, hb, t, causal, monkeypatch):
+        q, k, v, mask = _schedule_case(hb, t, monkeypatch)
+        g1 = self._grads(
+            lambda q, k, v: flash_attention(q, k, v, mask, causal=causal),
+            q, k, v,
+        )
+        g2 = self._grads(
+            lambda q, k, v: mha_reference(q, k, v, mask, causal=causal),
+            q, k, v,
+        )
+        for a, b in zip(g1, g2):
+            np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
+            assert float(jnp.max(jnp.abs(a[1]))) == 0.0  # all keys masked
 
 
 class TestModelIntegration:

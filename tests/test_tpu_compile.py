@@ -414,3 +414,38 @@ def test_hybrid_step_updates_pages_and_states_in_place_at_falcon_h1_widths(
     for scope in ("ssm_proj", "ssm_conv", "ssm_step", "ssm_norm",
                   "ssm_out"):
         assert scope in text, scope
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["encoder", "causal"])
+def test_flash_kernels_fit_the_chip_head_blocked_at_bert_base_widths(
+        causal, one_chip, monkeypatch):
+    """The attention of ``bert-base.fit-s512`` (batch 32, 12 heads of 64,
+    512 positions, a key mask), forward and backward: each of the three
+    kernels takes 6 heads over the whole sequence a grid step, and the
+    VMEM the step asks for (``ops/attention.py`` ``_vmem_bytes``) is
+    enough for the chip's compiler.  The compiled instructions keep the
+    kernels' names, the schedule's scope in their ``op_name``."""
+    import re
+
+    from learningorchestra_tpu.ops.attention import flash_attention
+
+    monkeypatch.setenv("LO_TPU_FLASH_INTERPRET", "0")
+
+    def step(q, k, v, km):
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, km, causal=causal)
+            return out.astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    a = jax.ShapeDtypeStruct((32, 12, 512, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    km = jax.ShapeDtypeStruct((32, 512), jnp.bool_, sharding=one_chip)
+    text = jax.jit(step).lower(a, a, a, km).compile().as_text()
+    calls = re.findall(
+        r"%(\S+) = [^\n]* custom-call\([^\n]*custom_call_target="
+        r"\"tpu_custom_call\"[^\n]*op_name=\"([^\"]*)\"", text)
+    assert sorted(re.sub(r"[.\d]+$", "", name) for name, _ in calls) \
+        == ["flash_dkv", "flash_dq", "flash_fwd"]
+    for name, op_name in calls:
+        assert "(flash_sched_h6_q512_k512)" in op_name, (name, op_name)

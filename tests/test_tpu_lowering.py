@@ -172,6 +172,142 @@ class TestKernelVariantsLowerer:
         assert n >= 2  # fwd (for residuals) + backward kernel(s)
 
 
+def _flash_calls(fn, *args) -> list:
+    """``(kernel name, scope)`` of each flash custom call in the TPU
+    lowering of ``fn``: the kernel's ``kernel_name`` and the name stack
+    of its location, which the compiled HLO carries as ``op_name``."""
+    import re
+
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text(debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    calls = []
+    for line in text.splitlines():
+        found = re.search(r'kernel_name = "([^"]+)".*loc\((#loc\d+)\)$',
+                          line)
+        if found and "tpu_custom_call" in line:
+            calls.append((found.group(1), locs.get(found.group(2), "")))
+    return calls
+
+
+def _fwd_bwd(**kw):
+    from learningorchestra_tpu.ops.attention import flash_attention
+
+    def step(q, k, v, km):
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, km, **kw)
+            return out.astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    return step
+
+
+class TestFlashSchedule:
+    """Where one block holds the sequence a grid step takes a block of
+    heads (``flash_schedule``); every other call keeps one head a step
+    and the (256, 512) / (512, 1024) blocks."""
+
+    def test_the_fit_cells_shape_is_head_blocked(self, mosaic):
+        # bert-base.fit-s512: batch 32, 12 heads of 64, 512 positions.
+        a = jax.ShapeDtypeStruct((32, 12, 512, 64), jnp.bfloat16)
+        km = jax.ShapeDtypeStruct((32, 512), jnp.bool_)
+        calls = _flash_calls(_fwd_bwd(), a, a, a, km)
+        assert sorted(name for name, _ in calls) == [
+            "flash_dkv", "flash_dq", "flash_fwd"]
+        for name, scope in calls:
+            assert "(flash_sched_h6_q512_k512)" in scope, (name, scope)
+            assert scope.endswith(f"/{name}/pallas_call"), scope
+
+    @pytest.mark.parametrize("shape,kw", [
+        ((1, 2, 8192, 64), {}),
+        ((2, 4, 512, 64), {"causal": True, "window": 128}),
+        ((2, 4, 512, 128), {}),
+        ((2, 4, 512, 64), {"block_q": 256, "block_k": 512}),
+    ], ids=["t8192", "window", "head_dim128", "explicit_blocks"])
+    def test_other_calls_keep_one_head_a_step(self, mosaic, shape, kw):
+        a = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        km = jax.ShapeDtypeStruct(shape[:1] + shape[2:3], jnp.bool_)
+        calls = _flash_calls(_fwd_bwd(**kw), a, a, a, km)
+        assert len(calls) == 3
+        assert not any("sched" in scope for _, scope in calls), calls
+
+    def test_the_ring_keeps_one_head_a_step(self, mosaic):
+        from learningorchestra_tpu.parallel.mesh import MeshSpec, build_mesh
+        from learningorchestra_tpu.parallel.ring_attention import (
+            ring_flash_attention,
+        )
+
+        mesh = build_mesh(MeshSpec(sp=8))
+        q = jax.ShapeDtypeStruct((1, 1024, 2, 32), jnp.bfloat16)
+
+        def step(q, k, v):
+            return jax.grad(lambda q, k, v: ring_flash_attention(
+                q, k, v, mesh=mesh).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+
+        calls = _flash_calls(step, q, q, q)
+        assert {name for name, _ in calls} >= {"ring_fwd", "ring_dq",
+                                               "ring_dkv"}
+        assert not any("sched" in scope for _, scope in calls), calls
+
+    @pytest.mark.parametrize("t_q,t_k,heads,head_dim,window,expected", [
+        (512, 512, 12, 64, None, (6, 512, 512)),
+        (500, 500, 4, 16, None, (4, 512, 512)),
+        (128, 128, 16, 64, None, (16, 128, 128)),
+        (48, 40, 3, 32, None, (3, 48, 40)),
+        (512, 512, 7, 64, None, (7, 512, 512)),
+        (512, 512, 25, 64, None, (5, 512, 512)),
+        (1024, 1024, 12, 64, None, (1, 1024, 1024)),
+        (1025, 1025, 12, 64, None, (1, 256, 512)),
+        (512, 512, 12, 64, 128, (1, 256, 512)),
+        (512, 512, 12, 128, None, (1, 256, 512)),
+        (8192, 8192, 12, 64, None, (1, 256, 512)),
+        (9000, 9000, 12, 64, None, (1, 512, 1024)),
+    ])
+    def test_schedule_rule(self, t_q, t_k, heads, head_dim, window,
+                           expected):
+        from learningorchestra_tpu.ops import attention
+
+        got = attention.flash_schedule(t_q, t_k, heads, head_dim, window)
+        assert got == expected
+        hb, block_q, block_k = got
+        assert heads % hb == 0
+        if hb > 1:  # the largest divisor of the heads that fits
+            fits = [
+                n for n in range(1, heads + 1) if heads % n == 0
+                and attention._step_vmem(n, block_q, block_k, head_dim, 2)
+                <= attention._VMEM_BUDGET
+            ]
+            assert hb == max(fits)
+
+    def test_vmem_reckoning_pads_lanes_and_sublanes(self):
+        from learningorchestra_tpu.ops import attention
+
+        # A (512, 64) bf16 block takes a (512, 128) one's room, a (512,
+        # 1) f32 column a (512, 128) f32 matrix's, a (1, 512) f32 row
+        # eight rows'.
+        assert attention._tile_bytes(512, 64, 2) == 512 * 128 * 2
+        assert attention._tile_bytes(512, 1, 4) == 512 * 128 * 4
+        assert attention._tile_bytes(1, 512, 4) == 8 * 512 * 4
+        assert attention._tile_bytes(1, 512, 2) == 16 * 512 * 2
+        # fwd at 6 heads of (512, 64) bf16: q, k, v, o and lse blocks
+        # double-buffered, m, l, acc a head, three score tiles.
+        blocks = 4 * 512 * 128 * 2 + 512 * 128 * 4
+        scratch = 3 * 512 * 128 * 4
+        need = 2 * (6 * blocks + 8 * 512 * 4) + 6 * scratch \
+            + 3 * 512 * 512 * 4
+        assert attention._vmem_bytes("fwd", 6, 512, 512, 64, 2) == need
+        # The backward kernels take more than the forward; every step
+        # grows with the heads it holds.
+        for kernel in ("dq", "dkv"):
+            assert attention._vmem_bytes(kernel, 6, 512, 512, 64, 2) \
+                > need
+        assert attention._step_vmem(12, 512, 512, 64, 2) \
+            > attention._VMEM_BUDGET \
+            >= attention._step_vmem(6, 512, 512, 64, 2)
+
+
 class TestS2dResNetLowersForTpu:
     def test_train_step_exports_for_tpu(self):
         # The MXU-friendly stem: prove the whole s2d train step
