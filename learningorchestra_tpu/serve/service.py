@@ -23,6 +23,7 @@ import numpy as np
 
 from learningorchestra_tpu.concurrency_rt import make_lock
 from learningorchestra_tpu.jobs.leases import LeaseTimeout
+from learningorchestra_tpu.obs import tracing as obs_tracing
 from learningorchestra_tpu.obs.metrics import get_registry
 from learningorchestra_tpu.serve.batcher import MicroBatcher
 from learningorchestra_tpu.serve.fleet.manager import FleetManager
@@ -123,6 +124,10 @@ class ServingService:
 
         meta = self.ctx.require_finished_parent(name)
         instance = self.ctx.volumes.read_object(meta.get("type", ""), name)
+        # The registry's ``load_artifact`` span is open around this.
+        obs_tracing.set_span_attrs(
+            **self.ctx.volumes.raw_extent(meta.get("type", ""), name)
+        )
         if not isinstance(instance, NeuralEstimator):
             raise ValidationError(
                 f"artifact {name!r} is not a neural model binary "

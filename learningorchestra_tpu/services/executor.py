@@ -64,15 +64,17 @@ def store_history_rows(documents, name: str, history: dict) -> int:
 
 def publish_object(ctx: ServiceContext, artifact_type: str, name: str,
                    obj: Any, *, replaces: bool = True) -> None:
-    """Write a job's result binary as the job's ``publish`` span (attr
-    ``bytes``).  ``replaces``: a re-run just replaced this artifact's
-    binary, so a serving registry holding its old params resident must
-    reload before the next request."""
+    """Write a job's result binary as the job's ``publish`` span (attrs
+    ``bytes``, and ``raw_bytes`` / ``raw_arrays``: how much of it went
+    raw beside the pickle stream).  ``replaces``: a re-run just
+    replaced this artifact's binary, so a serving registry holding its
+    old params resident must reload before the next request."""
     with obs_tracing.span("publish"):
         ctx.volumes.save_object(artifact_type, name, obj)
         if obs_tracing.current_trace() is not None:
             obs_tracing.set_span_attrs(
-                bytes=ctx.volumes.object_bytes(artifact_type, name)
+                bytes=ctx.volumes.object_bytes(artifact_type, name),
+                **ctx.volumes.raw_extent(artifact_type, name),
             )
         if replaces:
             ctx.notify_artifact_changed(name)
@@ -202,6 +204,10 @@ class ExecutorService:
                 instance = self.ctx.volumes.read_object(
                     parent_type, parent_name
                 )
+                if obs_tracing.current_trace() is not None:
+                    obs_tracing.set_span_attrs(**self.ctx.volumes.raw_extent(
+                        parent_type, parent_name
+                    ))
             params = dsl.resolve_params(method_parameters, self.ctx.loader)
             # Which preemption-retry attempt is this body running as?
             # 0 on the first execution; >0 means the engine's in-loop

@@ -20,6 +20,14 @@ hosts cap the size of one file (``RLIMIT_FSIZE`` — the write fails with
 state.  The artifact's path then holds a small manifest naming the part
 files next to it; anything that fits one part stays the plain dill file
 it always was.
+
+A plain numpy array of at least :data:`RAW_MIN_BYTES` (dense in memory,
+in C or any other axis order; no object dtype) is not copied through
+the pickle stream: the stream holds its index, dtype, shape and axis
+order, and its bytes go raw, from the array's own memory, into part
+files of their own, written and read side by side by a few threads.
+The manifest lists those arrays under ``raw``.  An object with no such
+array is written exactly as before.
 """
 
 from __future__ import annotations
@@ -30,10 +38,12 @@ import os
 import re
 import shutil
 import uuid
+from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 from typing import Any
 
 import dill
+import numpy as np
 
 # Same grammar as DocumentStore collection names: binary names come from
 # REST request JSON and become file names — no separators, no traversal.
@@ -50,6 +60,10 @@ def _validate_name(name: str) -> str:
 PART_BYTES = 16 << 20
 #: First bytes of a manifest; a pickle starts with ``\x80``.
 _MANIFEST_MAGIC = b"LO-ARTIFACT-PARTS\n"
+#: Smallest array written raw beside the pickle stream (module docstring).
+RAW_MIN_BYTES = 1 << 20
+#: Threads that write or read one artifact's raw arrays.
+_RAW_THREADS = 4
 
 
 def max_file_bytes() -> int:
@@ -112,6 +126,162 @@ class _PartWriter:
         if self._fh is not None:
             self._fh.close()
             self._fh = None
+
+
+def _raw_axes(obj) -> tuple | None:
+    """The axis order in which ``obj``'s bytes lie in memory, where it
+    goes raw, else None: decided by type, layout and size alone (an
+    ndarray subclass or a ``jax.Array`` keeps its own reduce).  A
+    C-contiguous array's order is ``(0, 1, ...)``; a dense array in
+    another order (a transposed view; a TPU host copy of a 3-d kernel)
+    goes raw in its own order too."""
+    if (type(obj) is not np.ndarray or obj.dtype.hasobject
+            or obj.nbytes < RAW_MIN_BYTES):
+        return None
+    axes = tuple(np.argsort([-s for s in obj.strides], kind="stable"))
+    return axes if obj.transpose(axes).flags.c_contiguous else None
+
+
+def _write_file(path: Path, data: memoryview) -> None:
+    with open(path, "wb", buffering=0) as fh:
+        done = 0
+        while done < len(data):
+            done += fh.write(data[done:])
+
+
+def _read_file(path: Path, buf: memoryview) -> None:
+    with open(path, "rb", buffering=0) as fh:
+        done = 0
+        while done < len(buf):
+            n = fh.readinto(buf[done:])
+            if not n:
+                raise EOFError(f"artifact part {path} ends early")
+            done += n
+
+
+class _RawWriter:
+    """Writes each C-contiguous array :meth:`add` is given into part
+    files ``<gen>-r<i>`` of at most ``part_bytes`` each, on a pool of
+    :data:`_RAW_THREADS` threads started by the first array."""
+
+    def __init__(self, directory: Path, gen: str, part_bytes: int):
+        self.directory, self.gen, self.part_bytes = directory, gen, part_bytes
+        self.arrays: list[dict] = []  # the manifest's ``raw``
+        self.parts: list[Path] = []
+        self._pool: ThreadPoolExecutor | None = None
+        self._writes = []
+
+    def add(self, array: np.ndarray) -> None:
+        if self._pool is None:
+            self.directory.mkdir(exist_ok=True)
+            self._pool = ThreadPoolExecutor(_RAW_THREADS)
+        # The array's own memory, no copy (a bfloat16 array's dtype has
+        # no buffer format, so a memoryview of it cannot be cast).
+        data = memoryview(array.reshape(-1).view(np.uint8))
+        first = len(self.parts)
+        for lo in range(0, len(data), self.part_bytes):
+            self.parts.append(
+                self.directory / f"{self.gen}-r{len(self.parts):05d}"
+            )
+            self._writes.append(self._pool.submit(
+                _write_file, self.parts[-1], data[lo:lo + self.part_bytes]
+            ))
+        self.arrays.append({
+            "index": len(self.arrays), "dtype": str(array.dtype),
+            "shape": list(array.shape), "nbytes": len(data),
+            "parts": [first, len(self.parts)],
+        })
+
+    def close(self) -> None:
+        """Wait for every write; raise the first that failed."""
+        if self._pool is None:
+            return
+        self._pool.shutdown(wait=True)
+        for write in self._writes:
+            write.result()
+
+
+class _RawPickler(dill.Pickler):
+    """dill's pickler as ``dill.dump`` builds it, with every array that
+    :func:`_raw_axes` takes handed to ``write`` in its memory order and
+    kept out of the stream: the stream holds ``(index, dtype, shape,
+    axes)`` in its place, ``axes`` turning the array as written back
+    into the one pickled.  With no such array the stream is byte for
+    byte ``dill.dump``'s."""
+
+    def __init__(self, file, write):
+        super().__init__(file, dill.settings["protocol"])
+        self._write = write
+        # id -> (pid, array): holding the array keeps its id from being
+        # reused by a temporary pickled later (a ``__getstate__`` copy).
+        self._raw: dict[int, tuple] = {}
+
+    def persistent_id(self, obj):
+        seen = self._raw.get(id(obj))
+        if seen is None:
+            axes = _raw_axes(obj)
+            if axes is None:
+                return None
+            written = obj.transpose(axes)
+            seen = self._raw[id(obj)] = ((
+                len(self._raw), obj.dtype, written.shape,
+                tuple(int(i) for i in np.argsort(axes)),
+            ), obj)
+            self._write(written)
+        return seen[0]
+
+
+class _RawUnpickler(dill.Unpickler):
+    """dill's unpickler, handing each ``(index, dtype, shape, axes)``
+    the array read raw for it (one array an index, as pickle's memo
+    would give one object)."""
+
+    def __init__(self, file, buffers: list[np.ndarray]):
+        super().__init__(file)
+        self._buffers = buffers
+        self._arrays: dict[int, np.ndarray] = {}
+
+    def persistent_load(self, pid):
+        index, dtype, shape, axes = pid
+        if index not in self._arrays:
+            self._arrays[index] = self._buffers[index].view(dtype).reshape(
+                shape
+            ).transpose(axes)
+        return self._arrays[index]
+
+
+def _part_paths(directory: Path, manifest: dict) -> list[Path]:
+    """Every part file a manifest names: the stream's, then the raw."""
+    gen = manifest["gen"]
+    return [
+        directory / f"{gen}-{i:05d}" for i in range(manifest["parts"])
+    ] + [
+        directory / f"{gen}-r{i:05d}"
+        for i in range(manifest.get("raw_parts", 0))
+    ]
+
+
+def _read_raw(directory: Path, manifest: dict) -> list[np.ndarray]:
+    """The manifest's raw arrays as writable byte buffers, allocated
+    once and filled from their part files side by side."""
+    arrays = manifest.get("raw", [])
+    buffers = [np.empty(a["nbytes"], np.uint8) for a in arrays]
+    if not arrays:
+        return buffers
+    paths = _part_paths(directory, manifest)[manifest["parts"]:]
+    step = manifest["raw_part_bytes"]
+    with ThreadPoolExecutor(_RAW_THREADS) as pool:
+        reads = []
+        for a, buf in zip(arrays, buffers):
+            first, stop = a["parts"]
+            for k in range(stop - first):
+                reads.append(pool.submit(
+                    _read_file, paths[first + k],
+                    memoryview(buf)[k * step:(k + 1) * step],
+                ))
+    for read in reads:
+        read.result()
+    return buffers
 
 
 class _PartReader(io.RawIOBase):
@@ -196,32 +366,43 @@ class VolumeStorage:
         concurrent job dill-loads it must never expose a torn file
         (same discipline as the shard writer's os.replace).  The parts
         are complete before the one rename that makes them the
-        artifact — of the only part where the object fits one, of the
-        manifest otherwise — and the parts it supersedes go after."""
+        artifact — of the only part where the object fits one and has
+        no raw array, of the manifest otherwise — and the parts it
+        supersedes go after."""
         path.parent.mkdir(parents=True, exist_ok=True)
         directory = _parts_dir(path)
         old = _manifest_of(path) if path.is_file() else None
         writer = _PartWriter(directory, uuid.uuid4().hex, self.part_bytes)
+        raw = _RawWriter(directory, writer.gen, self.part_bytes)
         try:
             try:
-                dill.dump(obj, writer)
+                _RawPickler(writer, raw.add).dump(obj)
             finally:
-                writer.close()
-            if len(writer.parts) == 1:
+                try:
+                    writer.close()
+                finally:
+                    raw.close()
+            if len(writer.parts) == 1 and not raw.arrays:
                 os.replace(writer.parts[0], path)
             else:
+                manifest = {"gen": writer.gen, "parts": len(writer.parts)}
+                if raw.arrays:
+                    manifest.update(
+                        raw=raw.arrays, raw_parts=len(raw.parts),
+                        raw_part_bytes=self.part_bytes,
+                    )
                 tmp = directory / f"{writer.gen}-manifest"
-                tmp.write_bytes(_MANIFEST_MAGIC + json.dumps({
-                    "gen": writer.gen, "parts": len(writer.parts),
-                }).encode())
+                tmp.write_bytes(
+                    _MANIFEST_MAGIC + json.dumps(manifest).encode()
+                )
                 os.replace(tmp, path)
         except BaseException:
-            for part in writer.parts:
+            for part in writer.parts + raw.parts:
                 part.unlink(missing_ok=True)
             raise
         if old is not None:
-            for i in range(old["parts"]):
-                (directory / f"{old['gen']}-{i:05d}").unlink(missing_ok=True)
+            for part in _part_paths(directory, old):
+                part.unlink(missing_ok=True)
         try:
             directory.rmdir()  # nothing left in it: a one-part artifact
         except OSError:
@@ -237,12 +418,12 @@ class VolumeStorage:
                     fh.seek(0)
                     return dill.load(fh)
                 manifest = json.loads(fh.read())
-            stream = io.BufferedReader(_PartReader([
-                directory / f"{manifest['gen']}-{i:05d}"
-                for i in range(manifest["parts"])
-            ]), buffer_size=1 << 20)
+            stream = io.BufferedReader(_PartReader(
+                _part_paths(directory, manifest)[:manifest["parts"]]
+            ), buffer_size=1 << 20)
             try:
-                return dill.load(stream)
+                buffers = _read_raw(directory, manifest)
+                return _RawUnpickler(stream, buffers).load()
             except FileNotFoundError:
                 # A re-run published a new generation and removed this
                 # one's parts mid-read: load what it published.
@@ -289,16 +470,25 @@ class VolumeStorage:
 
     def object_bytes(self, artifact_type: str, name: str) -> int:
         """What :meth:`save_object` wrote for this artifact: the one
-        file, or the parts behind its manifest."""
+        file, or the parts behind its manifest, raw parts included."""
         path = self.path_for(artifact_type, name)
         manifest = _manifest_of(path)
         if manifest is None:
             return path.stat().st_size
-        directory = _parts_dir(path)
         return sum(
-            (directory / f"{manifest['gen']}-{i:05d}").stat().st_size
-            for i in range(manifest["parts"])
+            part.stat().st_size
+            for part in _part_paths(_parts_dir(path), manifest)
         )
+
+    def raw_extent(self, artifact_type: str, name: str) -> dict:
+        """How much of this artifact went raw beside the pickle stream:
+        ``raw_bytes`` and ``raw_arrays`` (the spans' attrs)."""
+        manifest = _manifest_of(self.path_for(artifact_type, name)) or {}
+        arrays = manifest.get("raw", [])
+        return {
+            "raw_bytes": sum(a["nbytes"] for a in arrays),
+            "raw_arrays": len(arrays),
+        }
 
     # -- lifecycle ------------------------------------------------------------
 

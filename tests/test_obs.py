@@ -317,6 +317,57 @@ def test_every_second_of_a_fit_job_has_a_named_span(trained_job):
     assert covered <= job["durationS"] + 0.05
 
 
+def test_publish_and_load_spans_count_the_raw_arrays(api):
+    """A fit whose parameters hold an array of at least
+    ``RAW_MIN_BYTES`` publishes it raw: the ``publish`` span says how
+    much (``raw_bytes``, ``raw_arrays``) of its ``bytes``, and a job
+    that continues from the artifact says what it read the same way in
+    its ``load_artifact`` span."""
+    base, _server = api
+    resp = requests.post(f"{base}/model/tensorflow", json={
+        "modelName": "obs_wide",
+        "modulePath": "learningorchestra_tpu.models.mlp",
+        "class": "MLPClassifier",
+        # The 512 x 512 kernel and its two Adam moments: 1 MiB each.
+        "classParameters": {"hidden_layer_sizes": [512, 512],
+                            "num_classes": 2},
+    })
+    assert resp.status_code == 201, resp.text
+    wait_finished(base, "obs_wide")
+    rng = np.random.default_rng(0)
+    fit = {
+        "x": rng.standard_normal((32, 4)).tolist(),
+        "y": rng.integers(0, 2, (32,)).tolist(),
+        "epochs": 1, "batch_size": 16,
+    }
+
+    def spans(name):
+        return requests.get(
+            f"{base}/observability/jobs/{name}/trace", timeout=30
+        ).json()["spans"]
+
+    for name, parent in (("obs_wide_fit", "obs_wide"),
+                         ("obs_wide_refit", "obs_wide_fit")):
+        resp = requests.post(f"{base}/train/tensorflow", json={
+            "name": name, "parentName": parent, "modelName": "obs_wide",
+            "method": "fit", "methodParameters": fit,
+        })
+        assert resp.status_code == 201, resp.text
+        wait_finished(base, name)
+    publish = next(
+        s for s in spans("obs_wide_fit") if s["name"] == "publish"
+    )["attrs"]
+    assert publish["raw_arrays"] >= 3
+    assert 3 << 20 <= publish["raw_bytes"] < publish["bytes"]
+    load = next(
+        s for s in spans("obs_wide_refit") if s["name"] == "load_artifact"
+    )["attrs"]
+    assert load["parent"] == "obs_wide_fit"
+    assert (load["raw_bytes"], load["raw_arrays"]) == (
+        publish["raw_bytes"], publish["raw_arrays"],
+    )
+
+
 def test_a_streaming_fit_names_the_same_pieces(tmp_path):
     """The shard-streaming fit (``_fit_streaming``) leaves the spans
     the in-memory fit does: ``fit_init``, one with-block ``epoch`` an
